@@ -12,8 +12,8 @@ the min-of-N difference isolates the stage).
 CPU is the honest qualifier: on a TPU the elementwise augment ops fuse
 into memory-bound kernels XLA was already emitting, so the CPU number —
 where the same ops compete for the cores running everything else — is the
-UPPER bound for the stage's relative cost. The device-side confirmation
-row rides tpu_session_r10.sh.
+UPPER bound for the stage's relative cost. The device-side cost is not
+measured.
 
     JAX_PLATFORMS=cpu python benchmarks/augment_step_bench.py \
         --model vggf --image-size 128 --batch 16 --repeats 6 \

@@ -8,7 +8,7 @@ Two receipts, one harness:
    ALTERNATING-window protocol of every r7+ receipt times the jitted
    train step bucketing-OFF vs bucketing-ON at the same sharding basis.
    CPU is the honest qualifier for the OVERHEAD half of the claim; the
-   overlap WIN is device-side and rides tpu_session_r11.sh.
+   overlap WIN is device-side and is not measured.
 
 2. **Lowered-HLO overlap evidence** (`--hlo-report`): the committed
    ASSERTION that bucketing produces an overlap-capable exchange

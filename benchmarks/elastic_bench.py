@@ -17,11 +17,17 @@ semantics —
   a restart is; in-process timing would flatter it by the whole runtime
   warm-up.
 
-Both paths share one persistent XLA compilation cache (set up before
-jax initializes, inherited by the restart subprocess): a preempted fleet
-has a warm compile cache, and min-of-N timings therefore compare the
-warm path on BOTH sides — without it the receipt would mostly race two
-cold compiles of the same survivor-mesh program.
+Both paths share one persistent XLA compilation cache at its fixed home
+(utils/compile_cache: JAX_COMPILATION_CACHE_DIR when set, else the
+in-checkout directory — the restart subprocess resolves the same path): a
+preempted fleet has a warm compile cache, and min-of-N timings therefore
+compare the warm path on BOTH sides — without it the receipt would mostly
+race two cold compiles of the same survivor-mesh program.
+
+A CPU receipt by construction: the restart control IS a second
+interpreter, and an accelerator belongs to one process at a time, so a
+parent that held the chip could never time it. Both sides are pinned to
+four virtual CPU devices and the artifact names that platform.
 
 The artifact (--json-out) carries `metric:
 elastic_resize_downtime_seconds` with `value` = the elastic row's min
@@ -88,11 +94,6 @@ def _build_trainer(cfg, mesh_size: int, jsonl_path: str | None = None):
     from distributed_vgg_f_tpu.parallel.mesh import MeshSpec, build_mesh
     from distributed_vgg_f_tpu.train.trainer import Trainer
     from distributed_vgg_f_tpu.utils.logging import MetricLogger
-    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if cache:  # robust to jax having initialized before the env was set
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
     mesh = build_mesh(MeshSpec(("data",), (mesh_size,)),
                       devices=jax.devices()[:mesh_size])
     logger = MetricLogger(jsonl_path=jsonl_path, stream=io.StringIO())
@@ -143,8 +144,7 @@ def restart_control_once(args, workdir: str, fresh_checkpoint: bool) -> float:
          "--steps", str(child_steps),
          "--preempt-at", str(args.preempt_at),
          "--survivors", str(DEVICES - 1)],
-        check=True, env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        stdout=subprocess.DEVNULL)
+        check=True, stdout=subprocess.DEVNULL)
     return time.perf_counter() - t0
 
 
@@ -181,26 +181,29 @@ def main(argv=None) -> int:
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
-    # the virtual device count must be pinned before jax initializes
-    # (CPU receipt: 4 virtual devices, resize 4->3 on rank-1 preemption)
+    # platform and virtual device count are pinned before jax initializes
+    # (CPU receipt: 4 virtual devices, resize 4->3 on rank-1 preemption);
+    # the restart subprocess inherits both
+    os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count="
             f"{DEVICES}").strip()
+    # one warm compilation cache for BOTH paths — see the module docstring
+    # for why this is the honest comparison
+    import jax
+
+    from distributed_vgg_f_tpu.utils.compile_cache import enable_compile_cache
+    from distributed_vgg_f_tpu.utils.device import device_facts
+    enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
     if args._child_restart:
         return child_restart(args)
 
     elastic_runs, restart_s = [], []
     with tempfile.TemporaryDirectory(prefix="elastic_bench_") as workdir:
-        # one warm compilation cache for BOTH paths (subprocess inherits
-        # the env) — see the module docstring for why this is the honest
-        # comparison
-        os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                              os.path.join(workdir, "xla_cache"))
-        os.environ.setdefault(
-            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
         for i in range(args.repeats):
             run_dir = os.path.join(workdir, f"r{i}")
             os.makedirs(run_dir)
@@ -236,6 +239,7 @@ def main(argv=None) -> int:
         "metric": ELASTIC_METRIC,
         "value": row["downtime_seconds"],
         "unit": "seconds",
+        **device_facts(),
         "layouts": [row],
     }
     errors = schema.validate_bench_artifact(artifact)

@@ -17,8 +17,8 @@ The machine-checked half of the r5–r10 receipt discipline:
     python benchmarks/regression_sentinel.py --check /tmp/a.json
 
 Exit code: 0 = green, 1 = any check failed. One JSON line per finding on
-stdout plus a final summary line — greppable in CI logs, parseable by the
-session scripts.
+stdout plus a final summary line — greppable in CI logs, parseable by
+scripts.
 """
 
 from __future__ import annotations
@@ -72,8 +72,7 @@ def main(argv=None) -> int:
             json.dump(trajectory, f, indent=1)
             f.write("\n")
         print(json.dumps({"wrote": os.path.relpath(path, args.repo),
-                          "rounds": len(trajectory["host_decode"]),
-                          "device_rows": len(trajectory["device"])}))
+                          "rounds": len(trajectory["host_decode"])}))
 
     if args.check_committed:
         found = regress.check_committed(args.repo)

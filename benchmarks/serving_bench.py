@@ -316,6 +316,16 @@ def main(argv=None) -> int:
     ap.add_argument("--json-out", default="")
     args = ap.parse_args(argv)
 
+    from distributed_vgg_f_tpu.utils.compile_cache import enable_compile_cache
+    from distributed_vgg_f_tpu.utils.device import (NoAcceleratorError,
+                                                    require_accelerator)
+    enable_compile_cache()
+    try:
+        device = require_accelerator()
+    except NoAcceleratorError as e:
+        print(f"serving_bench: {e}", file=sys.stderr)
+        return 1
+
     from distributed_vgg_f_tpu.config import (ServingConfig,
                                               ServingTiersConfig)
     from distributed_vgg_f_tpu.serving.server import PredictServer
@@ -476,6 +486,7 @@ def main(argv=None) -> int:
                      f"tier {args.tier}"
                      + (", trained weights" if args.weights else "")),
         "host_vcpus": os.cpu_count(),
+        **device,
         "layouts": [row],
     }
     if value is None:

@@ -1,12 +1,12 @@
 """Time ViT-S/16 train-step variants of the attention sublayer on the chip.
 
-The r3 TPU trace (VERDICT r2 #2) attributed ~15.5% of the ViT step to
+The r3 TPU trace attributed ~15.5% of the ViT step to
 `data formatting` HLOs (attention layout transposes) and ~10% to
 rng-bit-generator + per-block uniforms (attention-weight dropout masks over
 (B,H,197,197) ×12 blocks). This harness measures each lever independently,
 plus the round-2 flax `nn.MultiHeadDotProductAttention` build as the
-regression reference, all in ONE process (single-grant TPU: clients queue,
-so serial in-process variants are the only safe sweep).
+regression reference, all in ONE process (a chip belongs to one process at
+a time, so the variants run serially in it).
 
 Usage:
     python benchmarks/vit_attention_variants.py [--batch-size 256] [--steps 20]

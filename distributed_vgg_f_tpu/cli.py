@@ -22,6 +22,9 @@ import sys
 
 
 def main(argv=None) -> None:
+    from distributed_vgg_f_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from distributed_vgg_f_tpu.config import parse_cli
     from distributed_vgg_f_tpu.train.trainer import Trainer
     from distributed_vgg_f_tpu.utils.logging import MetricLogger
@@ -108,7 +111,6 @@ def main(argv=None) -> None:
             # but say so, and let anything unexpected propagate.
             logger.log("eval_dataset_unavailable", {"error": repr(e)})
         trainer.fit(eval_dataset=eval_ds)
-
 
 
 if __name__ == "__main__":

@@ -946,7 +946,7 @@ class CollectorConfig:
     # `stale` (the entry keeps its last verdict + an age, never vanishes).
     stale_after_s: float = 10.0
     # Per-request scrape timeout — a hanging endpoint costs one cycle this
-    # much, then degrades to stale; it never wedges the collector.
+    # much, then degrades to stale; it never blocks the collector.
     scrape_timeout_s: float = 2.0
 
     def __post_init__(self):
@@ -1182,14 +1182,14 @@ def _vggf_imagenet_dp() -> ExperimentConfig:
         # flips the flagship on, with the CPU-mesh loss-trajectory parity
         # pin in tests/test_zero1.py. Single-process CPU smoke runs
         # downgrade themselves (one shard = replicated). The device HBM
-        # receipt stays queued for the next TPU grant (tpu_session_r10.sh).
+        # saving is not measured.
         # ZeRO-2 + bucketed overlap (r14): gradients held only as 1/N
         # shards and the exchange issued as 4 MB buckets in
         # reverse-backward order, so the scatter runs under the remaining
         # backward instead of after it (parallel/buckets.py; CPU
         # loss-trajectory parity + lowered-HLO overlap evidence pinned in
-        # tests/test_comm_buckets.py; step-time/HBM receipts queued in
-        # tpu_session_r11.sh).
+        # tests/test_comm_buckets.py; device step time and HBM are not
+        # measured).
         mesh=MeshConfig(shard_opt_state=True, shard_gradients=True,
                         comm_bucket_mb=4.0),
         train=TrainConfig(epochs=90.0),

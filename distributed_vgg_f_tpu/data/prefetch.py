@@ -16,7 +16,7 @@ The buffer is deliberately small (default 2): each slot holds a full on-device
 batch in HBM, and deeper queues add memory pressure without latency benefit.
 
 Resilience (train.data_timeout_s; resilience layer): the consumer side is
-also the **data watchdog**. A loader that stalls (hung NFS/GCS read, wedged
+also the **data watchdog**. A loader that stalls (hung NFS/GCS read, stuck
 decode worker, remote shard server gone) used to hang `next()` forever — the
 step loop just stopped, indistinguishable from slow compute. With a timeout
 configured, `__next__` waits `data_timeout_s`, then retries with exponential
@@ -444,7 +444,7 @@ class HostPrefetchIterator:
         # JOIN the worker BEFORE touching the source: closing the inner
         # loader while the worker is still inside next(source) would
         # destroy native decode state under a live call (use-after-free —
-        # observed as a wedged teardown in the bench's wire-rebuild hook)
+        # observed as a hung teardown in the bench's wire-rebuild hook)
         if self._thread.is_alive() \
                 and threading.current_thread() is not self._thread:
             self._thread.join(timeout=10)
@@ -456,7 +456,7 @@ class HostPrefetchIterator:
         telemetry.set_gauge("prefetch/host_queue_depth", 0)
         if self._thread.is_alive() \
                 and threading.current_thread() is not self._thread:
-            # join timed out: the worker is wedged INSIDE next(source)
+            # join timed out: the worker is stuck INSIDE next(source)
             # (hung storage read). Closing the source now would be the
             # exact use-after-free the join exists to prevent — leak the
             # handles instead (the daemon thread dies with the process)

@@ -44,16 +44,6 @@ def initialize_distributed(coordinator_address: str | None = None,
     if explicit:
         kwargs = dict(coordinator_address=coordinator_address,
                       num_processes=num_processes, process_id=process_id)
-    # jaxlib 0.4.x builds the CPU client WITHOUT a cross-process collectives
-    # layer unless told which one to use — multiprocess CPU compiles then
-    # fail with "Multiprocess computations aren't implemented on the CPU
-    # backend" (hit by the dryrun gloo phase and the two-process tests on
-    # this image). Newer jax defaults the option to gloo and eventually
-    # drops it, so set it best-effort; TPU clients ignore it.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # option gone (newer jax) — default
-        pass                              # is already gloo there
     try:
         jax.distributed.initialize(**kwargs)
     except RuntimeError as e:

@@ -35,12 +35,9 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# check_vma-kwarg-translating shim over jax.shard_map /
-# jax.experimental.shard_map (parallel/compat.py)
-from distributed_vgg_f_tpu.parallel.compat import axis_size, shard_map
 
 
 def ring_self_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

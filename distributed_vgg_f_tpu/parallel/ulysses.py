@@ -46,12 +46,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# check_vma-kwarg-translating shim over jax.shard_map /
-# jax.experimental.shard_map (parallel/compat.py)
-from distributed_vgg_f_tpu.parallel.compat import axis_size, shard_map
 
 from distributed_vgg_f_tpu.ops.flash_attention import flash_self_attention
 from distributed_vgg_f_tpu.parallel.ring_attention import (

@@ -275,7 +275,7 @@ class PredictServer:
         gauges, append a window to the flight ring, and heartbeat the
         process exporter (the serving heartbeat /healthz reads — ticked
         whether or not traffic arrives, so an idle server is healthy and a
-        wedged one goes 503)."""
+        stuck one goes 503)."""
         interval = max(0.01, float(self.cfg.controller_interval_s))
         while not self._closed.wait(interval):
             self._windows += 1

@@ -36,8 +36,7 @@ dequantized-constant GEMMs (XLA folds `wq * scale` once at compile), and
 activations are still rounded/clamped onto the int8 grid so the numerics
 are int8-faithful. XLA:CPU has no fast int8 GEMM kernel (measured ~6x
 SLOWER than f32 at batch 8 on this host — benchmarks/runs/host_r23
-protocol notes); the MXU int8 path is the queued device row
-(benchmarks/tpu_session_r18.sh tier grid).
+protocol notes); the MXU int8 path is not measured.
 """
 
 from __future__ import annotations

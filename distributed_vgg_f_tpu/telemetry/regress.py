@@ -6,7 +6,7 @@ hand-read tables).
 Three jobs, all over COMMITTED evidence:
 
 1. **Trajectory** (`build_trajectory`): parse every committed
-   `benchmarks/runs/host_r*/` decode artifact and repo-root `BENCH_r*.json`
+   `benchmarks/runs/host_r*/` decode artifact
    into one machine-readable file (`benchmarks/runs/trajectory.json`) — per
    round: the pinned constant, its provenance artifacts (the exact files the
    `HOST_DECODE_RATE_R*` docstrings cite), every other artifact in the round
@@ -381,9 +381,8 @@ SERVING_PINS: Tuple[Pin, ...] = (
         drift_note="host_r23/README.md: bf16 is EMULATED on XLA:CPU "
                    "(measured within noise of fp32 at equal architecture "
                    "— no MXU to cash the narrower dtype); the tier's "
-                   "latency claim is the queued MXU device row "
-                   "(tpu_session_r18.sh), this pin guards the CPU "
-                   "baseline only"),
+                   "latency claim needs an MXU and is not measured; "
+                   "this pin guards the CPU baseline only"),
     Pin("SERVING_RPS_R18_INT8", "r18", "benchmarks/runs/host_r23",
         ("serving_r18_tier_int8_run1.json",
          "serving_r18_tier_int8_run2.json"),
@@ -519,7 +518,7 @@ def _round_sort_key(dirname: str):
 
 
 def build_trajectory(repo: str) -> dict:
-    """Every committed host_r*/ artifact + BENCH_r*.json, one file. No
+    """Every committed host_r*/ artifact, one file. No
     timestamps on purpose: regeneration from the same tree is byte-stable,
     so `--check-committed` can diff the committed trajectory.json against a
     fresh build."""
@@ -567,18 +566,6 @@ def build_trajectory(repo: str) -> dict:
               "run_dir": d, "artifacts": entries}
              for d, entries in by_dir.items()
              if d not in pinned_dirs and entries]
-    device = []
-    for path in sorted(glob.glob(os.path.join(repo, "BENCH_r*.json"))):
-        obj = _read_json(path)
-        if not isinstance(obj, dict):
-            continue
-        parsed = obj.get("parsed") or {}
-        device.append({
-            "path": os.path.basename(path), "n": obj.get("n"),
-            "metric": parsed.get("metric"), "value": parsed.get("value"),
-            "error": parsed.get("error"),
-            "last_committed": parsed.get("last_committed"),
-        })
     return {"schema_version": schema.SCHEMA_VERSION,
             "kind": "perf_trajectory", "metric": HOST_METRIC,
             "serving_metric": SERVING_METRIC,
@@ -587,8 +574,7 @@ def build_trajectory(repo: str) -> dict:
                               "same-box bands — cross-session claims need "
                               "worktree controls (host_r7 README protocol)",
             "host_decode": rounds, "serving": serving_rounds,
-            "unpinned_rounds": extra,
-            "device": device}
+            "unpinned_rounds": extra}
 
 
 # ---------------------------------------------------------------------------

@@ -20,11 +20,8 @@ from typing import Any, Callable, Mapping, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-# check_vma-kwarg-translating shim over jax.shard_map /
-# jax.experimental.shard_map (parallel/compat.py)
-from distributed_vgg_f_tpu.parallel.compat import shard_map
 
 from distributed_vgg_f_tpu.ops.losses import l2_regularization, softmax_cross_entropy
 from distributed_vgg_f_tpu.ops.metrics import topk_correct
@@ -588,13 +585,16 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         out_specs=(state_specs, P()),
         check_vma=False,
     )
-    # State donation halves the step's peak param memory on accelerators.
-    # NOT on XLA:CPU: jaxlib 0.4.x reloads persistently-cached CPU
-    # executables with donation/aliasing metadata unsafely — re-running a
-    # cache-deserialized donating step after an Orbax restore corrupts the
-    # glibc heap ("corrupted double-linked list"; reproduced 5/5 with
-    # donation+cache, 0/5 with either removed — resilience PR). CPU runs
-    # are smoke/CI scale, where the memory win is irrelevant anyway.
+    # State donation halves the step's peak state memory on accelerators:
+    # callers must not touch a state after handing it to the step. NOT on
+    # XLA:CPU. An earlier jaxlib corrupted the glibc heap when a donating
+    # step loaded from the persistent compile cache ran after an Orbax
+    # restore. Re-checked under jax 0.9.0 (PR 21): 370 targeted test runs
+    # with donation on the CPU were clean, cold and warm cache, but the
+    # one whole tier-1 run with it lost a worker to a segfault in a native
+    # thread of an unrelated test — where heap corruption shows up. Not
+    # proven either way, so the CPU, where the memory is irrelevant, stays
+    # without donation.
     donate = () if jax.default_backend() == "cpu" else (0,)
     jitted = jax.jit(sharded, donate_argnums=donate)
 

@@ -1579,8 +1579,7 @@ class Trainer:
         finally:
             if self.autotuner is not None:
                 # swap the LIVE /autotunez provider for a plain-data final
-                # snapshot: the run's last controller state stays readable
-                # (and bench.py's last-good recording reads it after fit),
+                # snapshot: the run's last controller state stays readable,
                 # but the bound method no longer pins the closed pipeline
                 # object graph — and a later run can never be served this
                 # one's state as live

@@ -11,7 +11,7 @@ from distributed_vgg_f_tpu.config import ModelConfig
 from distributed_vgg_f_tpu.models import build_model
 from distributed_vgg_f_tpu.parallel.mesh import MeshSpec, build_mesh
 
-from distributed_vgg_f_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 def _param_count(params):

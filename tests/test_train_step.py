@@ -179,7 +179,7 @@ def test_dropout_differs_across_replicas(devices8):
     """Per-replica RNG folding (SURVEY.md §7): identical inputs on every replica
     must produce *different* dropout masks per replica."""
     from jax.sharding import Mesh
-    from distributed_vgg_f_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     from distributed_vgg_f_tpu.parallel.collectives import fold_rng_per_replica
 

@@ -14,9 +14,9 @@
 #
 # All three are stdlib-only static passes — no toolchain, no jax, no
 # native build — so the gate runs anywhere in ~seconds. Exercised on
-# every default test loop (tests/test_check_gate.py) and at the top of
-# the TPU session scripts (benchmarks/tpu_session_r12.sh): a session on
-# scarce hardware must not start on a tree that fails its own invariants.
+# every default test loop (tests/test_check_gate.py); run it before a chip
+# call too: scarce hardware must not start on a tree that fails its own
+# invariants.
 #
 # Exit: 0 all green; the first failing pass's exit code otherwise (every
 # pass still runs, so one invocation reports everything).
