@@ -331,18 +331,16 @@ class CheckpointManager:
             raise FileNotFoundError(f"no checkpoints under {self._dir}")
         # one measurement feeds both the span and the counter, so the two
         # views of the interval can never disagree (native_loader idiom)
-        t0 = time.monotonic_ns()
-        restored = self._mngr.restore(
-            step,
-            args=ocp.args.Composite(
-                state=ocp.args.StandardRestore(template),
-                extra=ocp.args.JsonRestore(),
-            ),
-        )
-        dt = time.monotonic_ns() - t0
-        telemetry.record("checkpoint_restore", "checkpoint", t0, dt)
+        with telemetry.span("checkpoint_restore", "checkpoint") as span:
+            restored = self._mngr.restore(
+                step,
+                args=ocp.args.Composite(
+                    state=ocp.args.StandardRestore(template),
+                    extra=ocp.args.JsonRestore(),
+                ),
+            )
         telemetry.inc("checkpoint/restores")
-        telemetry.inc("checkpoint/restore_ns", dt)
+        telemetry.inc("checkpoint/restore_ns", span.dur_ns)
         extra = restored.get("extra") or {}
         return restored["state"], extra
 
@@ -399,12 +397,10 @@ class CheckpointManager:
 
     def wait(self) -> None:
         """Block until pending async saves are durable (and manifested)."""
-        t0 = time.monotonic_ns()
-        self._mngr.wait_until_finished()
-        self._flush_manifests()
-        dt = time.monotonic_ns() - t0
-        telemetry.record("checkpoint_wait", "checkpoint", t0, dt)
-        telemetry.inc("checkpoint/wait_ns", dt)
+        with telemetry.span("checkpoint_wait", "checkpoint") as span:
+            self._mngr.wait_until_finished()
+            self._flush_manifests()
+        telemetry.inc("checkpoint/wait_ns", span.dur_ns)
 
     def close(self) -> None:
         self.wait()

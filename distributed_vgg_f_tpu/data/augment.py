@@ -223,16 +223,22 @@ def make_device_augment(aug_cfg, mean_rgb: Sequence[float],
         in_dtype = images.dtype
         x = images.astype(jnp.float32)
         k_flip, k_jit, k_rand, k_mix = jax.random.split(rng, 4)
+        # each stage under its own scope (distributed_vgg_f_tpu/scopes.py);
+        # the step wraps the whole call in `augment`
         if hflip:
-            x = _hflip(k_flip, x)
+            with jax.named_scope("flip"):
+                x = _hflip(k_flip, x)
         if jitter > 0:
-            x = _crop_jitter(k_jit, x, jitter)
+            with jax.named_scope("crop_jitter"):
+                x = _crop_jitter(k_jit, x, jitter)
         if rand_ops > 0:
-            x = _rand_ops(k_rand, x, mean, inv_std, rand_ops, magnitude)
+            with jax.named_scope("rand_ops"):
+                x = _rand_ops(k_rand, x, mean, inv_std, rand_ops, magnitude)
         mix_labels = mix_lam = None
         if mixup_alpha > 0 or cutmix_alpha > 0:
-            x, mix_labels, mix_lam = _mix(k_mix, x, labels,
-                                          mixup_alpha, cutmix_alpha)
+            with jax.named_scope("mix"):
+                x, mix_labels, mix_lam = _mix(k_mix, x, labels,
+                                              mixup_alpha, cutmix_alpha)
         x = x.astype(in_dtype)
         if pack and x.shape[1] % 4 == 0 and x.shape[2] % 4 == 0:
             x = space_to_depth_batch(x)

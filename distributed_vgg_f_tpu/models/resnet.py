@@ -17,6 +17,7 @@ import functools
 from typing import Any, Optional, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -110,7 +111,8 @@ class ResNet(nn.Module):
     def __call__(self, x: jnp.ndarray, *, train: bool = False) -> jnp.ndarray:
         from distributed_vgg_f_tpu.models.ingest import reject_raw_uint8
         reject_raw_uint8(x, "ResNet")  # u8-wire zoo contract
-        x = x.astype(self.compute_dtype)
+        with jax.named_scope("cast_in"):
+            x = x.astype(self.compute_dtype)
         x = StemConv(64, self.compute_dtype, stem=self.stem,
                      name="conv_init")(x)
         x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
@@ -119,7 +121,9 @@ class ResNet(nn.Module):
                          axis_name=self.bn_axis_name if train else None,
                          name="bn_init")(x)
         x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=[(1, 1), (1, 1)])
+        with jax.named_scope("pool_init"):
+            x = nn.max_pool(x, (3, 3), strides=(2, 2),
+                            padding=[(1, 1), (1, 1)])
         for stage, num_blocks in enumerate(self.stage_sizes):
             for block in range(num_blocks):
                 x = BottleneckBlock(
@@ -128,7 +132,8 @@ class ResNet(nn.Module):
                     compute_dtype=self.compute_dtype,
                     bn_axis_name=self.bn_axis_name,
                     name=f"stage{stage + 1}_block{block + 1}")(x, train=train)
-        x = jnp.mean(x, axis=(1, 2))
+        with jax.named_scope("gap"):
+            x = jnp.mean(x, axis=(1, 2))
         x = nn.Dense(self.num_classes, dtype=self.compute_dtype,
                      param_dtype=jnp.float32, name="head")(x)
         return x.astype(jnp.float32)

@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Any, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 
@@ -26,7 +27,8 @@ class VGG16(nn.Module):
     def __call__(self, x: jnp.ndarray, *, train: bool = False) -> jnp.ndarray:
         from distributed_vgg_f_tpu.models.ingest import reject_raw_uint8
         reject_raw_uint8(x, "VGG16")  # u8-wire zoo contract
-        x = x.astype(self.compute_dtype)
+        with jax.named_scope("cast_in"):
+            x = x.astype(self.compute_dtype)
         for b, (reps, feat) in enumerate(zip(self.block_sizes,
                                              self.block_features), start=1):
             for i in range(1, reps + 1):
@@ -34,7 +36,8 @@ class VGG16(nn.Module):
                             dtype=self.compute_dtype, param_dtype=jnp.float32,
                             name=f"conv{b}_{i}")(x)
                 x = nn.relu(x)
-            x = nn.max_pool(x, (2, 2), strides=(2, 2))
+            with jax.named_scope(f"pool{b}"):
+                x = nn.max_pool(x, (2, 2), strides=(2, 2))
         x = x.reshape((x.shape[0], -1))
         x = nn.relu(nn.Dense(4096, dtype=self.compute_dtype,
                              param_dtype=jnp.float32, name="fc6")(x))

@@ -25,6 +25,7 @@ from __future__ import annotations
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -114,16 +115,26 @@ class VGGF(nn.Module):
 
         from distributed_vgg_f_tpu.models.ingest import reject_raw_uint8
         reject_raw_uint8(x, "VGGF")  # u8-wire contract (r8; zoo-wide r13)
-        x = x.astype(self.compute_dtype)
+        # LRN and the pools are plain function calls, not modules: a scope
+        # (distributed_vgg_f_tpu/scopes.py) names their device time.
+        with jax.named_scope("cast_in"):
+            x = x.astype(self.compute_dtype)
         x = nn.relu(Conv1SpaceToDepth(self.stem_features, self.compute_dtype,
                                       name="conv1")(x))
-        x = _maxpool_3x3s2(lrn(x))
+        with jax.named_scope("lrn1"):
+            x = lrn(x)
+        with jax.named_scope("pool1"):
+            x = _maxpool_3x3s2(x)
         x = nn.relu(conv(self.conv_features, (5, 5), (1, 1), "SAME", "conv2")(x))
-        x = _maxpool_3x3s2(lrn(x))
+        with jax.named_scope("lrn2"):
+            x = lrn(x)
+        with jax.named_scope("pool2"):
+            x = _maxpool_3x3s2(x)
         x = nn.relu(conv(self.conv_features, (3, 3), (1, 1), "SAME", "conv3")(x))
         x = nn.relu(conv(self.conv_features, (3, 3), (1, 1), "SAME", "conv4")(x))
         x = nn.relu(conv(self.conv_features, (3, 3), (1, 1), "SAME", "conv5")(x))
-        x = _maxpool_3x3s2(x)
+        with jax.named_scope("pool5"):
+            x = _maxpool_3x3s2(x)
 
         x = x.reshape((x.shape[0], -1))
         x = nn.relu(dense(self.fc_features, "fc6")(x))

@@ -236,7 +236,8 @@ class ViT(nn.Module):
         from distributed_vgg_f_tpu.models.ingest import reject_raw_uint8
         reject_raw_uint8(x, "ViT")  # u8-wire zoo contract
         B = x.shape[0]
-        x = x.astype(self.compute_dtype)
+        with jax.named_scope("cast_in"):
+            x = x.astype(self.compute_dtype)
         # patch embedding as a strided conv → (B, H/p, W/p, D), then flatten
         x = nn.Conv(self.hidden_dim,
                     (self.patch_size, self.patch_size),
@@ -247,13 +248,14 @@ class ViT(nn.Module):
 
         cls_tok = self.param("cls", nn.initializers.zeros,
                              (1, 1, self.hidden_dim), jnp.float32)
-        x = jnp.concatenate(
-            [jnp.broadcast_to(cls_tok.astype(self.compute_dtype),
-                              (B, 1, self.hidden_dim)), x], axis=1)
-        pos = self.param("pos_embed",
-                         nn.initializers.normal(stddev=0.02),
-                         (1, x.shape[1], self.hidden_dim), jnp.float32)
-        x = x + pos.astype(self.compute_dtype)
+        with jax.named_scope("embed_tokens"):
+            x = jnp.concatenate(
+                [jnp.broadcast_to(cls_tok.astype(self.compute_dtype),
+                                  (B, 1, self.hidden_dim)), x], axis=1)
+            pos = self.param("pos_embed",
+                             nn.initializers.normal(stddev=0.02),
+                             (1, x.shape[1], self.hidden_dim), jnp.float32)
+            x = x + pos.astype(self.compute_dtype)
         x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
 
         for i in range(self.depth):

@@ -1,0 +1,27 @@
+"""The names the program gives its own work on the device.
+
+Each name is a `jax.named_scope` at the place where the work happens.
+Scopes are op metadata: they add no equation and change no compiled
+instruction, but XLA carries them to every instruction's `op_name`, and a
+profiler trace of the TPU stores that beside each operation. So device time
+can be read by these names (`chipbench/scope_reduce.py`), and the names
+survive a change to the program where XLA's own (`%fusion.512`) do not.
+Forward and backward need no name: JAX wraps them as `jvp(...)` and
+`transpose(jvp(...))`. Flax modules (`conv2`, `stage1_block1/bn1`) name
+themselves.
+
+The persistent compile cache's key leaves metadata out, so a change to
+names alone loads the old executable, with the old names, from a cache an
+earlier build has filled. Rename the jitted function (`train_step`,
+`eval_step`: the module's name is in the key) or clear the cache when only
+names change. `tests/test_scopes.py` holds every call site to these lists.
+"""
+
+#: Phases of the train step (train/step.py, data/augment.py) and the
+#: model's input cast.
+PHASES = ("finish_u8", "augment", "flip", "crop_jitter", "rand_ops", "mix",
+          "cast_in", "loss", "exchange", "optimizer", "step_metrics")
+
+#: Layers that are plain function calls in a model, not flax modules.
+LAYERS = ("lrn1", "lrn2", "pool1", "pool2", "pool3", "pool4", "pool5",
+          "pool_init", "gap", "embed_tokens")

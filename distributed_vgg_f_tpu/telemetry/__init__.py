@@ -38,7 +38,7 @@ tests/test_telemetry.py pins this in a subprocess.
 from __future__ import annotations
 
 import time
-from typing import Iterator, Optional
+from typing import Callable, Iterator, Optional
 
 from distributed_vgg_f_tpu.telemetry import schema  # noqa: F401 (re-export)
 from distributed_vgg_f_tpu.telemetry.registry import (
@@ -74,11 +74,16 @@ __all__ = [
 
 def configure(*, enabled: Optional[bool] = None,
               span_capacity: Optional[int] = None,
-              flight_windows: Optional[int] = None) -> None:
+              flight_windows: Optional[int] = None,
+              annotate: Optional[Callable] = None) -> None:
     """Flip the process-wide default recorder+registry from config
     (TelemetryConfig → Trainer.__init__). `enabled=False` is the
     kill-switch the overhead receipt measures against: record/inc become
-    attribute-check-and-return."""
+    attribute-check-and-return. `annotate` (the trainer hands in
+    `jax.profiler.TraceAnnotation`) puts every `span(...)` on the
+    profiler's timeline too, as `dvggf:<category>:<name>` (spans.py)."""
+    if annotate is not None:
+        get_recorder().annotate = annotate
     if enabled is not None:
         get_recorder().enabled = bool(enabled)
         get_registry().enabled = bool(enabled)

@@ -17,8 +17,17 @@ prefetch worker's own source draw / H2D), "checkpoint" (save/restore/wait),
 "dispatch" (host dispatch of the jitted step), "coord" (cross-process
 barriers), "eval", "host" (everything else).
 
+A span can also ride a profiler's timeline: while the recorder is enabled
+and holds an `annotate` hook, every `span(...)` opens the hook's context
+manager under the name `dvggf:<category>:<name>`. The trainer installs
+`jax.profiler.TraceAnnotation` there (`telemetry.configure(annotate=...)`),
+so a `jax.profiler` capture shows the program's own spans on the clock of
+the device's operations and an idle gap of the device can be put down to
+`infeed`, `dispatch` or `checkpoint`. With no capture running the hook
+costs one activity check a span.
+
 No numpy, no jax, no TF — importing this package must stay free of heavy
-deps (tests/test_telemetry.py pins that).
+deps (tests/test_telemetry.py pins that); the hook is handed in.
 """
 
 from __future__ import annotations
@@ -28,7 +37,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import List, Optional, Tuple
+from typing import Callable, ContextManager, List, Optional, Tuple
 
 #: (name, category, start_ns, dur_ns, tid[, args]) — plain tuples, not
 #: objects: recording must cost nanoseconds, not an allocation-heavy
@@ -39,10 +48,16 @@ from typing import List, Optional, Tuple
 SpanTuple = Tuple[str, str, int, int, int]
 
 
-class _Span:
-    """Reusable context manager handed out by `SpanRecorder.span`."""
+#: Prefix of the names spans carry on a profiler's timeline.
+ANNOTATION_PREFIX = "dvggf:"
 
-    __slots__ = ("_rec", "_name", "_cat", "_t0")
+
+class _Span:
+    """Context manager handed out by `SpanRecorder.span`. After exit,
+    `dur_ns` is the measured duration (for call sites that also feed a
+    counter from the same measurement)."""
+
+    __slots__ = ("_rec", "_name", "_cat", "_t0", "_annotation", "dur_ns")
 
     def __init__(self, rec: "SpanRecorder", name: str, category: str):
         self._rec = rec
@@ -50,12 +65,20 @@ class _Span:
         self._cat = category
 
     def __enter__(self) -> "_Span":
+        rec = self._rec
+        self._annotation = None
+        if rec.enabled and rec.annotate is not None:
+            self._annotation = rec.annotate(
+                f"{ANNOTATION_PREFIX}{self._cat}:{self._name}")
+            self._annotation.__enter__()
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._rec.record(self._name, self._cat, self._t0,
-                         time.monotonic_ns() - self._t0)
+        self.dur_ns = time.monotonic_ns() - self._t0
+        self._rec.record(self._name, self._cat, self._t0, self.dur_ns)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -77,6 +100,9 @@ class SpanRecorder:
         self._lock = threading.Lock()
         self._dropped = 0
         self._recorded = 0
+        #: `name -> context manager` opened around every `span(...)` while
+        #: enabled (module docstring); None = spans stay on this clock only.
+        self.annotate: Optional[Callable[[str], ContextManager]] = None
 
     # ------------------------------------------------------------- recording
     def record(self, name: str, category: str, start_ns: int,

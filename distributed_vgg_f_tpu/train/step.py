@@ -14,7 +14,6 @@ batches and reads metrics (BASELINE.json north_star).
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, Callable, Mapping, Tuple
 
 import jax
@@ -234,7 +233,11 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     wire_dtype = (None if reduce_dtype in ("float32", None)
                   else jnp.dtype(reduce_dtype))
 
-    def step_fn(state: TrainState, batch: Batch, base_rng: jax.Array):
+    # Named `train_step` and not `step_fn`: the jitted module's name
+    # (`jit_train_step`) is what a trace's `XLA Modules` line shows, and it
+    # is part of the persistent compile cache's key where the scopes below
+    # are not (distributed_vgg_f_tpu/scopes.py).
+    def train_step(state: TrainState, batch: Batch, base_rng: jax.Array):
         images, labels = batch["image"], batch["label"]
         if device_finish is not None:
             # u8-wire finish (data/device_ingest.py): normalize + cast +
@@ -242,7 +245,8 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # elementwise math into the step. Dispatches on dtype — float
             # (host-normalized) batches pass through untouched, so the
             # prologue is safe to install for every wire.
-            images = device_finish(images)
+            with jax.named_scope("finish_u8"):
+                images = device_finish(images)
         rng = jax.random.fold_in(base_rng, state.step)
         rng = fold_rng_per_replica(rng, data_axis)
         # Fused on-device augmentation (r13, data/augment.py): flip/jitter/
@@ -256,8 +260,9 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         mix_labels = mix_lam = None
         if device_augment is not None:
             from distributed_vgg_f_tpu.data.augment import AUGMENT_RNG_FOLD
-            images, mix_labels, mix_lam = device_augment(
-                jax.random.fold_in(rng, AUGMENT_RNG_FOLD), images, labels)
+            with jax.named_scope("augment"):
+                images, mix_labels, mix_lam = device_augment(
+                    jax.random.fold_in(rng, AUGMENT_RNG_FOLD), images, labels)
 
         def make_loss_fn(images, labels, mix_labels, batch_stats,
                          dropout_rng):
@@ -265,26 +270,29 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 logits, new_batch_stats = _apply_model(
                     model, params, batch_stats, images, train=True,
                     dropout_rng=dropout_rng)
-                if mix_labels is not None:
-                    # mixup/cutmix with INTEGER labels: the mixed target is
-                    # a two-point distribution, so its CE decomposes as the
-                    # lam-weighted sum of the two integer-label CEs — no
-                    # one-hot materialization.
-                    ce = mix_lam * softmax_cross_entropy(logits, labels) \
-                        + (1.0 - mix_lam) * softmax_cross_entropy(
-                            logits, mix_labels)
-                else:
-                    ce = softmax_cross_entropy(logits, labels)
-                l2 = l2_regularization(params, weight_decay)
-                loss = ce + l2
-                n = jnp.asarray(labels.shape[0], jnp.float32)
-                metrics = {
-                    "loss": ce,
-                    # top1 scores against the PRIMARY labels (the standard
-                    # mixup-training convention; eval is unaugmented anyway)
-                    "l2_loss": l2,
-                    "top1": topk_correct(logits, labels, 1).astype(jnp.float32) / n,
-                }
+                with jax.named_scope("loss"):
+                    if mix_labels is not None:
+                        # mixup/cutmix with INTEGER labels: the mixed target
+                        # is a two-point distribution, so its CE decomposes
+                        # as the lam-weighted sum of the two integer-label
+                        # CEs — no one-hot materialization.
+                        ce = mix_lam * softmax_cross_entropy(logits, labels) \
+                            + (1.0 - mix_lam) * softmax_cross_entropy(
+                                logits, mix_labels)
+                    else:
+                        ce = softmax_cross_entropy(logits, labels)
+                    l2 = l2_regularization(params, weight_decay)
+                    loss = ce + l2
+                    n = jnp.asarray(labels.shape[0], jnp.float32)
+                    metrics = {
+                        "loss": ce,
+                        # top1 scores against the PRIMARY labels (the standard
+                        # mixup-training convention; eval is unaugmented
+                        # anyway)
+                        "l2_loss": l2,
+                        "top1": topk_correct(logits, labels,
+                                             1).astype(jnp.float32) / n,
+                    }
                 return loss, (new_batch_stats, metrics)
             return loss_fn
 
@@ -354,6 +362,7 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 comm_meta["wire_bytes"] = (comm_meta["scatter_bytes"]
                                            + comm_meta["gather_bytes"])
 
+        @jax.named_scope("exchange")
         def scatter_mean_shard(g_tree):
             """Ravel + pad + [SYNC] reduce-scatter one gradient pytree to
             this replica's fp32 mean 1/N flat shard — PER BUCKET when the
@@ -386,18 +395,19 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         # transient; at a fp32 wire it is bit-identical to the ZeRO-2
         # replicated params (the equality-grid pin).
         if shard_params:
-            if bucket_layout is not None:
-                full_params = bucket_layout.gather_param_tree(
-                    state.params, data_axis, wire_dtype=wire_dtype)
-            else:
-                from distributed_vgg_f_tpu.parallel.collectives import (
-                    cast_from_wire, cast_to_wire)
-                from distributed_vgg_f_tpu.parallel.zero import (
-                    _unflatten_like)
-                full = cast_from_wire(jax.lax.all_gather(
-                    cast_to_wire(state.params, wire_dtype), data_axis,
-                    tiled=True), jnp.float32)
-                full_params = _unflatten_like(full[:n_elem], params_struct)
+            from distributed_vgg_f_tpu.parallel.collectives import (
+                cast_from_wire, cast_to_wire)
+            from distributed_vgg_f_tpu.parallel.zero import _unflatten_like
+            with jax.named_scope("exchange"):
+                if bucket_layout is not None:
+                    full_params = bucket_layout.gather_param_tree(
+                        state.params, data_axis, wire_dtype=wire_dtype)
+                else:
+                    full = cast_from_wire(jax.lax.all_gather(
+                        cast_to_wire(state.params, wire_dtype), data_axis,
+                        tiled=True), jnp.float32)
+                    full_params = _unflatten_like(full[:n_elem],
+                                                  params_struct)
         else:
             full_params = state.params
 
@@ -458,7 +468,8 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             (_, (new_batch_stats, metrics)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(full_params)
             accum_grad_shard = None
-        metrics = cross_replica_mean(metrics, data_axis)
+        with jax.named_scope("step_metrics"):
+            metrics = cross_replica_mean(metrics, data_axis)
 
         if zero1:
             if accum_grad_shard is not None:
@@ -469,68 +480,78 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 # reduce-scatter half of the all-reduce: each replica owns
                 # the mean gradient for its contiguous 1/N flat shard.
                 grad_shard = scatter_mean_shard(grads)
-            grad_norm = jnp.sqrt(jax.lax.psum(
-                jnp.sum(jnp.square(grad_shard)), data_axis))
-            if grad_clip_norm > 0:
-                grad_shard = _clip_by_global_norm(grad_shard, grad_norm,
-                                                  grad_clip_norm)
+            with jax.named_scope("optimizer"):
+                grad_norm = jnp.sqrt(jax.lax.psum(
+                    jnp.sum(jnp.square(grad_shard)), data_axis))
+                if grad_clip_norm > 0:
+                    grad_shard = _clip_by_global_norm(grad_shard, grad_norm,
+                                                      grad_clip_norm)
 
-            if shard_params:
-                # ZeRO-3: the resident (S,) flat shard IS the optimizer's
-                # parameter frame — no slicing out of a replicated tree, and
-                # (below) no trailing re-sync gather: the NEXT step's
-                # just-in-time gather reconstitutes the tree from exactly
-                # what the ZeRO-2 step would have stored.
-                param_shard = state.params
-                unravel = None
-            elif bucket_layout is not None:
-                # bucket-major flat frame (parallel/buckets.py): the param
-                # shard, the opt-state vectors, and the gathered update all
-                # live in GradBucketLayout's replica-interleaved layout
-                param_shard = bucket_layout.local_param_shard(
-                    state.params, data_axis)
-            else:
-                flat_params, unravel = ravel_pytree(state.params)
-                offset = jax.lax.axis_index(data_axis) * shard_size
-                param_shard = jax.lax.dynamic_slice_in_dim(
-                    jnp.pad(flat_params, (0, padded - n_elem)), offset,
-                    shard_size)
-            updates_shard, new_opt_state = tx.update(
-                grad_shard, state.opt_state, param_shard)
-            new_param_shard = optax.apply_updates(param_shard, updates_shard)
+                if shard_params:
+                    # ZeRO-3: the resident (S,) flat shard IS the optimizer's
+                    # parameter frame — no slicing out of a replicated tree,
+                    # and (below) no trailing re-sync gather: the NEXT step's
+                    # just-in-time gather reconstitutes the tree from exactly
+                    # what the ZeRO-2 step would have stored.
+                    param_shard = state.params
+                    unravel = None
+                elif bucket_layout is not None:
+                    # bucket-major flat frame (parallel/buckets.py): the
+                    # param shard, the opt-state vectors, and the gathered
+                    # update all live in GradBucketLayout's
+                    # replica-interleaved layout
+                    param_shard = bucket_layout.local_param_shard(
+                        state.params, data_axis)
+                else:
+                    flat_params, unravel = ravel_pytree(state.params)
+                    offset = jax.lax.axis_index(data_axis) * shard_size
+                    param_shard = jax.lax.dynamic_slice_in_dim(
+                        jnp.pad(flat_params, (0, padded - n_elem)), offset,
+                        shard_size)
+                updates_shard, new_opt_state = tx.update(
+                    grad_shard, state.opt_state, param_shard)
+                new_param_shard = optax.apply_updates(param_shard,
+                                                      updates_shard)
             if shard_params:
                 # ZeRO-3 persists the shard itself — params stay O(1/N).
                 new_params = new_param_shard
-            # [SYNC] all-gather half: replicas re-sync the updated parameters.
-            elif bucket_layout is not None:
-                new_params = bucket_layout.gather_params(new_param_shard,
-                                                         data_axis)
             else:
-                new_flat = jax.lax.all_gather(
-                    new_param_shard, data_axis, tiled=True)
-                new_params = unravel(new_flat[:n_elem])
+                # [SYNC] all-gather half: replicas re-sync the updated
+                # parameters.
+                with jax.named_scope("exchange"):
+                    if bucket_layout is not None:
+                        new_params = bucket_layout.gather_params(
+                            new_param_shard, data_axis)
+                    else:
+                        new_flat = jax.lax.all_gather(
+                            new_param_shard, data_axis, tiled=True)
+                        new_params = unravel(new_flat[:n_elem])
             metrics["grad_norm"] = grad_norm
         else:
             # [SYNC] — the one cross-replica point per step (reference: NCCL/MPI
             # ring all-reduce; here: XLA ICI all-reduce emitted from pmean).
             # Bucketed: one pmean per size-targeted bucket instead of one
             # per leaf — same elementwise math, ICI-friendly message sizes.
-            if bucket_layout is not None:
-                grads = bucket_layout.pmean_buckets(grads, data_axis,
-                                                    wire_dtype=wire_dtype)
-            else:
-                grads = all_reduce_gradients(grads, data_axis,
-                                             reduce_dtype=wire_dtype)
-            grad_norm = optax.global_norm(grads)
-            if grad_clip_norm > 0:
-                grads = _clip_by_global_norm(grads, grad_norm, grad_clip_norm)
-            updates, new_opt_state = tx.update(grads, state.opt_state,
-                                               state.params)
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("exchange"):
+                if bucket_layout is not None:
+                    grads = bucket_layout.pmean_buckets(
+                        grads, data_axis, wire_dtype=wire_dtype)
+                else:
+                    grads = all_reduce_gradients(grads, data_axis,
+                                                 reduce_dtype=wire_dtype)
+            with jax.named_scope("optimizer"):
+                grad_norm = optax.global_norm(grads)
+                if grad_clip_norm > 0:
+                    grads = _clip_by_global_norm(grads, grad_norm,
+                                                 grad_clip_norm)
+                updates, new_opt_state = tx.update(grads, state.opt_state,
+                                                   state.params)
+                new_params = optax.apply_updates(state.params, updates)
             metrics["grad_norm"] = grad_norm
 
         if schedule is not None:
-            metrics["lr"] = schedule(state.step)
+            with jax.named_scope("step_metrics"):
+                metrics["lr"] = schedule(state.step)
 
         # Parameter EMA (train.ema_decay): stored like params — replicated
         # tree under DP/ZeRO-1/2 (it tracks the post-all-gather params);
@@ -543,9 +564,10 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         new_ema_bs = state.ema_batch_stats
         if ema_decay > 0.0:
             avg = lambda e, p: e * ema_decay + (1.0 - ema_decay) * p
-            new_ema = jax.tree.map(avg, state.ema_params, new_params)
-            new_ema_bs = jax.tree.map(avg, state.ema_batch_stats,
-                                      new_batch_stats)
+            with jax.named_scope("optimizer"):
+                new_ema = jax.tree.map(avg, state.ema_params, new_params)
+                new_ema_bs = jax.tree.map(avg, state.ema_batch_stats,
+                                          new_batch_stats)
 
         if skip_nonfinite:
             # Non-finite step guard: metrics["loss"]/["l2_loss"] are the
@@ -559,18 +581,19 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # internal schedule count, so skips don't consume warmup/decay
             # (see the build_train_step docstring for the metrics["lr"]
             # consequence).
-            ok = jnp.logical_and(
-                jnp.isfinite(metrics["loss"] + metrics["l2_loss"]),
-                jnp.isfinite(metrics["grad_norm"]))
-            keep = lambda new, old: jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new, old)
-            new_params = keep(new_params, state.params)
-            new_opt_state = keep(new_opt_state, state.opt_state)
-            new_batch_stats = keep(new_batch_stats, state.batch_stats)
-            if ema_decay > 0.0:
-                new_ema = keep(new_ema, state.ema_params)
-                new_ema_bs = keep(new_ema_bs, state.ema_batch_stats)
-            metrics["bad_step"] = 1.0 - ok.astype(jnp.float32)
+            with jax.named_scope("step_metrics"):
+                ok = jnp.logical_and(
+                    jnp.isfinite(metrics["loss"] + metrics["l2_loss"]),
+                    jnp.isfinite(metrics["grad_norm"]))
+                keep = lambda new, old: jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o), new, old)
+                new_params = keep(new_params, state.params)
+                new_opt_state = keep(new_opt_state, state.opt_state)
+                new_batch_stats = keep(new_batch_stats, state.batch_stats)
+                if ema_decay > 0.0:
+                    new_ema = keep(new_ema, state.ema_params)
+                    new_ema_bs = keep(new_ema_bs, state.ema_batch_stats)
+                metrics["bad_step"] = 1.0 - ok.astype(jnp.float32)
 
         new_state = state.replace(step=state.step + 1, params=new_params,
                                   batch_stats=new_batch_stats,
@@ -580,7 +603,7 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         return new_state, metrics
 
     sharded = shard_map(
-        step_fn, mesh=mesh,
+        train_step, mesh=mesh,
         in_specs=(state_specs, P(data_axis), P()),
         out_specs=(state_specs, P()),
         check_vma=False,
@@ -607,14 +630,12 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     from distributed_vgg_f_tpu import telemetry
 
     @functools.wraps(jitted)
-    def train_step(state, batch, rng):
+    def dispatch(state, batch, rng):
         rec = telemetry.get_recorder()
         if not rec.enabled:
             return jitted(state, batch, rng)
-        t0 = time.monotonic_ns()
-        out = jitted(state, batch, rng)
-        rec.record("train_step_dispatch", "dispatch", t0,
-                   time.monotonic_ns() - t0)
+        with rec.span("train_step_dispatch", "dispatch"):
+            out = jitted(state, batch, rng)
         telemetry.inc("step/dispatched")
         # comm/* receipts (ISSUE 11): per-step exchange counters + the
         # static exchange-shape gauges, single-sourced from the geometry
@@ -635,11 +656,11 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             reg.set_gauge("comm/bucket_mb", comm_meta["bucket_mb"])
         return out
 
-    train_step.lower = jitted.lower
+    dispatch.lower = jitted.lower
     # the static exchange receipt (trainer JSONL `comm` block, bench rows);
     # empty until the first trace fills it
-    train_step.comm_meta = comm_meta
-    return train_step
+    dispatch.comm_meta = comm_meta
+    return dispatch
 
 
 def build_eval_step(model, mesh: Mesh, data_axis: str = "data",
@@ -660,7 +681,7 @@ def build_eval_step(model, mesh: Mesh, data_axis: str = "data",
     if state_specs is None:
         state_specs = P()
 
-    def step_fn(state: TrainState, batch: Batch):
+    def eval_step(state: TrainState, batch: Batch):
         images, labels = batch["image"], batch["label"]
         if device_finish is not None:
             # SAME prologue as the train step (single-normalization
@@ -668,24 +689,30 @@ def build_eval_step(model, mesh: Mesh, data_axis: str = "data",
             # through untouched; a uint8 batch fed here is finished exactly
             # once — the host/device double-normalize hazard is
             # structurally impossible (tests/test_wire_u8.py).
-            images = device_finish(images)
+            with jax.named_scope("finish_u8"):
+                images = device_finish(images)
         # Exact eval (data/eval_pad.py): a "valid" mask marks padding rows in
         # the final partial batch; they contribute to neither hits nor count.
         valid = batch.get("valid")
-        params = (param_gather(state.params) if param_gather is not None
-                  else state.params)
+        if param_gather is not None:
+            with jax.named_scope("exchange"):
+                params = param_gather(state.params)
+        else:
+            params = state.params
         logits, _ = _apply_model(model, params, state.batch_stats, images,
                                  train=False)
-        k5 = min(5, logits.shape[-1])
-        counts = {
-            "top1": topk_correct(logits, labels, 1, valid),
-            "top5": topk_correct(logits, labels, k5, valid),
-            "count": (jnp.sum(valid.astype(jnp.int32)) if valid is not None
-                      else jnp.asarray(labels.shape[0], jnp.int32)),
-        }
-        return cross_replica_sum(counts, data_axis)
+        with jax.named_scope("step_metrics"):
+            k5 = min(5, logits.shape[-1])
+            counts = {
+                "top1": topk_correct(logits, labels, 1, valid),
+                "top5": topk_correct(logits, labels, k5, valid),
+                "count": (jnp.sum(valid.astype(jnp.int32))
+                          if valid is not None
+                          else jnp.asarray(labels.shape[0], jnp.int32)),
+            }
+            return cross_replica_sum(counts, data_axis)
 
-    sharded = shard_map(step_fn, mesh=mesh,
+    sharded = shard_map(eval_step, mesh=mesh,
                         in_specs=(state_specs, P(data_axis)),
                         out_specs=P(),
                         check_vma=False)

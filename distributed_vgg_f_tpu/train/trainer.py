@@ -85,7 +85,8 @@ class Trainer:
         # defaults this flips.
         telemetry.configure(enabled=cfg.telemetry.enabled,
                             span_capacity=cfg.telemetry.span_capacity,
-                            flight_windows=cfg.telemetry.flight_windows)
+                            flight_windows=cfg.telemetry.flight_windows,
+                            annotate=jax.profiler.TraceAnnotation)
         if cfg.data.space_to_depth and not supports_space_to_depth(
                 cfg.model.name, cfg.data.image_size, cfg.data.name):
             # the packed layout is the VGG-F stem's input contract
@@ -1181,16 +1182,14 @@ class Trainer:
                         # device_get drains the async dispatch queue so the trace
                         # window brackets device execution, not host dispatch.
                         profiler.step(step, sync=lambda: jax.device_get(state.step))
-                    t_feed = time.monotonic_ns()
-                    batch = next(ds)  # already sharded on-device by the prefetcher
-                    dt_feed = time.monotonic_ns() - t_feed
-                    host_wait += dt_feed / 1e9
                     # "infeed" span: consumer-side block. Overlaps the prefetch
                     # iterator's own wait span — same category, and the span
                     # occupancy union (telemetry/stall.py) dedupes overlaps, so
                     # the sync fallback path is covered without double-counting
                     # the threaded one.
-                    rec.record("next_batch", "infeed", t_feed, dt_feed)
+                    with rec.span("next_batch", "infeed") as feed:
+                        batch = next(ds)  # already sharded on-device by the prefetcher
+                    host_wait += feed.dur_ns / 1e9
                     state, metrics = self.train_step(state, batch, rng)
                     if elastic_t0 is not None:
                         # the resize is OVER only when the first survivor-mesh

@@ -48,10 +48,3 @@ class StepProfiler:
             jax.profiler.stop_trace()
             self._active = False
             self.captured = True
-
-
-def annotate(name: str):
-    """Named host-side region, visible on the trace timeline
-    (`jax.profiler.TraceAnnotation`). Use around host work (input feed,
-    checkpoint save) to attribute host-device gaps."""
-    return jax.profiler.TraceAnnotation(name)

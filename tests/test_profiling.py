@@ -10,7 +10,7 @@ from distributed_vgg_f_tpu.config import (
     TrainConfig)
 from distributed_vgg_f_tpu.train.trainer import Trainer
 from distributed_vgg_f_tpu.utils.logging import MetricLogger
-from distributed_vgg_f_tpu.utils.profiling import StepProfiler, annotate
+from distributed_vgg_f_tpu.utils.profiling import StepProfiler
 
 
 def test_step_profiler_window(tmp_path, monkeypatch):
@@ -58,8 +58,37 @@ def test_trainer_fit_captures_real_trace(tmp_path):
     traces = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
                        recursive=True)
     assert traces, f"no trace files under {logdir}"
+    # the program's own spans ride the profiler's clock (telemetry's
+    # bridge): the host plane holds them beside the runtime's events
+    from chipbench import scope_reduce
+    host = {e["name"] for e in scope_reduce.load(traces[-1])["spans"]}
+    assert "dvggf:infeed:next_batch" in host
+    assert "dvggf:dispatch:train_step_dispatch" in host
 
 
-def test_annotate_is_usable_inline():
-    with annotate("host-feed"):
+def test_spans_open_the_profiler_hook_only_while_enabled():
+    """The bridge itself, with a fake in the hook's place: a span opens it
+    under `dvggf:<category>:<name>` and closes it, a disabled recorder
+    opens nothing, and `record(...)` after the fact never does."""
+    import contextlib
+
+    from distributed_vgg_f_tpu.telemetry.spans import SpanRecorder
+    seen = []
+
+    @contextlib.contextmanager
+    def fake(name):
+        seen.append(("open", name))
+        yield
+        seen.append(("close", name))
+
+    rec = SpanRecorder()
+    rec.annotate = fake
+    with rec.span("next_batch", "infeed") as span:
+        assert seen == [("open", "dvggf:infeed:next_batch")]
+    assert seen[-1] == ("close", "dvggf:infeed:next_batch")
+    assert span.dur_ns >= 0 and rec.recorded == 1
+    rec.record("late", "host", 0, 1)
+    rec.enabled = False
+    with rec.span("next_batch", "infeed"):
         pass
+    assert len(seen) == 2

@@ -1,0 +1,142 @@
+"""The names the program gives its device work (distributed_vgg_f_tpu/
+scopes.py): every declared name has a `jax.named_scope` call site and
+reaches the lowered program's debug info, in the forward or the backward
+pass as it should; nothing outside the lists is named; and the two jitted
+steps are called what they are."""
+
+import io
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from distributed_vgg_f_tpu import scopes
+from distributed_vgg_f_tpu.config import apply_overrides, get_config
+from distributed_vgg_f_tpu.parallel.mesh import MeshSpec, build_mesh
+from distributed_vgg_f_tpu.train.trainer import Trainer
+from distributed_vgg_f_tpu.utils.logging import MetricLogger
+
+PACKAGE = os.path.dirname(scopes.__file__)
+SIZE, ROWS = 32, 8
+
+
+def _lowered_steps(preset: str, chips: int, **overrides):
+    """(train step, eval step) of a shrunk preset as lowered text with
+    debug info; nothing is compiled."""
+    cfg = apply_overrides(get_config(preset), {
+        "data.image_size": SIZE, "model.num_classes": 10,
+        "data.global_batch_size": ROWS, "mesh.num_data": chips, **overrides})
+    mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (chips,)),
+                      jax.devices()[:chips])
+    trainer = Trainer(cfg, mesh=mesh, logger=MetricLogger(stream=io.StringIO()))
+    state = jax.eval_shape(trainer.init_state)
+    batch = {"image": jax.ShapeDtypeStruct((ROWS, SIZE, SIZE, 3), jnp.uint8),
+             "label": jax.ShapeDtypeStruct((ROWS,), jnp.int32)}
+    train = trainer.train_step.lower(state, batch, trainer.base_rng())
+    evaluate = trainer.eval_step.lower(state, batch)
+    return (train.as_text(debug_info=True), evaluate.as_text(debug_info=True))
+
+
+def _lowered_forward(name: str, **extra) -> str:
+    from distributed_vgg_f_tpu.config import ModelConfig
+    from distributed_vgg_f_tpu.models.registry import build_model
+    model = build_model(ModelConfig(name=name, num_classes=10, extra=extra))
+    x = jax.ShapeDtypeStruct((2, SIZE, SIZE, 3), jnp.float32)
+    variables = jax.eval_shape(model.init, jax.random.key(0), x)
+    return jax.jit(model.apply).lower(variables, x).as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """Two devices for VGG-F, so that its ZeRO-2 flags stand and the
+    exchange has something to exchange; every stage of the augmentation
+    switched on."""
+    vggf, vggf_eval = _lowered_steps(
+        "vggf_imagenet_dp", 2, **{"data.augment.crop_jitter": 2,
+                                  "data.augment.rand_ops": 1})
+    resnet, _ = _lowered_steps("resnet50_imagenet", 1,
+                               **{"model.extra": {"stage_sizes": (1, 1, 1, 1)}})
+    return {"vggf": vggf, "vggf_eval": vggf_eval, "resnet50": resnet,
+            "vgg16": _lowered_forward("vgg16"),
+            "vit_s16": _lowered_forward("vit_s16", depth=1)}
+
+
+def _stacks(text: str) -> set:
+    """Every name stack of a lowered text, less its primitive."""
+    return {loc.rpartition("/")[0]
+            for loc in re.findall(r'loc\("([^"]*)"', text)}
+
+
+def _holds(stacks: set, name: str) -> bool:
+    """`name` is one scope of some stack, bare or inside `jvp(...)`."""
+    scope = re.compile(rf"(^|[/(]){re.escape(name)}($|[/)])")
+    return any(scope.search(stack) for stack in stacks)
+
+
+#: where each declared name has to arrive
+HOME = {**{name: "vggf" for name in scopes.PHASES},
+        "lrn1": "vggf", "lrn2": "vggf", "pool1": "vggf", "pool2": "vggf",
+        "pool5": "vggf", "pool3": "vgg16", "pool4": "vgg16",
+        "pool_init": "resnet50", "gap": "resnet50",
+        "embed_tokens": "vit_s16"}
+
+
+def test_every_declared_name_has_a_home():
+    assert set(HOME) == set(scopes.PHASES) | set(scopes.LAYERS)
+    assert not set(scopes.PHASES) & set(scopes.LAYERS)
+
+
+@pytest.mark.parametrize("name", scopes.PHASES + scopes.LAYERS)
+def test_declared_name_reaches_the_lowered_program(lowered, name):
+    assert _holds(_stacks(lowered[HOME[name]]), name)
+
+
+@pytest.mark.parametrize("model", ["resnet50", "vgg16", "vit_s16"])
+def test_every_model_names_its_input_cast(lowered, model):
+    assert _holds(_stacks(lowered[model]), "cast_in")
+
+
+def test_forward_and_backward_carry_the_names_they_should(lowered):
+    """(With one device the stacks start at `jit(train_step)/`, with more
+    they start inside the `shard_map` body: compared by their ends.)"""
+    stacks = _stacks(lowered["vggf"])
+    ends = lambda stacks, end: any(s.endswith(end) for s in stacks)
+    # the LRN's hand-written backward (a custom_vjp) keeps its layer's name
+    assert ends(stacks, "transpose(jvp(VGGF))/lrn1")
+    assert ends(stacks, "/jvp(VGGF)/lrn1") or "jvp(VGGF)/lrn1" in stacks
+    assert ends(stacks, "transpose(jvp(VGGF))/pool5")
+    assert ends(stacks, "jvp(loss)") and ends(stacks, "transpose(jvp(loss))")
+    # the prologue runs before the gradient is taken: forward only
+    for phase in ("finish_u8", "augment/flip", "augment/mix"):
+        assert ends(stacks, phase)
+        assert not any("transpose(" in s and phase in s for s in stacks)
+    resnet = _stacks(lowered["resnet50"])
+    assert ends(resnet, "transpose(jvp(ResNet))/pool_init")
+    assert ends(resnet, "transpose(jvp(ResNet))/stage1_block1/bn1")
+
+
+def test_jitted_steps_are_named_for_what_they_are(lowered):
+    """The module's name is what a trace's `XLA Modules` line shows and,
+    unlike the scopes, part of the persistent compile cache's key."""
+    module = lambda text: re.search(r"module @(\S+)", text).group(1)
+    assert module(lowered["vggf"]) == "jit_train_step"
+    assert module(lowered["resnet50"]) == "jit_train_step"
+    assert module(lowered["vggf_eval"]) == "jit_eval_step"
+    assert _holds(_stacks(lowered["vggf_eval"]), "finish_u8")
+
+
+def test_call_sites_and_declared_lists_agree():
+    """`grep named_scope` over the package: every name of the lists has a
+    call site, and no call site names anything else."""
+    found = set()
+    for folder, _, files in os.walk(PACKAGE):
+        for file in files:
+            if file.endswith(".py"):
+                with open(os.path.join(folder, file)) as f:
+                    found |= set(re.findall(
+                        r'named_scope\(f?"([^"]+)"\)', f.read()))
+    assert "pool{b}" in found          # models/vgg16.py, one for each block
+    found = (found - {"pool{b}"}) | {f"pool{b}" for b in range(1, 6)}
+    assert found == set(scopes.PHASES) | set(scopes.LAYERS)
