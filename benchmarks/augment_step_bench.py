@@ -85,8 +85,6 @@ def main() -> int:
                                     compute_dtype="float32"))
     mesh = build_mesh(MeshSpec(("data",), (0,)))
     tx = optax.sgd(0.01, momentum=0.9)
-    finish = make_device_finish(IMAGENET_MEAN_RGB, IMAGENET_STDDEV_RGB,
-                                space_to_depth=False)
     aug_cfg = AugmentConfig(enabled=True, hflip=True, mixup_alpha=0.2)
     augment = make_device_augment(aug_cfg, IMAGENET_MEAN_RGB,
                                   IMAGENET_STDDEV_RGB, space_to_depth=s2d)
@@ -106,11 +104,12 @@ def main() -> int:
         state = TrainState.create(
             model, tx, jax.random.key(0),
             jnp.zeros((1, args.image_size, args.image_size, 3), jnp.float32))
-        # augment-on defers the pack behind the stage; augment-off packs in
-        # the finish — each column runs ITS production configuration
+        # augment-on: the stage is the whole prologue (it packs and runs
+        # a finish of its own); augment-off packs in the finish — each
+        # column runs ITS production configuration
         step = build_train_step(
             model, tx, mesh, weight_decay=5e-4,
-            device_finish=finish if with_augment else finish_s2d,
+            device_finish=None if with_augment else finish_s2d,
             device_augment=augment if with_augment else None)
         return state, step
 

@@ -631,10 +631,10 @@ class DataConfig:
 
     @property
     def host_space_to_depth(self) -> bool:
-        """Whether the HOST pipeline packs the 4x4 layout. With the fused
-        device augmentation enabled, packing must happen AFTER the
-        device-side geometric augments — the host then always ships
-        unpacked (S, S, 3) and the train step packs post-augment, for the
+        """Whether the HOST pipeline packs the 4x4 layout. With the device
+        augmentation enabled the stage takes the batch unpacked (its crop
+        jitter and RandAugment ops address pixels by (y, x)) and packs it
+        itself — the host then always ships unpacked (S, S, 3), for the
         host wires exactly as the u8 wire always did. The single source of
         the packing split; every pipeline builder consults this, never
         `space_to_depth` directly."""

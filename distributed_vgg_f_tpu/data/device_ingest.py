@@ -8,9 +8,11 @@ resampled uint8 pixels instead (1 byte/pixel, a 4x wire/ring reduction vs
 f32) and performs that elementwise finishing math HERE, on the
 accelerator: ``make_device_finish`` returns a pure function the jitted
 train/eval steps apply to the batch's images INSIDE the ``shard_map`` body
-(train/step.py), so XLA fuses normalize + cast + relayout into the step
-for free — the tf.data-paper move (PAPERS.md arxiv 2101.12127) of pushing
-cheap elementwise work to the device whose FLOPs are not the bottleneck.
+(train/step.py) — the tf.data-paper move (PAPERS.md arxiv 2101.12127) of
+pushing elementwise work off the host. It is not free on the device: at
+batch 1024 the float32 prologue was a quarter of the VGG-F step (PERF.md
+§5, PR 26), which is why everything that only moves pixels runs on the
+1-byte batch BEFORE this arithmetic (data/augment.py's ordering contract).
 
 Single-normalization contract: the finish dispatches on DTYPE — uint8
 batches are normalized exactly once; float batches (the host-normalize
@@ -59,13 +61,16 @@ def make_device_finish(mean_rgb: Sequence[float], stddev_rgb: Sequence[float],
     on the host); eval/predict callers leave it False, matching the
     host-path convention that eval batches stay (S, S, 3).
 
-    Ordering under the fused augmentation stage (r13, data/augment.py):
-    with `data.augment.enabled` the trainer builds THIS finish with
-    `space_to_depth=False` and the augment closure performs the relayout
-    AFTER the geometric augments (flipping a packed block layout would
-    have to permute channels per block) — the host skips packing by the
-    same predicate (DataConfig.host_space_to_depth), so the pack happens
-    exactly once in every configuration.
+    Ordering under the fused augmentation stage (data/augment.py): with
+    `data.augment.enabled` the train step does not call a finish of its
+    own. The augment stage owns one (built without `space_to_depth`) and
+    calls it AFTER its pixel permutations — flip, mixup partner, 4x4 pack —
+    which run on the batch as it arrived, so the finish sees an already
+    packed (B, S/4, S/4, 48) uint8 batch there and tiles its per-channel
+    constants over the packed (dy, dx, c) order: the same float32 ops per
+    element as on (B, S, S, 3). The host skips packing by the same
+    predicate (DataConfig.host_space_to_depth), so the pack happens exactly
+    once in every configuration.
     """
     mean = jnp.asarray(mean_rgb, jnp.float32)
     # reciprocal-multiply, NOT divide: mirrors the native kernels'
@@ -78,7 +83,11 @@ def make_device_finish(mean_rgb: Sequence[float], stddev_rgb: Sequence[float],
     def finish(images: jnp.ndarray) -> jnp.ndarray:
         if images.dtype != jnp.uint8:
             return images  # host-normalized already — never touch twice
-        x = (images.astype(jnp.float32) - mean) * inv_std
+        # a packed batch carries block*block pixels of 3 channels in its
+        # last axis, c fastest: the constants repeat once a pixel
+        reps = images.shape[-1] // mean.shape[0]
+        x = (images.astype(jnp.float32) - jnp.tile(mean, reps)) \
+            * jnp.tile(inv_std, reps)
         if out_dtype != jnp.float32:
             x = x.astype(out_dtype)
         if space_to_depth and x.ndim == 4 and x.shape[-1] == 3 \

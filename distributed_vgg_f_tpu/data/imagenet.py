@@ -184,7 +184,7 @@ def _finalize(tf, ds, cfg: DataConfig, is_train: bool, local_batch: int,
             # tf.nn.space_to_depth's channel order (dy, dx, c) matches the
             # VGG-F stem's packed-input contract (models/vggf.py). With
             # device augmentation enabled the host never packs — the train
-            # step relayouts AFTER the geometric augments
+            # step's augmentation stage relayouts
             # (DataConfig.host_space_to_depth is the single source).
             ds = ds.map(lambda img, label:
                         (tf.nn.space_to_depth(img, 4), label),

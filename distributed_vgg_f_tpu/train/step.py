@@ -181,12 +181,14 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
       checkpoint's `extra`). Unset (0) keeps the pre-r14 monolithic
       exchange and flat layout byte-for-byte — the kill-switch
       lowered-text identity is pinned.
-    - `device_augment` (r13, data/augment.py): the fused on-device
-      augmentation stage, applied to the post-finish batch inside the
+    - `device_augment` (r13, data/augment.py): the on-device
+      augmentation stage, applied to the batch as it arrived inside the
       shard_map body off a constant fold of the per-replica train key
-      (dropout stream untouched). Returns possibly-mixed images plus the
-      mixup/cutmix label pairing, which the loss consumes as
-      lam*CE(y) + (1-lam)*CE(y[perm]). None = structurally absent (the
+      (dropout stream untouched). It owns the device finish of its batch
+      (`device_finish` is then not called: the stage permutes the wire's
+      pixels first and finishes them itself). Returns possibly-mixed
+      images plus the mixup/cutmix label pairing, which the loss consumes
+      as lam*CE(y) + (1-lam)*CE(y[perm]). None = structurally absent (the
       augment-off kill-switch is byte-identical to a pre-r13 step). Only
       the TRAIN step takes this — eval/predict never augment.
     """
@@ -239,30 +241,29 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     # are not (distributed_vgg_f_tpu/scopes.py).
     def train_step(state: TrainState, batch: Batch, base_rng: jax.Array):
         images, labels = batch["image"], batch["label"]
-        if device_finish is not None:
-            # u8-wire finish (data/device_ingest.py): normalize + cast +
-            # space-to-depth INSIDE the shard_map body, so XLA fuses the
-            # elementwise math into the step. Dispatches on dtype — float
-            # (host-normalized) batches pass through untouched, so the
-            # prologue is safe to install for every wire.
-            with jax.named_scope("finish_u8"):
-                images = device_finish(images)
         rng = jax.random.fold_in(base_rng, state.step)
         rng = fold_rng_per_replica(rng, data_axis)
-        # Fused on-device augmentation (r13, data/augment.py): flip/jitter/
-        # photometric/mix applied to the post-finish batch INSIDE the step,
-        # keyed off a constant fold of the per-replica train key — every
-        # draw is reproducible from (seed, step, replica), the dropout
-        # stream below is untouched, and augment-off is structurally
-        # absent (device_augment=None adds zero equations — the
-        # kill-switch byte-identity contract). mix_labels/mix_lam carry
-        # the mixup/cutmix label pairing into the loss.
+        # The prologue, INSIDE the shard_map body. With on-device
+        # augmentation (data/augment.py) the stage is the whole of it: flip,
+        # mixup partner and pack on the batch as it arrived, then its own
+        # finish, then the mix — keyed off a constant fold of the
+        # per-replica train key, so every draw is reproducible from (seed,
+        # step, replica) and the dropout stream below is untouched.
+        # mix_labels/mix_lam carry the mixup/cutmix label pairing into the
+        # loss. Without it (device_augment=None adds zero equations — the
+        # kill-switch byte-identity contract) the u8-wire finish
+        # (data/device_ingest.py) runs alone: normalize + cast +
+        # space-to-depth, dispatching on dtype — float (host-normalized)
+        # batches pass through untouched, so it is safe for every wire.
         mix_labels = mix_lam = None
         if device_augment is not None:
             from distributed_vgg_f_tpu.data.augment import AUGMENT_RNG_FOLD
             with jax.named_scope("augment"):
                 images, mix_labels, mix_lam = device_augment(
                     jax.random.fold_in(rng, AUGMENT_RNG_FOLD), images, labels)
+        elif device_finish is not None:
+            with jax.named_scope("finish_u8"):
+                images = device_finish(images)
 
         def make_loss_fn(images, labels, mix_labels, batch_stats,
                          dropout_rng):

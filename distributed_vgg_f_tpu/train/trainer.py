@@ -134,25 +134,25 @@ class Trainer:
         # never packs.
         from distributed_vgg_f_tpu.data.device_ingest import (
             make_device_finish)
-        # Fused on-device augmentation (r13, data/augment.py): with the
-        # stage enabled, space-to-depth moves BEHIND it (finish stops
-        # packing, the host stops packing via host_space_to_depth, and the
-        # augment closure performs the relayout post-augment) — flipping a
-        # packed block layout would have to permute channels per block.
-        # augment.enabled=false keeps the pre-r13 wiring byte-identical.
-        augment_on = cfg.data.augment.enabled
         self.device_finish = make_device_finish(
             cfg.data.mean_rgb, cfg.data.stddev_rgb,
             image_dtype=cfg.data.image_dtype,
-            space_to_depth=cfg.data.space_to_depth and not augment_on)
+            space_to_depth=cfg.data.space_to_depth)
         self._eval_finish = make_device_finish(
             cfg.data.mean_rgb, cfg.data.stddev_rgb,
             image_dtype=cfg.data.image_dtype, space_to_depth=False)
         from distributed_vgg_f_tpu.data.augment import make_device_augment
-        # None when disabled — structurally absent from the train step
-        # (and never handed to eval/predict at all).
+        # On-device augmentation (r13, data/augment.py): with the stage
+        # enabled it is the train step's whole prologue, in place of
+        # `device_finish` above. It packs, flips and pairs the batch as it
+        # arrived (u8 on the u8 wire), then runs a finish of its own and
+        # mixes; the host skips packing by the same predicate
+        # (host_space_to_depth). None when disabled — structurally absent
+        # from the train step (augment.enabled=false keeps the pre-r13
+        # wiring byte-identical), and never handed to eval/predict at all.
         self.device_augment = make_device_augment(
             cfg.data.augment, cfg.data.mean_rgb, cfg.data.stddev_rgb,
+            image_dtype=cfg.data.image_dtype,
             space_to_depth=cfg.data.space_to_depth)
         self._build_steps()
         self.logger = logger or MetricLogger()
@@ -1076,6 +1076,13 @@ class Trainer:
                 # the counter-table rows the drift guard cross-checks
                 reg.counter("augment/steps")
                 reg.set_gauge("augment/enabled", 1)
+                # the order the stage's builder took (data/augment.py):
+                # 1 = flip, partner gather and pack ran on the wire's own
+                # dtype ahead of the finish; 0 = a stage such as rand_ops
+                # kept the gather and the pack on floats
+                reg.set_gauge(
+                    "augment/permute_on_wire_dtype",
+                    int(self.device_augment.permute_on_wire_dtype))
             # comm receipts (r14): pre-create so "zero exchanges" reads as
             # 0, not a missing key; the step wrapper increments per
             # dispatch and sets the static exchange-shape gauges

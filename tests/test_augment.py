@@ -165,16 +165,133 @@ def test_augment_stage_shapes_and_guards():
         size=(4, 8, 8, 3)), jnp.float32)
     labels = jnp.arange(4, dtype=jnp.int32)
     out, mix_labels, lam = jax.jit(aug)(jax.random.key(0), x, labels)
-    assert out.shape == (4, 2, 2, 48)  # packed AFTER augmenting
+    assert out.shape == (4, 2, 2, 48)  # the stage packs
     assert out.dtype == jnp.float32
     assert mix_labels.shape == (4,)
     assert float(lam) == pytest.approx(float(lam))  # finite scalar
-    # packed input refused: augmentation must run pre-pack
+    # packed input refused: the stage takes the batch as it arrived
     with pytest.raises(ValueError, match="unpacked"):
         aug(jax.random.key(0), space_to_depth_batch(x), labels)
-    # raw wire pixels refused: the finish runs first
-    with pytest.raises(TypeError, match="finish"):
-        aug(jax.random.key(0), jnp.zeros((4, 8, 8, 3), jnp.uint8), labels)
+    # raw wire pixels are the stage's own to finish: same shapes, and the
+    # finish's dtype (its `image_dtype`), never uint8
+    pixels = jnp.asarray(np.random.default_rng(1).integers(
+        0, 256, (4, 8, 8, 3)), jnp.uint8)
+    out, _, _ = jax.jit(aug)(jax.random.key(0), pixels, labels)
+    assert out.shape == (4, 2, 2, 48) and out.dtype == jnp.float32
+    aug16 = make_device_augment(FLAGS_ON, MEAN, STD, image_dtype="bfloat16")
+    out, _, _ = jax.jit(aug16)(jax.random.key(0), pixels, labels)
+    assert out.shape == (4, 8, 8, 3) and out.dtype == jnp.bfloat16
+    # the order a built stage took is its own receipt
+    assert aug.permute_on_wire_dtype is False       # rand_ops keeps floats
+    assert aug16.permute_on_wire_dtype is True
+
+
+# ---------------------------------- the order is exact: permute, then finish
+#: recipe -> AugmentConfig fields (every one with the flip, as shipped)
+RECIPES = {
+    "flip": {},
+    "flip_mixup": {"mixup_alpha": 0.2},             # the shipped recipe
+    "flip_mixup_jitter2": {"mixup_alpha": 0.2, "crop_jitter": 2},
+    "cutmix": {"cutmix_alpha": 1.0},
+    "mixup_cutmix": {"mixup_alpha": 0.2, "cutmix_alpha": 1.0},
+    "rand_ops2": {"mixup_alpha": 0.2, "rand_ops": 2},
+}
+
+
+def _old_order(cfg, rng, images, labels, pack):
+    """The prologue as it was until PR 27, written out: normalize -> float32
+    flip -> jitter -> rand_ops -> mix -> pack. `_crop_jitter` and
+    `_rand_ops` are the module's own, which PR 27 left as they were; what it
+    reordered (finish, flip, pairing, mix, pack) is spelled out here."""
+    from distributed_vgg_f_tpu.data.augment import _crop_jitter, _rand_ops
+    mean = jnp.asarray(MEAN, jnp.float32)
+    inv_std = jnp.float32(1.0) / jnp.asarray(STD, jnp.float32)
+    x = images
+    if x.dtype == jnp.uint8:
+        x = (x.astype(jnp.float32) - mean) * inv_std
+    x = x.astype(jnp.float32)
+    b, h, w, _ = x.shape
+    k_flip, k_jit, k_rand, k_mix = jax.random.split(rng, 4)
+    if cfg.hflip:
+        bits = jax.random.bernoulli(k_flip, 0.5, (b,))
+        x = jnp.where(bits[:, None, None, None], x[:, :, ::-1, :], x)
+    if cfg.crop_jitter:
+        x = _crop_jitter(k_jit, x, cfg.crop_jitter)
+    if cfg.rand_ops:
+        x = _rand_ops(k_rand, x, mean, inv_std, cfg.rand_ops,
+                      cfg.rand_magnitude)
+    mix_labels = lam = None
+    if cfg.mixup_alpha > 0 or cfg.cutmix_alpha > 0:
+        k_perm, k_lam, k_box, k_choice = jax.random.split(k_mix, 4)
+        perm = jax.random.permutation(k_perm, b)
+
+        def mixup(x, lam0):
+            return lam0, x * lam0 + x[perm] * (1.0 - lam0)
+
+        def cutmix(x, lam0):
+            ratio = jnp.sqrt(1.0 - lam0)
+            bh = jnp.round(ratio * h).astype(jnp.int32)
+            bw = jnp.round(ratio * w).astype(jnp.int32)
+            cy = jax.random.randint(k_box, (), 0, h)
+            cx = jax.random.randint(jax.random.fold_in(k_box, 1), (), 0, w)
+            y0, y1 = (jnp.clip(cy - bh // 2, 0, h),
+                      jnp.clip(cy + (bh + 1) // 2, 0, h))
+            x0, x1 = (jnp.clip(cx - bw // 2, 0, w),
+                      jnp.clip(cx + (bw + 1) // 2, 0, w))
+            rows = (jnp.arange(h) >= y0) & (jnp.arange(h) < y1)
+            cols = (jnp.arange(w) >= x0) & (jnp.arange(w) < x1)
+            mask = (rows[:, None] & cols[None, :])[None, :, :, None]
+            lam = 1.0 - ((y1 - y0) * (x1 - x0)).astype(jnp.float32) / (h * w)
+            return lam, jnp.where(mask, x[perm], x)
+
+        a, c = cfg.mixup_alpha, cfg.cutmix_alpha
+        if a > 0 and c > 0:
+            lam_mix = jax.random.beta(k_lam, a, a)
+            lam_cut = jax.random.beta(jax.random.fold_in(k_lam, 1), c, c)
+            if bool(jax.random.bernoulli(k_choice, 0.5)):
+                lam, x = cutmix(x, lam_cut)
+            else:
+                lam, x = mixup(x, lam_mix)
+        elif c > 0:
+            lam, x = cutmix(x, jax.random.beta(k_lam, c, c))
+        else:
+            lam, x = mixup(x, jax.random.beta(k_lam, a, a))
+        mix_labels, lam = labels[perm], lam.astype(jnp.float32)
+    if pack:
+        x = space_to_depth_batch(x)
+    return x, mix_labels, lam
+
+
+@pytest.mark.parametrize("recipe", list(RECIPES))
+@pytest.mark.parametrize("pack", [True, False], ids=["pack", "nopack"])
+@pytest.mark.parametrize("wire", ["u8", "float32"])
+def test_prologue_equals_the_old_order_bit_for_bit(wire, pack, recipe):
+    """Permuting 1-byte pixels and normalizing once gives every element the
+    float32 operations normalize-first gave it: `==` on every element and
+    on the label pairing and lam. Compared operation by operation
+    (`jax.disable_jit`): compiled, XLA:CPU contracts `a*b + c` into one FMA
+    here and not there as its fusions fall, in the old order as in the new,
+    which moves single results by one ulp and says nothing of either."""
+    cfg = AugmentConfig(enabled=True, hflip=True, **RECIPES[recipe])
+    aug = make_device_augment(cfg, MEAN, STD, space_to_depth=pack)
+    assert aug.permute_on_wire_dtype is (cfg.rand_ops == 0)
+    pixels = jnp.asarray(np.random.default_rng(5).integers(
+        0, 256, (8, 16, 16, 3)), jnp.uint8)
+    images = pixels if wire == "u8" else make_device_finish(MEAN, STD)(pixels)
+    labels = jnp.arange(8, dtype=jnp.int32)
+    with jax.disable_jit():
+        for seed in range(4):  # both flips, both arms of mixup_cutmix
+            key = jax.random.key(seed)
+            want = _old_order(cfg, key, images, labels, pack)
+            got = aug(key, images, labels)
+            for name, w, g in zip(("images", "mix_labels", "mix_lam"),
+                                  want, got):
+                if w is None:
+                    assert g is None, name
+                    continue
+                assert g.dtype == w.dtype and g.shape == w.shape, name
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                              err_msg=f"{name}, seed {seed}")
 
 
 def test_hflip_only_stage_flips_about_half():
@@ -447,6 +564,72 @@ def test_zoo_wire_parity_with_augment(model_name, devices8):
     np.testing.assert_array_equal(run(True), run(False))
 
 
+# ------------------------------------------ the lowered step moves bytes
+_MOVERS = ("stablehlo.reverse", "stablehlo.gather", "stablehlo.transpose",
+           "stablehlo.reshape", "stablehlo.select",
+           "stablehlo.dynamic_slice", "stablehlo.concatenate")
+
+
+def _image_movers(text, numel):
+    """(op, element type) of every operation of a lowered module that only
+    moves elements and touches a tensor as large as the image batch."""
+    import re
+    found = []
+    for line in text.splitlines():
+        op = next((m for m in _MOVERS if m in line), None)
+        if op is None:
+            continue
+        for dims, dtype in re.findall(r"tensor<((?:\d+x)+)(\w+)>", line):
+            if dtype != "i1" and np.prod(
+                    [int(d) for d in dims.split("x") if d]) == numel:
+                found.append((op.split(".")[1], dtype))
+    return found
+
+
+@pytest.mark.parametrize("preset,packs", [("vggf_imagenet_dp", True),
+                                          ("resnet50_imagenet", False)])
+def test_lowered_step_permutes_the_u8_batch(preset, packs):
+    """The shipped recipe (flip + mixup) on the u8 wire, lowered at a tiny
+    size: between the step's image argument and the stem every operation
+    that only moves the batch's pixels (the flip's reverse and select, the
+    partner's gather with its views, the pack) takes uint8, and a packing
+    model never sees a float32 tensor of the unpacked shape at all. The
+    stage's receipt says the same."""
+    from distributed_vgg_f_tpu.config import apply_overrides
+    from distributed_vgg_f_tpu.train.trainer import Trainer
+    from distributed_vgg_f_tpu.utils.logging import MetricLogger
+    rows, size = 8, 32
+    extra = {} if packs else {"model.extra": {"stage_sizes": (1, 1, 1, 1)}}
+    cfg = apply_overrides(get_config(preset), {
+        "data.image_size": size, "model.num_classes": 10,
+        "data.global_batch_size": rows, "mesh.num_data": 1, **extra})
+    aug = cfg.data.augment
+    assert aug.enabled and aug.hflip and aug.mixup_alpha > 0
+    assert not (aug.crop_jitter or aug.cutmix_alpha or aug.rand_ops)
+    assert cfg.data.space_to_depth is packs
+    mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
+                      jax.devices()[:1])
+    trainer = Trainer(cfg, mesh=mesh,
+                      logger=MetricLogger(stream=io.StringIO()))
+    assert trainer.device_augment.permute_on_wire_dtype is True
+    state = jax.eval_shape(trainer.init_state)
+    batch = {"image": jax.ShapeDtypeStruct((rows, size, size, 3), jnp.uint8),
+             "label": jax.ShapeDtypeStruct((rows,), jnp.int32)}
+    text = trainer.train_step.lower(state, batch,
+                                    trainer.base_rng()).as_text()
+    movers = _image_movers(text, rows * size * size * 3)
+    moved = {op for op, _ in movers}
+    assert {"reverse", "gather", "select"} <= moved, moved
+    assert ("reshape" in moved) or not packs
+    assert {dtype for _, dtype in movers} == {"ui8"}, movers
+    unpacked_f32 = f"tensor<{rows}x{size}x{size}x3xf32>"
+    if packs:
+        assert unpacked_f32 not in text
+        assert f"tensor<{rows}x{size // 4}x{size // 4}x48xf32>" in text
+    else:
+        assert unpacked_f32 in text  # the finish's output, then arithmetic
+
+
 # ------------------------------------------------------- trainer + JSONL
 def test_trainer_fit_emits_augment_receipts(tmp_path):
     """A tiny augmented fit: the per-window JSONL carries the
@@ -493,6 +676,9 @@ def test_trainer_fit_emits_augment_receipts(tmp_path):
     snap = telemetry.get_registry().snapshot_split()
     assert snap["counters"].get("augment/steps") == 4
     assert snap["gauges"].get("augment/enabled") == 1
+    # the order the built stage took, not the config restated
+    assert trainer.device_augment.permute_on_wire_dtype is True
+    assert snap["gauges"].get("augment/permute_on_wire_dtype") == 1
     telemetry.reset()
     telemetry.configure(enabled=True)
 

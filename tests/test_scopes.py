@@ -108,8 +108,9 @@ def test_forward_and_backward_carry_the_names_they_should(lowered):
     assert ends(stacks, "/jvp(VGGF)/lrn1") or "jvp(VGGF)/lrn1" in stacks
     assert ends(stacks, "transpose(jvp(VGGF))/pool5")
     assert ends(stacks, "jvp(loss)") and ends(stacks, "transpose(jvp(loss))")
-    # the prologue runs before the gradient is taken: forward only
-    for phase in ("finish_u8", "augment/flip", "augment/mix"):
+    # the prologue runs before the gradient is taken: forward only; the
+    # augmentation stage owns the train step's finish (data/augment.py)
+    for phase in ("augment/finish_u8", "augment/flip", "augment/mix"):
         assert ends(stacks, phase)
         assert not any("transpose(" in s and phase in s for s in stacks)
     resnet = _stacks(lowered["resnet50"])
