@@ -526,7 +526,7 @@ class AugmentConfig:
 
 @dataclass(frozen=True)
 class DataConfig:
-    name: str = "synthetic"  # "synthetic" | "cifar10" | "imagenet" | "teacher"
+    name: str = "synthetic"  # "synthetic" | "cifar10" | "imagenet" | "teacher" | "synthetic_tokens"
     data_dir: str = ""       # dataset root; "" = synthetic fallback where supported
     image_size: int = 224    # square train/eval resolution after crop+resize
     global_batch_size: int = 256   # across ALL replicas; must divide by replica count
@@ -1283,6 +1283,82 @@ def _vggf_teacher() -> ExperimentConfig:
     )
 
 
+#: `Mistral-Small-4-119B-2603`'s published language-model config
+#: (https://huggingface.co/mistralai/Mistral-Small-4-119B-2603/blob/main/
+#: config.json, `model_type: mistral4`): every width as published.
+MISTRAL_SMALL4_PUBLISHED = {
+    "hidden_size": 4096, "num_attention_heads": 32, "q_lora_rank": 1024,
+    "kv_lora_rank": 256, "qk_nope_head_dim": 64, "qk_rope_head_dim": 64,
+    "v_head_dim": 128, "n_routed_experts": 128, "num_experts_per_tok": 4,
+    "moe_intermediate_size": 2048, "n_shared_experts": 1,
+    "rms_norm_eps": 1e-6,
+    "rope_parameters": {
+        "rope_theta": 10000, "factor": 128, "beta_fast": 32, "beta_slow": 1,
+        "original_max_position_embeddings": 8192, "mscale": 1,
+        "mscale_all_dim": 1, "llama_4_scaling_beta": 0.1,
+        "rope_type": "yarn", "type": "yarn"},
+}
+
+
+def _mistral4(name: str, extra: dict, vocab_rows: int,
+              steps: int) -> ExperimentConfig:
+    """The language-model presets' common frame: packed int32 tokens from
+    the seeded source, one sequence a step, bf16 compute on float32
+    weights, SGD-momentum 0.9 at a constant rate, no weight decay, no
+    dropout, no augmentation (the catalog gives no recipe). `model.extra`
+    carries the published widths, the share (`experts_held`,
+    `first_expert`, `num_hidden_layers`; `model.num_classes` is the
+    vocabulary rows held) and `seq_len`, which the token source reads.
+    Training only: serving, checkpoint re-topology and ZeRO's flat vector
+    for this model are out of scope (mesh flags stay off)."""
+    return ExperimentConfig(
+        name=name,
+        model=ModelConfig(name="mistral4", num_classes=vocab_rows,
+                          dropout_rate=0.0, extra=extra),
+        optim=OptimConfig(base_lr=0.01, reference_batch_size=1, momentum=0.9,
+                          weight_decay=0.0, schedule="constant"),
+        data=DataConfig(name="synthetic_tokens", global_batch_size=1,
+                        num_train_examples=1_000_000),
+        train=TrainConfig(steps=steps, log_every=100),
+    )
+
+
+def _mistral_small4_119b_ep16() -> ExperimentConfig:
+    """One chip's share of Mistral-Small-4-119B under 16-way expert
+    parallelism: 4 of 36 layers, experts [0, 8) of 128 (the router stays
+    128 wide, top-4), 16384 of 131072 vocabulary rows, sequences of 4096.
+    1.15 B parameters: 12 bytes each (weights, momentum, gradients) fill
+    the chip before the first activation, so every block is recomputed in
+    the backward pass and the loss goes over the sequence in chunks."""
+    return _mistral4(
+        "mistral_small4_119b_ep16",
+        {**MISTRAL_SMALL4_PUBLISHED, "num_hidden_layers": 4,
+         "first_expert": 0, "experts_held": 8, "seq_len": 4096},
+        vocab_rows=16384, steps=100)
+
+
+def _mistral_small4_tiny() -> ExperimentConfig:
+    """The same block at a size a CPU test holds, every expert held:
+    hidden 64, 8 experts top-2, 2 layers, vocabulary 256, sequences of 32,
+    float32 compute (off a TPU the attention core is explicit scores, not
+    the Pallas kernel: models/mistral4.py)."""
+    cfg = _mistral4(
+        "mistral_small4_tiny",
+        {"hidden_size": 64, "num_attention_heads": 4, "q_lora_rank": 32,
+         "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+         "v_head_dim": 16, "n_routed_experts": 8, "num_experts_per_tok": 2,
+         "moe_intermediate_size": 32, "n_shared_experts": 1,
+         "rms_norm_eps": 1e-6,
+         "rope_parameters": MISTRAL_SMALL4_PUBLISHED["rope_parameters"],
+         "num_hidden_layers": 2, "seq_len": 32},
+        vocab_rows=256, steps=3)
+    return _replace(
+        cfg, model=_replace(cfg.model, compute_dtype="float32"),
+        data=_replace(cfg.data, global_batch_size=2),
+        optim=_replace(cfg.optim, reference_batch_size=2),
+        train=_replace(cfg.train, log_every=1))
+
+
 PRESETS = {
     "vggf_cifar10_smoke": _vggf_cifar10_smoke,
     "vggf_imagenet_dp": _vggf_imagenet_dp,
@@ -1291,6 +1367,8 @@ PRESETS = {
     "vit_s16_imagenet": _vit_s16_imagenet,
     "vggf_synthetic": _vggf_synthetic,
     "vggf_teacher": _vggf_teacher,
+    "mistral_small4_119b_ep16": _mistral_small4_119b_ep16,
+    "mistral_small4_tiny": _mistral_small4_tiny,
 }
 
 

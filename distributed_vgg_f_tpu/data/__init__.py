@@ -1,7 +1,8 @@
 """Input pipelines (SURVEY.md §2.1 #5): host-side data feeding the device mesh.
 
 `build_dataset(cfg.data, ...)` returns an iterator of process-local numpy batches
-{'image': (B_local, H, W, 3) float32, 'label': (B_local,) int32}; the trainer
+{'image': (B_local, H, W, 3) float32, 'label': (B_local,) int32} (or, for a
+language model, {'tokens': (B_local, S + 1) int32}); the trainer
 shards them over the mesh with `parallel.mesh.shard_host_batch`.
 """
 
@@ -11,7 +12,7 @@ from distributed_vgg_f_tpu.data.synthetic import SyntheticDataset  # noqa: F401
 def build_dataset(data_cfg, split: str = "train", *, seed: int = 0,
                   num_shards: int = 1, shard_index: int = 0,
                   state_dir: str = "", snapshot_every: int = 0,
-                  num_classes: int | None = None):
+                  num_classes: int | None = None, seq_len: int = 0):
     """Dataset factory. Per-host sharding: each process gets 1/num_shards of the
     global batch (the reference's per-worker shard, SURVEY.md §1).
 
@@ -22,7 +23,12 @@ def build_dataset(data_cfg, split: str = "train", *, seed: int = 0,
     label spaces, but synthetic labels must stay inside the head — a
     1000-class synthetic label against a 10-class head is an out-of-range
     CE gather (r3: surfaced as loss=nan with finite grads when overriding
-    model.num_classes under the synthetic pipeline)."""
+    model.num_classes under the synthetic pipeline).
+
+    `data.name == "synthetic_tokens"` is the language model's source
+    (data/synthetic_tokens.py): `seq_len` is the model's (its preset's
+    `model.extra`), `num_classes` the vocabulary rows it holds. It has a
+    train split only."""
     if data_cfg.global_batch_size % num_shards != 0:
         raise ValueError(
             f"global batch {data_cfg.global_batch_size} not divisible by "
@@ -42,6 +48,13 @@ def build_dataset(data_cfg, split: str = "train", *, seed: int = 0,
             data_cfg, local_batch, seed=seed, num_shards=num_shards,
             shard_index=shard_index, num_classes=num_classes,
             state_dir=state_dir, snapshot_every=snapshot_every)
+    if data_cfg.name == "synthetic_tokens":
+        if split != "train":
+            raise ValueError("synthetic_tokens has a train split only")
+        from distributed_vgg_f_tpu.data.synthetic_tokens import (
+            SyntheticTokens)
+        return SyntheticTokens(local_batch, seq_len, num_classes,
+                               seed=seed + shard_index)
     if data_cfg.name == "synthetic":
         return SyntheticDataset(
             batch_size=local_batch, image_size=data_cfg.image_size,

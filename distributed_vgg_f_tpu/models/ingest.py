@@ -70,6 +70,12 @@ class IngestDescriptor:
     #: per-model presets never pick it up, but first-class for the serving
     #: router (serving/tiers.py builds the `student` tier from it)
     serving_only: bool = False
+    #: what a batch of this model is: "image" ({'image', 'label'}, the u8
+    #: or host-float wire above) or "tokens" ({'tokens': int32[B, S + 1]},
+    #: inputs [:, :-1] and next-token targets [:, 1:]). The trainer picks
+    #: the data source, the sample input and the step's prologue, loss and
+    #: metrics by it; an image batch compiles to the step it always did
+    kind: str = "image"
 
     def describe(self) -> dict:
         """JSON-ready receipt for bench rows and the trainer start record."""
@@ -91,6 +97,10 @@ INGEST_DESCRIPTORS: Dict[str, IngestDescriptor] = {
     # stands in for, but never a training preset
     "vggf_student": IngestDescriptor("vggf_student", space_to_depth=True,
                                      serving_only=True),
+    # the decoder-only language model (models/mistral4.py): packed int32
+    # tokens, no pixel wire; `zoo_model_names` (the image grids, the
+    # serving router) leaves it out by its kind
+    "mistral4": IngestDescriptor("mistral4", kind="tokens", wire="tokens"),
 }
 
 
@@ -113,14 +123,15 @@ def reject_raw_uint8(x, model_name: str) -> None:
 
 
 def zoo_model_names(*, include_serving_only: bool = False) -> Tuple[str, ...]:
-    """The registered zoo, in table order — the serving router's model
+    """The registered image zoo, in table order — the serving router's model
     vocabulary (serving/server.py fronts one engine per descriptor row)
     and the per-model test grids iterate THIS, never a hand-kept list.
     Serving-only rows (the distilled student) are excluded by default so
     training grids and presets never see them; the serving surfaces opt
     in with `include_serving_only=True`."""
     return tuple(name for name, d in INGEST_DESCRIPTORS.items()
-                 if include_serving_only or not d.serving_only)
+                 if d.kind == "image"
+                 and (include_serving_only or not d.serving_only))
 
 
 def ingest_descriptor(model_name: str) -> IngestDescriptor:

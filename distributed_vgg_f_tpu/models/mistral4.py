@@ -1,0 +1,423 @@
+"""Mistral-Small-4's decoder block (`model_type: mistral4`) as a language
+model the trainer can train: latent attention (MLA) and, in every layer, a
+mixture of routed SwiGLU experts beside one shared expert.
+
+Equations (the DeepSeek-V2 form the config's keys name; eps 1e-6, no bias):
+
+    h = x + MLA(RMSNorm(x));   y = h + MoE(RMSNorm(h))
+    MLA:  c_q = RMSNorm(x W_dq);  q = c_q W_uq -> heads x [q_nope | q_rope]
+          [c_kv | k_r] = x W_dkv;  c_kv = RMSNorm(c_kv)
+          c_kv W_ukv -> heads x [k_nope | v];  k = [k_nope | rope(k_r)]
+          (one k_r shared by all heads; rope on interleaved pairs, YaRN
+          frequencies); causal softmax(q k^T scale) v; concat heads; W_o
+    MoE:  p = softmax(u W_r) in float32 over ALL experts; top-k;
+          w_k = p_k / sum p_k;  out = sum_k w_k E_k(u) + S(u)
+          E(u) = (silu(u W_g) * u W_u) W_d, S the same
+
+**The share.** A layer is told which experts it holds, `[first_expert,
+first_expert + experts_held)` of `n_routed_experts`. It routes over all of
+them at the published width and top-k, normalises the weights over all the
+chosen experts, held or not, and adds only its own experts' terms: what one
+chip of an expert-parallel group computes between the exchanges. What the
+absent experts would add is left out and the partial result goes on; no
+code stands in for the absent chips. With every expert held it is the whole
+layer. Nothing is dropped: the routed buffers hold every assignment a batch
+can make (tokens x top-k rows), sorted by expert, and the grouped products
+(`jax.lax.ragged_dot`, which the TPU compiler turns into its grouped-matmul
+kernel) visit the held experts' rows only. (Smaller buffers for the usual
+load, with these behind a `lax.cond` for the rest, would move a quarter of
+the rows; compiled for a v5e that form needs 1.6-2.9 GiB more temporaries
+than the chip has left beside this model's state: PERF.md, PR 28.)
+
+Training only: serving (a latent cache), checkpoint re-topology and ZeRO's
+flat vector for this model are out of scope.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+# ---- rotary position embedding ---------------------------------------------
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original_len: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's `dim // 2` inverse frequencies: the plain ones where a pair
+    turns more than `beta_fast` times over the original length, the plain
+    ones over `factor` where it turns fewer than `beta_slow` times, a
+    linear ramp between."""
+    def correction(turns):
+        return dim * math.log(original_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    extrap = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    return (extrap * (1.0 - ramp) + extrap / factor * ramp).astype(np.float32)
+
+
+def rotate_interleaved(x, positions, inv_freq):
+    """Rope on interleaved pairs: (x[2i], x[2i+1]) turned by
+    positions * inv_freq[i]. `x` is (B, T, heads, d) or (..., T, d) with
+    `positions` (T,); computed in float32, returned in x's dtype."""
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if x.ndim == 4:
+        cos, sin = cos[:, None, :], sin[:, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def yarn_attention_scale(qk_head_dim: int, factor: float,
+                         mscale_all_dim: float) -> float:
+    """qk_head_dim^-0.5 * m^2, m = 0.1 * mscale_all_dim * ln(factor) + 1
+    (the DeepSeek-V3 reading of `mscale_all_dim`)."""
+    m = 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return qk_head_dim ** -0.5 * m * m
+
+
+def llama4_query_scale(positions, original_len: int, beta: float):
+    """1 + beta * ln(1 + floor(pos / original_len)): 1 below the original
+    length."""
+    return 1.0 + beta * jnp.log1p(jnp.floor(
+        positions.astype(jnp.float32) / original_len))
+
+
+# ---- layers -----------------------------------------------------------------
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        x32 = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        return (x32 * scale).astype(x.dtype)
+
+
+def _dense(features: int, dtype, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=jnp.float32, name=name)
+
+
+class LatentAttention(nn.Module):
+    """MLA, causal over the whole sequence. The core is the Pallas kernel
+    of ops/flash_attention.py where that can run (a TPU, or the Pallas
+    interpreter that tests switch on) and explicit scores elsewhere (a CPU
+    run of the tiny preset)."""
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope: Any                     # the config's `rope_parameters`, a dict
+    compute_dtype: Any
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        from distributed_vgg_f_tpu.ops import flash_attention
+        b, t, d_model = x.shape
+        h, dn, dr, dv = (self.num_heads, self.qk_nope_head_dim,
+                         self.qk_rope_head_dim, self.v_head_dim)
+        rope = dict(self.rope)
+        inv_freq = jnp.asarray(yarn_inv_freq(
+            dr, rope["rope_theta"], rope["factor"],
+            rope["original_max_position_embeddings"], rope["beta_fast"],
+            rope["beta_slow"]))
+        positions = jnp.arange(t)
+        # the kernel applies d^-0.5 itself: what is left goes on q
+        q_scale = yarn_attention_scale(dn + dr, rope["factor"],
+                                       rope["mscale_all_dim"]) \
+            * math.sqrt(dn + dr)
+        with jax.named_scope("mla_q"):
+            c_q = RMSNorm(self.eps, name="q_a_norm")(
+                _dense(self.q_lora_rank, self.compute_dtype, "q_a_proj")(x))
+            q = _dense(h * (dn + dr), self.compute_dtype, "q_b_proj")(c_q)
+            q = q.reshape(b, t, h, dn + dr)
+            q = jnp.concatenate(
+                [q[..., :dn],
+                 rotate_interleaved(q[..., dn:], positions, inv_freq)], -1)
+            q = (q.astype(jnp.float32) * (q_scale * llama4_query_scale(
+                positions, rope["original_max_position_embeddings"],
+                rope["llama_4_scaling_beta"]))[None, :, None, None]
+                 ).astype(self.compute_dtype)
+        with jax.named_scope("mla_kv"):
+            kv_a = _dense(self.kv_lora_rank + dr, self.compute_dtype,
+                          "kv_a_proj")(x)
+            c_kv = RMSNorm(self.eps, name="kv_a_norm")(
+                kv_a[..., :self.kv_lora_rank])
+            k_rope = rotate_interleaved(kv_a[..., self.kv_lora_rank:],
+                                        positions, inv_freq)
+            kv = _dense(h * (dn + dv), self.compute_dtype, "kv_b_proj")(c_kv)
+            kv = kv.reshape(b, t, h, dn + dv)
+            k = jnp.concatenate(
+                [kv[..., :dn],
+                 jnp.broadcast_to(k_rope[:, :, None, :], (b, t, h, dr))], -1)
+            v = kv[..., dn:]
+        with jax.named_scope("mla_core"):
+            if jax.default_backend() == "tpu" or flash_attention.INTERPRET:
+                if dv != dn + dr:
+                    raise ValueError("the flash core takes one head size "
+                                     f"(qk {dn + dr}, v {dv})")
+                # the kernel's own default is blocks of at most 128: at
+                # 4096 tokens and 32 heads that is 17 k grid steps of one
+                # small product each, 30 ms forward and backward on a v5e
+                # against 5.8 ms with blocks of 1024 (PERF.md, PR 28)
+                block = next((n for n in (1024, 512, 256) if t % n == 0),
+                             None)
+                ctx = flash_attention.flash_self_attention(
+                    q, k, v, causal=True, block_q=block, block_k=block)
+            else:
+                scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                                    preferred_element_type=jnp.float32) \
+                    * (dn + dr) ** -0.5
+                scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores,
+                                   -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1)
+                ctx = jnp.einsum("bhqk,bkhd->bqhd",
+                                 probs.astype(self.compute_dtype), v)
+        with jax.named_scope("mla_out"):
+            return _dense(d_model, self.compute_dtype, "o_proj")(
+                ctx.reshape(b, t, h * dv))
+
+
+def route(probs, top_k: int, first_expert: int, experts_held: int):
+    """Top-k of `probs` (tokens, experts) and this share's view of it:
+    `(weights, local)` of shape (tokens, top_k), weights normalised over
+    all chosen experts, `local` the held experts' index in
+    [0, experts_held) and `experts_held` for one that lives elsewhere."""
+    top_p, top_e = jax.lax.top_k(probs, top_k)
+    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    local = top_e - first_expert
+    held = (local >= 0) & (local < experts_held)
+    return weights, jnp.where(held, local, experts_held)
+
+
+class ExpertShare(nn.Module):
+    """The routed experts this chip holds, and the shared expert."""
+    n_routed_experts: int
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    n_shared_experts: int
+    first_expert: int
+    experts_held: int
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, u):
+        b, t, d = u.shape
+        tokens, k, held = b * t, self.num_experts_per_tok, self.experts_held
+        width, dtype = self.moe_intermediate_size, self.compute_dtype
+        x = u.reshape(tokens, d)
+        with jax.named_scope("moe_router"):
+            router = self.param(
+                "router", nn.initializers.lecun_normal(),
+                (d, self.n_routed_experts), jnp.float32)
+            # float32 all the way: top-k is discontinuous, and a bf16 pass
+            # over these logits flips near-ties
+            logits = jnp.dot(x.astype(jnp.float32), router,
+                             precision=jax.lax.Precision.HIGHEST)
+            weights, local = route(jax.nn.softmax(logits, axis=-1), k,
+                                   self.first_expert, held)
+        with jax.named_scope("moe_dispatch"):
+            # every assignment of the batch, sorted by held expert; those
+            # of experts that live elsewhere sort behind the last group,
+            # where the grouped products do not go
+            flat = local.reshape(tokens * k)
+            order = jnp.argsort(flat, stable=True)
+            load = jnp.bincount(flat, length=held + 1)[:held].astype(
+                jnp.int32)
+            # assignments to a held expert that no group took: 0 by
+            # construction (the buffers hold every assignment)
+            dropped = jnp.sum(flat < held).astype(jnp.int32) - jnp.sum(load)
+            in_group = (jnp.arange(tokens * k) < jnp.sum(load))[:, None]
+            # rows behind the last group are read by nothing: zeros there
+            # keep what the grouped products leave unwritten out of the
+            # backward pass
+            rows = jnp.where(in_group, x.at[order // k].get(
+                mode="promise_in_bounds"), 0)
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        gate = self.param("experts_gate_proj", init, (held, d, width),
+                          jnp.float32)
+        up = self.param("experts_up_proj", init, (held, d, width),
+                        jnp.float32)
+        down = self.param("experts_down_proj", init, (held, width, d),
+                          jnp.float32)
+        with jax.named_scope("moe_experts"):
+            grouped = lambda lhs, rhs: jax.lax.ragged_dot(
+                lhs, rhs.astype(dtype), load)
+            outs = grouped(nn.silu(grouped(rows, gate)) * grouped(rows, up),
+                           down)
+        with jax.named_scope("moe_combine"):
+            outs = jnp.where(in_group, outs, 0)
+            # assignment (token, choice) -> its sorted row; one gather of
+            # `tokens` rows a choice, so that no (tokens x k)-row buffer
+            # has to be retiled into (tokens, k, d)
+            place = jnp.argsort(order).reshape(tokens, k)
+            routed = sum(
+                weights[:, j, None].astype(jnp.float32)
+                * outs.at[place[:, j]].get(
+                    mode="promise_in_bounds",
+                    unique_indices=True).astype(jnp.float32)
+                for j in range(k))
+        with jax.named_scope("moe_shared"):
+            shared_width = width * self.n_shared_experts
+            mid = nn.silu(_dense(shared_width, dtype, "shared_gate_proj")(x)) \
+                * _dense(shared_width, dtype, "shared_up_proj")(x)
+            shared = _dense(d, dtype, "shared_down_proj")(mid)
+        out = (routed + shared.astype(jnp.float32)).astype(u.dtype)
+        return out.reshape(b, t, d), jnp.append(load, dropped)
+
+
+class Block(nn.Module):
+    attention: dict
+    experts: dict
+    compute_dtype: Any
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        h = x + LatentAttention(
+            **self.attention, compute_dtype=self.compute_dtype, eps=self.eps,
+            name="attn")(RMSNorm(self.eps, name="input_norm")(x))
+        y, counts = ExpertShare(
+            **self.experts, compute_dtype=self.compute_dtype,
+            name="moe")(RMSNorm(self.eps, name="post_attention_norm")(h))
+        return h + y, counts
+
+
+def _logits(h, kernel, dtype):
+    return jnp.dot(h.astype(dtype), kernel.astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
+@jax.checkpoint
+def _chunk_loss(kernel, h_rows, ids):
+    """Summed cross-entropy of one chunk of rows; its float32 logits are
+    made again in the backward pass and never outlive the chunk."""
+    logits = _logits(h_rows, kernel, h_rows.dtype)
+    picked = jnp.take_along_axis(logits, ids[:, None], axis=-1)[:, 0]
+    return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked)
+
+
+class Head(nn.Module):
+    """The untied output head, `hidden x vocabulary`."""
+    hidden_size: int
+    vocab_size: int
+    compute_dtype: Any
+
+    def setup(self):
+        self.kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(),
+            (self.hidden_size, self.vocab_size), jnp.float32)
+
+    def __call__(self, h):
+        return _logits(h, self.kernel, self.compute_dtype)
+
+
+class Mistral4LM(nn.Module):
+    """Token ids (B, T) -> float32 logits (B, T, vocabulary held).
+    `next_token_loss` is what the train step calls: it never holds the
+    logits of a whole sequence."""
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    attention: dict
+    experts: dict
+    compute_dtype: Any = jnp.bfloat16
+    rms_norm_eps: float = 1e-6
+    loss_chunk_rows: int = 1024
+
+    def setup(self):
+        self.embed = nn.Embed(self.vocab_size, self.hidden_size,
+                              dtype=self.compute_dtype,
+                              param_dtype=jnp.float32, name="embed")
+        # recomputation per block: only a block's input outlives its forward
+        # pass, and the bf16 casts of its weights are made again inside it
+        self.blocks = [nn.remat(Block)(self.attention, self.experts,
+                                       self.compute_dtype, self.rms_norm_eps,
+                                       name=f"layer_{i}")
+                       for i in range(self.num_hidden_layers)]
+        self.norm = RMSNorm(self.rms_norm_eps, name="norm")
+        self.lm_head = Head(self.hidden_size, self.vocab_size,
+                            self.compute_dtype, name="lm_head")
+
+    def hidden(self, tokens):
+        """Final-norm hidden states (B, T, hidden) and every layer's
+        counts (layers, experts_held + 1): the assignments each held
+        expert took, then the dropped ones (0 by construction)."""
+        with jax.named_scope("embed_tokens"):
+            x = self.embed(tokens)
+        counts = []
+        for block in self.blocks:
+            x, count = block(x)
+            counts.append(count)
+        return self.norm(x), jnp.stack(counts)
+
+    def __call__(self, tokens, *, train: bool = False):
+        h, _ = self.hidden(tokens)
+        with jax.named_scope("lm_head"):
+            return self.lm_head(h)
+
+    def next_token_loss(self, tokens, targets):
+        """(mean cross-entropy of `targets` under the logits at `tokens`,
+        the layers' counts as `hidden` gives them): float32 logits,
+        `loss_chunk_rows` rows at a time, each chunk's logits made again in
+        the backward pass."""
+        h, counts = self.hidden(tokens)
+        rows = h.reshape(-1, h.shape[-1])
+        wanted = targets.reshape(-1)
+        chunk = math.gcd(rows.shape[0], self.loss_chunk_rows)
+        with jax.named_scope("lm_head"):
+            total = 0.0
+            for start in range(0, rows.shape[0], chunk):
+                total = total + _chunk_loss(
+                    self.lm_head.kernel,
+                    rows[start:start + chunk].astype(self.compute_dtype),
+                    wanted[start:start + chunk])
+        return total / rows.shape[0], counts
+
+
+#: keys of `ModelConfig.extra` (the preset's own) and of the published
+#: config alike; `build` splits them between the layers
+_ATTENTION = ("num_attention_heads", "q_lora_rank", "kv_lora_rank",
+              "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
+_EXPERTS = ("n_routed_experts", "num_experts_per_tok",
+            "moe_intermediate_size", "n_shared_experts")
+
+
+def build(vocab_size: int, compute_dtype, extra: dict) -> Mistral4LM:
+    """`extra`: the published keys (widths, heads, experts, rope) plus the
+    share: `first_expert`, `experts_held` (default: all), and
+    `num_hidden_layers`; `seq_len` is the data source's and is not read
+    here."""
+    extra = dict(extra)
+    attention = {k: extra[k] for k in _ATTENTION}
+    attention["num_heads"] = attention.pop("num_attention_heads")
+    attention["rope"] = dict(extra["rope_parameters"])
+    experts = {k: extra[k] for k in _EXPERTS}
+    experts["first_expert"] = extra.get("first_expert", 0)
+    experts["experts_held"] = extra.get("experts_held",
+                                        extra["n_routed_experts"])
+    return Mistral4LM(
+        vocab_size=vocab_size, hidden_size=extra["hidden_size"],
+        num_hidden_layers=extra["num_hidden_layers"], attention=attention,
+        experts=experts, compute_dtype=compute_dtype,
+        rms_norm_eps=extra.get("rms_norm_eps", 1e-6))

@@ -87,3 +87,11 @@ def _build_vit_s16(cfg: ModelConfig) -> nn.Module:
     from distributed_vgg_f_tpu.models.vit import ViT
     return ViT.s16(num_classes=cfg.num_classes, dropout_rate=cfg.dropout_rate,
                    compute_dtype=_dtype(cfg), **cfg.extra)
+
+
+@register("mistral4")
+def _build_mistral4(cfg: ModelConfig) -> nn.Module:
+    # `num_classes` is the vocabulary rows held; `extra` the published
+    # widths and the share (models/mistral4.py `build`)
+    from distributed_vgg_f_tpu.models import mistral4
+    return mistral4.build(cfg.num_classes, _dtype(cfg), cfg.extra)

@@ -25,3 +25,12 @@ PHASES = ("finish_u8", "augment", "flip", "crop_jitter", "rand_ops", "mix",
 #: Layers that are plain function calls in a model, not flax modules.
 LAYERS = ("lrn1", "lrn2", "pool1", "pool2", "pool3", "pool4", "pool5",
           "pool_init", "gap", "embed_tokens")
+
+#: The language model's layers (models/mistral4.py; `embed_tokens` above is
+#: shared): latent attention, the expert share, the head. A list of their
+#: own: the benchmark's first names file (`chipbench/scopes.json`) is held
+#: equal to `LAYERS`, and a traced run of the language model's cell is
+#: reduced by a second file, `chipbench/lm_scopes.json`.
+LM_LAYERS = ("mla_q", "mla_kv", "mla_core", "mla_out", "moe_router",
+             "moe_dispatch", "moe_experts", "moe_combine", "moe_shared",
+             "lm_head")

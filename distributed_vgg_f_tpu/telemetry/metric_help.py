@@ -32,6 +32,8 @@ NAMESPACE_HELP = {
     "checkpoint": "checkpoint manager (saves, retries, waits, restores)",
     "fault": "chaos injectors (injected nan/stall/crash/preempt/kills)",
     "step": "jitted train-step dispatch wrapper",
+    "moe": "language-model expert routing (assignments held, expert "
+           "load extremes, dropped assignments)",
     "eval": "trainer evaluation passes",
     "distributed": "cross-process coordination barriers",
     "telemetry": "the telemetry registry itself (poller faults)",

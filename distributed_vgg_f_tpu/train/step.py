@@ -59,6 +59,23 @@ def _apply_model(model, params, batch_stats, images, *, train: bool,
     return logits, batch_stats
 
 
+def _expert_load_metrics(counts) -> dict:
+    """A language model's routing counters as step metrics. `counts` is
+    (layers, experts held + 1): the assignments each held expert took in
+    this replica's batch, then the dropped ones (models/mistral4.py). Per
+    layer: assignments held, the largest and smallest held expert's load,
+    dropped assignments; and the whole table as `moe_load`."""
+    counts = counts.astype(jnp.float32)
+    load, dropped = counts[:, :-1], counts[:, -1]
+    metrics = {"moe_load": load}
+    for i in range(counts.shape[0]):
+        metrics[f"moe_held/layer_{i}"] = jnp.sum(load[i])
+        metrics[f"moe_load_max/layer_{i}"] = jnp.max(load[i])
+        metrics[f"moe_load_min/layer_{i}"] = jnp.min(load[i])
+        metrics[f"moe_dropped/layer_{i}"] = dropped[i]
+    return metrics
+
+
 def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                      weight_decay: float,
                      schedule: optax.Schedule | None = None,
@@ -77,6 +94,7 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                      skip_nonfinite: bool = False,
                      device_finish: Callable | None = None,
                      device_augment: Callable | None = None,
+                     batch_kind: str = "image",
                      ) -> Callable[[TrainState, Batch, jax.Array],
                                    Tuple[TrainState, Mapping[str, jnp.ndarray]]]:
     """Returns jitted `train_step(state, batch, base_rng) -> (state, metrics)`.
@@ -191,6 +209,15 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
       as lam*CE(y) + (1-lam)*CE(y[perm]). None = structurally absent (the
       augment-off kill-switch is byte-identical to a pre-r13 step). Only
       the TRAIN step takes this — eval/predict never augment.
+    - `batch_kind` (models/ingest.py, the model's descriptor): "image"
+      batches are {'image', 'label'} and take the prologue, the
+      classification loss and top-1 above. "tokens" batches are
+      {'tokens': int32[B, S + 1]}: inputs `[:, :-1]`, targets `[:, 1:]`,
+      no prologue, the model's own `next_token_loss` (mean next-token
+      cross-entropy, float32 logits in chunks of rows) and its routing
+      counters in place of top-1. Everything after the gradient (exchange,
+      optimizer, guard, donation) is shared. An image batch traces to the
+      step it traced to before the kind existed.
     """
     if state_specs is None:
         state_specs = P()
@@ -240,7 +267,10 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     # is part of the persistent compile cache's key where the scopes below
     # are not (distributed_vgg_f_tpu/scopes.py).
     def train_step(state: TrainState, batch: Batch, base_rng: jax.Array):
-        images, labels = batch["image"], batch["label"]
+        if batch_kind == "tokens":
+            images, labels = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+        else:
+            images, labels = batch["image"], batch["label"]
         rng = jax.random.fold_in(base_rng, state.step)
         rng = fold_rng_per_replica(rng, data_axis)
         # The prologue, INSIDE the shard_map body. With on-device
@@ -267,6 +297,18 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
 
         def make_loss_fn(images, labels, mix_labels, batch_stats,
                          dropout_rng):
+            def token_loss_fn(params):
+                ce, counts = model.apply({"params": params}, images, labels,
+                                         method="next_token_loss")
+                with jax.named_scope("loss"):
+                    l2 = l2_regularization(params, weight_decay)
+                    metrics = {"loss": ce, "l2_loss": l2,
+                               **_expert_load_metrics(counts)}
+                return ce + l2, (batch_stats, metrics)
+
+            if batch_kind == "tokens":
+                return token_loss_fn
+
             def loss_fn(params):
                 logits, new_batch_stats = _apply_model(
                     model, params, batch_stats, images, train=True,
@@ -413,6 +455,9 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             full_params = state.params
 
         if grad_accum_steps > 1:
+            if batch_kind != "image":
+                raise NotImplementedError(
+                    "grad_accum_steps > 1 splits image batches only")
             b_local = images.shape[0]
             if b_local % grad_accum_steps:
                 raise ValueError(

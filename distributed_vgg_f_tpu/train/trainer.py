@@ -103,6 +103,11 @@ class Trainer:
             MeshSpec((cfg.mesh.data_axis,), (cfg.mesh.num_data,)))
         self.data_axis = cfg.mesh.data_axis
         self.model = build_model(cfg.model)
+        # what a batch of this model is (models/ingest.py): "image" or
+        # "tokens". It picks the sample input, the data source's arguments
+        # and the step's prologue, loss and metrics
+        from distributed_vgg_f_tpu.models.ingest import ingest_descriptor
+        self.batch_kind = ingest_descriptor(cfg.model.name).kind
         self.num_shards = int(self.mesh.shape[self.data_axis])
         self.zero1 = bool(cfg.mesh.shard_opt_state) and self.num_shards > 1
         # ZeRO-2 (r14): gradient state sharded like the opt state —
@@ -154,6 +159,9 @@ class Trainer:
             cfg.data.augment, cfg.data.mean_rgb, cfg.data.stddev_rgb,
             image_dtype=cfg.data.image_dtype,
             space_to_depth=cfg.data.space_to_depth)
+        if self.batch_kind == "tokens":
+            # no pixels: no finish and no augmentation to install
+            self.device_finish = self.device_augment = None
         self._build_steps()
         self.logger = logger or MetricLogger()
         # Live observability endpoint (telemetry/exporter.py): one
@@ -291,7 +299,8 @@ class Trainer:
             reduce_dtype=cfg.mesh.reduce_dtype,
             skip_nonfinite=cfg.train.skip_nonfinite,
             device_finish=self.device_finish,
-            device_augment=self.device_augment)
+            device_augment=self.device_augment,
+            batch_kind=self.batch_kind)
         self.eval_step = build_eval_step(self.model, self.mesh,
                                          data_axis=self.data_axis,
                                          state_specs=self._state_specs,
@@ -300,6 +309,10 @@ class Trainer:
 
     # ------------------------------------------------------------------ state
     def _sample_input(self) -> jnp.ndarray:
+        if self.batch_kind == "tokens":
+            # parameter shapes do not depend on the length
+            seq_len = min(128, int(self.cfg.model.extra["seq_len"]))
+            return jnp.zeros((1, seq_len), jnp.int32)
         return jnp.zeros(
             (1, self.cfg.data.image_size, self.cfg.data.image_size, 3),
             jnp.float32)
@@ -614,7 +627,8 @@ class Trainer:
                              num_shards=jax.process_count(),
                              shard_index=jax.process_index(),
                              state_dir=state_dir, snapshot_every=every,
-                             num_classes=cfg.model.num_classes)
+                             num_classes=cfg.model.num_classes,
+                             seq_len=int(cfg.model.extra.get("seq_len", 0)))
 
     def _make_train_ingest(self):
         """The trainer-owned train stream for fit(). With
@@ -675,7 +689,8 @@ class Trainer:
         for batch in it:
             if first:
                 first = False
-                labels = np.asarray(batch["label"])
+                labels = np.asarray(batch["tokens" if self.batch_kind
+                                          == "tokens" else "label"])
                 nc = self.cfg.model.num_classes
                 if labels.size and int(labels.max()) >= nc:
                     raise ValueError(
@@ -1216,8 +1231,24 @@ class Trainer:
                     if (step + 1) % cfg.train.log_every == 0 or step + 1 == total:
                         # device_get syncs: throughput numbers include real device
                         # time.
+                        fetched = jax.device_get(metrics)
+                        # a language model's `moe_load` is a table; the
+                        # log takes the scalars
                         last_metrics = {k: float(v) for k, v in
-                                        jax.device_get(metrics).items()}
+                                        fetched.items() if np.ndim(v) == 0}
+                        if "moe_load" in fetched and tele.enabled:
+                            # routing receipts (models/mistral4.py), over
+                            # the layers of the step just logged
+                            load = np.asarray(fetched["moe_load"])
+                            reg.set_gauge("moe/assignments_held",
+                                          float(load.sum()))
+                            reg.set_gauge("moe/expert_load_max",
+                                          float(load.max()))
+                            reg.set_gauge("moe/expert_load_min",
+                                          float(load.min()))
+                            reg.set_gauge("moe/dropped_assignments", sum(
+                                v for k, v in last_metrics.items()
+                                if k.startswith("moe_dropped/")))
                         entry = {"step": step + 1, **last_metrics,
                                  **meter.snapshot(),
                                  # host_wait_fraction: share of wall time this

@@ -39,6 +39,20 @@ def _lowered_steps(preset: str, chips: int, **overrides):
     return (train.as_text(debug_info=True), evaluate.as_text(debug_info=True))
 
 
+def _lowered_token_step() -> str:
+    """The language model's train step (the tiny preset) as lowered text."""
+    cfg = get_config("mistral_small4_tiny")
+    mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
+                      jax.devices()[:1])
+    trainer = Trainer(cfg, mesh=mesh, logger=MetricLogger(stream=io.StringIO()))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (cfg.data.global_batch_size, cfg.model.extra["seq_len"] + 1),
+        jnp.int32)}
+    return trainer.train_step.lower(
+        jax.eval_shape(trainer.init_state), batch,
+        trainer.base_rng()).as_text(debug_info=True)
+
+
 def _lowered_forward(name: str, **extra) -> str:
     from distributed_vgg_f_tpu.config import ModelConfig
     from distributed_vgg_f_tpu.models.registry import build_model
@@ -60,7 +74,8 @@ def lowered():
                                **{"model.extra": {"stage_sizes": (1, 1, 1, 1)}})
     return {"vggf": vggf, "vggf_eval": vggf_eval, "resnet50": resnet,
             "vgg16": _lowered_forward("vgg16"),
-            "vit_s16": _lowered_forward("vit_s16", depth=1)}
+            "vit_s16": _lowered_forward("vit_s16", depth=1),
+            "mistral4": _lowered_token_step()}
 
 
 def _stacks(text: str) -> set:
@@ -80,15 +95,20 @@ HOME = {**{name: "vggf" for name in scopes.PHASES},
         "lrn1": "vggf", "lrn2": "vggf", "pool1": "vggf", "pool2": "vggf",
         "pool5": "vggf", "pool3": "vgg16", "pool4": "vgg16",
         "pool_init": "resnet50", "gap": "resnet50",
-        "embed_tokens": "vit_s16"}
+        "embed_tokens": "vit_s16",
+        **{name: "mistral4" for name in scopes.LM_LAYERS}}
 
 
 def test_every_declared_name_has_a_home():
-    assert set(HOME) == set(scopes.PHASES) | set(scopes.LAYERS)
+    assert set(HOME) == set(scopes.PHASES) | set(scopes.LAYERS) \
+        | set(scopes.LM_LAYERS)
     assert not set(scopes.PHASES) & set(scopes.LAYERS)
+    assert not (set(scopes.PHASES) | set(scopes.LAYERS)) \
+        & set(scopes.LM_LAYERS)
 
 
-@pytest.mark.parametrize("name", scopes.PHASES + scopes.LAYERS)
+@pytest.mark.parametrize("name", scopes.PHASES + scopes.LAYERS
+                         + scopes.LM_LAYERS)
 def test_declared_name_reaches_the_lowered_program(lowered, name):
     assert _holds(_stacks(lowered[HOME[name]]), name)
 
@@ -116,6 +136,15 @@ def test_forward_and_backward_carry_the_names_they_should(lowered):
     resnet = _stacks(lowered["resnet50"])
     assert ends(resnet, "transpose(jvp(ResNet))/pool_init")
     assert ends(resnet, "transpose(jvp(ResNet))/stage1_block1/bn1")
+    # the language model's names survive recomputation per block: forward,
+    # and again (with the backward pass) below the transposed stack
+    tokens = _stacks(lowered["mistral4"])
+    assert _holds(tokens, "embed_tokens")
+    for name in ("mla_core", "moe_experts", "moe_combine"):
+        assert any(s.endswith(name) and "transpose(" not in s
+                   for s in tokens), name
+        assert any(s.endswith(name) and "transpose(" in s
+                   for s in tokens), name
 
 
 def test_jitted_steps_are_named_for_what_they_are(lowered):
@@ -125,6 +154,7 @@ def test_jitted_steps_are_named_for_what_they_are(lowered):
     assert module(lowered["vggf"]) == "jit_train_step"
     assert module(lowered["resnet50"]) == "jit_train_step"
     assert module(lowered["vggf_eval"]) == "jit_eval_step"
+    assert module(lowered["mistral4"]) == "jit_train_step"
     assert _holds(_stacks(lowered["vggf_eval"]), "finish_u8")
 
 
@@ -140,4 +170,5 @@ def test_call_sites_and_declared_lists_agree():
                         r'named_scope\(f?"([^"]+)"\)', f.read()))
     assert "pool{b}" in found          # models/vgg16.py, one for each block
     found = (found - {"pool{b}"}) | {f"pool{b}" for b in range(1, 6)}
-    assert found == set(scopes.PHASES) | set(scopes.LAYERS)
+    assert found == set(scopes.PHASES) | set(scopes.LAYERS) \
+        | set(scopes.LM_LAYERS)
