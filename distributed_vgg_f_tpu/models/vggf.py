@@ -110,8 +110,11 @@ class VGGF(nn.Module):
             dtype=self.compute_dtype, param_dtype=jnp.float32)
         dense = lambda feat, name: nn.Dense(
             feat, name=name, dtype=self.compute_dtype, param_dtype=jnp.float32)
-        lrn = lambda v: local_response_norm(
-            v, self.lrn_depth_radius, self.lrn_bias, self.lrn_alpha, self.lrn_beta)
+        # relu then LRN, in one call: where `lrn` takes its fused kernel pair
+        # the relu and its mask are made inside it (ops/lrn.py)
+        relu_lrn = lambda v: local_response_norm(
+            v, self.lrn_depth_radius, self.lrn_bias, self.lrn_alpha,
+            self.lrn_beta, relu_input=True)
 
         from distributed_vgg_f_tpu.models.ingest import reject_raw_uint8
         reject_raw_uint8(x, "VGGF")  # u8-wire contract (r8; zoo-wide r13)
@@ -119,15 +122,15 @@ class VGGF(nn.Module):
         # (distributed_vgg_f_tpu/scopes.py) names their device time.
         with jax.named_scope("cast_in"):
             x = x.astype(self.compute_dtype)
-        x = nn.relu(Conv1SpaceToDepth(self.stem_features, self.compute_dtype,
-                                      name="conv1")(x))
+        x = Conv1SpaceToDepth(self.stem_features, self.compute_dtype,
+                              name="conv1")(x)
         with jax.named_scope("lrn1"):
-            x = lrn(x)
+            x = relu_lrn(x)
         with jax.named_scope("pool1"):
             x = _maxpool_3x3s2(x)
-        x = nn.relu(conv(self.conv_features, (5, 5), (1, 1), "SAME", "conv2")(x))
+        x = conv(self.conv_features, (5, 5), (1, 1), "SAME", "conv2")(x)
         with jax.named_scope("lrn2"):
-            x = lrn(x)
+            x = relu_lrn(x)
         with jax.named_scope("pool2"):
             x = _maxpool_3x3s2(x)
         x = nn.relu(conv(self.conv_features, (3, 3), (1, 1), "SAME", "conv3")(x))

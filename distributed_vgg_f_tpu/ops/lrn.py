@@ -2,30 +2,29 @@
 
 VGG-F (CNN-F, Chatfield et al. 2014) applies LRN after conv1 and conv2
 (SURVEY.md §3.3). JAX/Flax ship no LRN layer (SURVEY.md §7 hard parts), so this is
-implemented directly. Three implementations live in this package:
+implemented directly. The implementations in this package:
 
 - `local_response_norm` (here): squared-sum over a sliding channel window via
-  `lax.reduce_window`. Exact fp32 numerics — this is the test oracle.
+  `lax.reduce_window`. Exact fp32 numerics — this is the test oracle. On the
+  TPU its windows cross the 128-lane axis and `**0.75` lowers to exp/log.
 - `local_response_norm_matmul` (here): the channel-window sum recast as a banded
-  C×C matmul, `S = (x*x) @ B` with `B[i,j] = |i-j| <= r`. On TPU the window sum
-  rides the MXU instead of lane-crossing windowed reductions (measured ~1.7× faster
-  fwd+bwd than reduce_window on v5e), and for `beta=0.75` the power is computed as
-  `rsqrt(d)*sqrt(rsqrt(d))` instead of `exp(0.75*log d)`.
-- `ops/lrn_pallas.py`: a Pallas TPU kernel fusing square → band-matmul → scale into
-  one VMEM pass with a custom VJP (SURVEY.md §7 named LRN the one Pallas candidate;
-  profiling confirmed it: reduce_window LRN was 45% of the VGG-F train step).
-
-Measured inside the full VGG-F fwd+bwd on TPU v5e (batch 256): reduce_window
-37.3 ms/step, Pallas 21.1, matmul 14.7. XLA wins over the hand kernel here
-because it fuses the square into the preceding ReLU and the scale into the next
-conv's input, while the Pallas call boundary forces an HBM materialization (plus
-a lane-repacking relayout for C=64). On top of the matmul form,
-`local_response_norm_matmul_vjp` adds a hand-written VJP that saves NO residuals
-(autodiff stores a f32 normalizer tensor per LRN site; the VJP recomputes it
-with one extra cheap band matmul) — another ~5% off the whole VGG-F train step.
-`lrn()` dispatches to that form by default everywhere; the Pallas kernel stays
-available via `set_lrn_impl("pallas")` and as the template for ops where XLA's
-fusion is NOT sufficient.
+  C×C matmul, `S = (x*x) @ B` with `B[i,j] = |i-j| <= r`, so that the window
+  sum rides the MXU; for `beta=0.75` the power is `rsqrt(d)*sqrt(rsqrt(d))`.
+- `local_response_norm_matmul_vjp` (here): the same with a hand-written VJP
+  whose only residual is `x`. The default wherever the kernel pair does not
+  apply. What it costs on the TPU, compiled (PERF.md, PR 29): its backward
+  recomputes the normaliser, but XLA merges that recomputation with the
+  forward's identical product, so the compiled forward fusion has a second,
+  float32 output of the activation's size which the backward reads back; and
+  with the merge stopped the backward writes that float32 tensor itself,
+  between its two band products. 15.7 ms of VGG-F's 53.5 ms step at batch
+  1024, against 4.7 ms of bf16 traffic.
+- `ops/lrn_pallas.py`: one Pallas TPU kernel pass each way, bf16 in and out,
+  called through the view that is a bitcast of the layout XLA keeps the
+  activation in. `lrn()` takes it where it applies (bf16, batch a multiple
+  of 128, on a TPU): 6.4 ms for both sites, both ways (PERF.md, PR 29).
+- `local_response_norm_shift_vjp` (here): 2r+1 shifted slices and adds, a
+  measured non-win (`_band_sum`).
 
 Two parameterizations exist in the wild; both are supported so parity oracles are
 exact:
@@ -241,12 +240,13 @@ def _lrn_matmul_vjp_fwd(x, depth_radius, bias, a, beta):
 
 
 def _lrn_matmul_vjp_bwd(depth_radius, bias, a, beta, res, g):
-    """Hand-derived backward saving NO residuals beyond x (which XLA already
-    keeps for the surrounding conv's backward — so the LRN adds zero HBM
-    residual traffic; d and t are recomputed, one extra cheap band matmul):
+    """Hand-derived backward whose only residual is x; d and t are made
+    again with one more band matmul:
 
         grad_i = g_i * t_i - 2*a*beta * x_i * sum_j B_ij (g_j x_j t_j / d_j)
-    """
+
+    Compiled for the TPU, XLA merges that recomputation with the forward's
+    product and stores d in float32 after all (module docstring)."""
     (x,) = res
     _, d, t = _lrn_mm_core(x, depth_radius, bias, a, beta)
     xf = x.astype(jnp.float32)
@@ -275,11 +275,9 @@ def local_response_norm_matmul_vjp(x: jnp.ndarray,
                                    beta: float = 0.75,
                                    *,
                                    alpha_scaled: bool = False) -> jnp.ndarray:
-    """Banded-matmul LRN with a hand-written VJP (the default training impl;
-    measured ~5% whole-step gain over autodiff of the matmul form on v5e at
-    batch 1024 — autodiff stores a f32 normalizer residual per LRN site, this
-    stores nothing). Not twice-differentiable; use the autodiff forms for
-    higher-order grads."""
+    """Banded-matmul LRN with a hand-written VJP: what `lrn()` falls back to
+    where the kernel pair (`ops/lrn_pallas.py`) does not apply. Not
+    twice-differentiable; use the autodiff forms for higher-order grads."""
     n = 2 * depth_radius + 1
     a = alpha / n if alpha_scaled else alpha
     return _lrn_matmul_vjp(x, depth_radius, float(bias), float(a), float(beta))
@@ -287,11 +285,21 @@ def local_response_norm_matmul_vjp(x: jnp.ndarray,
 
 _IMPL_OVERRIDE: str | None = None
 
+# Call sites of `lrn()` traced so far in this process, by what they lowered
+# to: the fused kernel pair, or an XLA form. A step's builder reads the
+# difference across its own trace (train/step.py, gauges `lrn/fused_sites`
+# and `lrn/fallback_sites`).
+_SITES = {"fused": 0, "fallback": 0}
+
+
+def lrn_site_counts() -> dict:
+    return dict(_SITES)
+
 
 def set_lrn_impl(impl: str | None) -> None:
     """Force an LRN implementation globally: 'shift_vjp' | 'matmul_vjp' |
-    'pallas' | 'matmul' | 'reduce_window' | None (auto: the custom-VJP
-    banded-matmul form, fastest measured — see module docstring)."""
+    'pallas' | 'matmul' | 'reduce_window' | None (auto: the fused kernel pair
+    where it applies, else the custom-VJP banded-matmul form — see `lrn`)."""
     global _IMPL_OVERRIDE
     if impl not in (None, "shift_vjp", "matmul_vjp", "pallas", "matmul",
                     "reduce_window"):
@@ -305,25 +313,39 @@ def lrn(x: jnp.ndarray,
         alpha: float = 1e-4,
         beta: float = 0.75,
         *,
-        alpha_scaled: bool = False) -> jnp.ndarray:
+        alpha_scaled: bool = False,
+        relu_input: bool = False) -> jnp.ndarray:
     """Dispatching LRN over the last axis — what models should call.
+    `relu_input` normalises `relu(x)`: the kernel pair then makes the relu
+    and its gradient's mask in VMEM, where XLA would write the convolution's
+    output twice (before and after the relu) for the pair to read one.
 
-    Auto mode picks the banded-matmul form (fastest measured on TPU v5e — see
-    module docstring; implementation choice is a trace-time Python decision,
-    every branch is jittable on every backend)."""
+    Auto mode decides from what the call can observe, at trace time: a bf16
+    NHWC activation whose batch fills the lanes, on a TPU, takes the fused
+    kernel pair (`ops/lrn_pallas.py`) through the view its channel count
+    selects; anything else (float32 as the fp32 serving tier has, a batch
+    that is not a multiple of 128 as the server's small buckets have, another
+    backend) keeps the XLA banded-matmul form. 'pallas' forces the pair and
+    fails where no view applies."""
+    from distributed_vgg_f_tpu.ops import lrn_pallas
     impl = _IMPL_OVERRIDE
     if impl is None:
-        impl = "matmul_vjp"
+        on_tpu = jax.default_backend() == "tpu" or lrn_pallas.INTERPRET
+        fused = on_tpu and lrn_pallas.fused_view(x.shape, x.dtype) is not None
+        impl = "pallas" if fused else "matmul_vjp"
+    _SITES["fused" if impl == "pallas" else "fallback"] += 1
+    if impl == "pallas":
+        return lrn_pallas.local_response_norm_pallas(
+            x, depth_radius, bias, alpha, beta, alpha_scaled=alpha_scaled,
+            relu_input=relu_input)
+    if relu_input:
+        x = jax.nn.relu(x)
     if impl == "shift_vjp":
         return local_response_norm_shift_vjp(x, depth_radius, bias, alpha,
                                              beta, alpha_scaled=alpha_scaled)
     if impl == "matmul_vjp":
         return local_response_norm_matmul_vjp(x, depth_radius, bias, alpha,
                                               beta, alpha_scaled=alpha_scaled)
-    if impl == "pallas":
-        from distributed_vgg_f_tpu.ops.lrn_pallas import local_response_norm_pallas
-        return local_response_norm_pallas(x, depth_radius, bias, alpha, beta,
-                                          alpha_scaled=alpha_scaled)
     if impl == "matmul":
         return local_response_norm_matmul(x, depth_radius, bias, alpha, beta,
                                           alpha_scaled=alpha_scaled)

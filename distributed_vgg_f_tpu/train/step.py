@@ -23,6 +23,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_vgg_f_tpu.ops.losses import l2_regularization, softmax_cross_entropy
+from distributed_vgg_f_tpu.ops.lrn import lrn_site_counts
 from distributed_vgg_f_tpu.ops.metrics import topk_correct
 from distributed_vgg_f_tpu.parallel.collectives import (
     all_reduce_gradients,
@@ -255,6 +256,10 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     # needs leaf shapes). Read by the trainer's per-window `comm` JSONL
     # block and the comm/* counters below.
     comm_meta: dict = {}
+    # LRN call sites of the traced step by what they lowered to (ops/lrn.py:
+    # the fused kernel pair, or an XLA form), filled at first trace like
+    # comm_meta; gauges lrn/fused_sites and lrn/fallback_sites below.
+    lrn_sites: dict = {}
     num_shards = mesh.shape[data_axis]
     # mesh.reduce_dtype: wire dtype for the gradient sync only (None = the
     # gradients' own fp32). Halves collective bytes at ~16 mantissa bits of
@@ -273,6 +278,7 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             images, labels = batch["image"], batch["label"]
         rng = jax.random.fold_in(base_rng, state.step)
         rng = fold_rng_per_replica(rng, data_axis)
+        lrn_sites_before = lrn_site_counts()
         # The prologue, INSIDE the shard_map body. With on-device
         # augmentation (data/augment.py) the stage is the whole of it: flip,
         # mixup partner and pack on the batch as it arrived, then its own
@@ -646,6 +652,8 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                                   opt_state=new_opt_state,
                                   ema_params=new_ema,
                                   ema_batch_stats=new_ema_bs)
+        lrn_sites.update({kind: count - lrn_sites_before[kind]
+                          for kind, count in lrn_site_counts().items()})
         return new_state, metrics
 
     sharded = shard_map(
@@ -700,12 +708,15 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             reg = telemetry.get_registry()
             reg.set_gauge("comm/buckets_per_step", comm_meta["buckets"])
             reg.set_gauge("comm/bucket_mb", comm_meta["bucket_mb"])
+        for kind, count in lrn_sites.items():
+            telemetry.get_registry().set_gauge(f"lrn/{kind}_sites", count)
         return out
 
     dispatch.lower = jitted.lower
     # the static exchange receipt (trainer JSONL `comm` block, bench rows);
     # empty until the first trace fills it
     dispatch.comm_meta = comm_meta
+    dispatch.lrn_sites = lrn_sites
     return dispatch
 
 

@@ -14,8 +14,11 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -57,17 +60,82 @@ def _compile(fn, chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-# VGG-F's two LRN sites at the bench batch: after conv1 and after conv2.
+# VGG-F's two LRN sites: after conv1 ("sublanes" view) and after conv2
+# ("rows" view).
+_LRN_SITES = {
+    "conv1_54x54x64": dict(inputs=(56, 56, 48), kernel=(3, 3, 48, 64),
+                           padding="VALID", activation=(54, 54, 64)),
+    "conv2_27x27x256": dict(inputs=(27, 27, 64), kernel=(5, 5, 64, 256),
+                            padding="SAME", activation=(27, 27, 256)),
+}
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
-@pytest.mark.parametrize("shape", [(256, 54, 54, 64), (256, 27, 27, 256)],
-                         ids=["conv1_54x54x64", "conv2_27x27x256"])
-def test_lrn_pallas_compiles_for_v5e(chip, shape, backward):
+@pytest.mark.parametrize("site", list(_LRN_SITES))
+def test_lrn_pallas_compiles_for_v5e(chip, site, backward):
     assert not lrn_pallas.INTERPRET
     fn = lrn_pallas.local_response_norm_pallas
     if backward:
         fn = _sum_grad(fn, 1)
-    compiled = _compile(fn, chip, shape)
+    compiled = _compile(fn, chip, (256,) + _LRN_SITES[site]["activation"])
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _entry_instructions(compiled) -> list:
+    """(result type, opcode, whole line) of the entry computation."""
+    text = compiled.as_text()
+    out = []
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", line)
+        if m:
+            out.append((m.group(1), m.group(2), line))
+    return out
+
+
+def _elements(result_type: str) -> int:
+    dims = re.match(r"\w+\[([\d,]*)\]", result_type)
+    return int(np.prod([int(d) for d in dims.group(1).split(",") if d])) \
+        if dims else 0
+
+
+@pytest.mark.parametrize("batch", [1024, 256])
+@pytest.mark.parametrize("site", list(_LRN_SITES))
+def test_lrn_region_keeps_the_convolutions_layout(chip, site, batch):
+    """conv -> relu -> lrn -> pool and its gradient, as `lrn()` lowers it
+    on a TPU: between conv and pool each direction is one kernel, its view a
+    bitcast of what XLA keeps (no copy or transpose of the activation), and
+    no float32 array of the activation's size reaches HBM. The guard for the
+    day XLA picks another layout."""
+    from distributed_vgg_f_tpu.ops.lrn import lrn, set_lrn_impl
+    from distributed_vgg_f_tpu.ops.pooling import maxpool_3x3s2_ceil
+    spec = _LRN_SITES[site]
+
+    def region(x, kernel, bias):
+        y = jax.lax.conv_general_dilated(
+            x, kernel, (1, 1), spec["padding"],
+            dimension_numbers=("NHWC", "HWIO", "NHWC")) + bias
+        return maxpool_3x3s2_ceil(lrn(y, relu_input=True))
+
+    set_lrn_impl("pallas")      # `lrn()` sees the CPU's backend here
+    try:
+        compiled = _compile(_sum_grad(region, 3), chip,
+                            (batch,) + spec["inputs"], spec["kernel"],
+                            spec["kernel"][-1:])
+    finally:
+        set_lrn_impl(None)
+    entry = _entry_instructions(compiled)
+    size = batch * int(np.prod(spec["activation"]))
+    calls = [line for _, op, line in entry
+             if op == "custom-call" and "tpu_custom_call" in line]
+    assert len(calls) == 2, calls
+    moved = [line[:200] for kind, op, line in entry
+             if _elements(kind) == size
+             and (op in ("copy", "transpose")
+                  or re.search(r"calls=%\S*(copy|transpose)", line))]
+    assert not moved, moved
+    wide = [line[:200] for kind, op, line in entry
+            if kind.startswith("f32[") and _elements(kind) == size]
+    assert not wide, wide
 
 
 # (B, T, H, D) at flash_self_attention's default block sizes. 197 tokens is

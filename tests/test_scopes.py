@@ -147,6 +147,41 @@ def test_forward_and_backward_carry_the_names_they_should(lowered):
                    for s in tokens), name
 
 
+def test_fused_lrn_kernels_keep_their_layers_names():
+    """VGG-F's train step with the LRN kernel pair (ops/lrn_pallas.py),
+    lowered for a TPU: each of the four custom calls carries its layer's
+    name, forward and backward, and the benchmark's reader
+    (`chipbench/scope_reduce.py`) gives it to `lrn1` / `lrn2`."""
+    from chipbench import scope_reduce
+    from distributed_vgg_f_tpu.ops.lrn import set_lrn_impl
+    rows = 128                  # a batch that fills the lanes
+    cfg = apply_overrides(get_config("vggf_imagenet_dp"), {
+        "data.image_size": SIZE, "model.num_classes": 10,
+        "data.global_batch_size": rows, "mesh.num_data": 1})
+    mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
+                      jax.devices()[:1])
+    trainer = Trainer(cfg, mesh=mesh, logger=MetricLogger(stream=io.StringIO()))
+    state = jax.eval_shape(trainer.init_state)
+    batch = {"image": jax.ShapeDtypeStruct((rows, SIZE, SIZE, 3), jnp.uint8),
+             "label": jax.ShapeDtypeStruct((rows,), jnp.int32)}
+    set_lrn_impl("pallas")      # `lrn()` sees the CPU's backend here
+    try:
+        text = trainer.train_step.__wrapped__.trace(
+            state, batch, trainer.base_rng()).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=True)
+    finally:
+        set_lrn_impl(None)
+    assert trainer.train_step.lrn_sites == {"fused": 2, "fallback": 0}
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = [named[loc] for loc in re.findall(
+        r"custom_call @tpu_custom_call.*loc\((#loc\d+)\)", text)]
+    names = scope_reduce.declared()
+    found = sorted(scope_reduce.scope_of("jit(train_step)/" + call + ":",
+                                         names) for call in calls)
+    assert found == [("lrn1", False), ("lrn1", True),
+                     ("lrn2", False), ("lrn2", True)], calls
+
+
 def test_jitted_steps_are_named_for_what_they_are(lowered):
     """The module's name is what a trace's `XLA Modules` line shows and,
     unlike the scopes, part of the persistent compile cache's key."""
