@@ -60,7 +60,11 @@ def main() -> int:
     import numpy as np
     import optax
 
-    from distributed_vgg_f_tpu.config import AugmentConfig, ModelConfig
+    from distributed_vgg_f_tpu.config import (
+        AugmentConfig,
+        MeshConfig,
+        ModelConfig,
+    )
     from distributed_vgg_f_tpu.data.augment import make_device_augment
     from distributed_vgg_f_tpu.data.device_ingest import make_device_finish
     from distributed_vgg_f_tpu.models import build_model
@@ -74,6 +78,7 @@ def main() -> int:
         build_mesh,
         shard_host_batch,
     )
+    from distributed_vgg_f_tpu.parallel.zero import plan_exchange
     from distributed_vgg_f_tpu.train.state import TrainState
     from distributed_vgg_f_tpu.train.step import build_train_step
 
@@ -108,7 +113,7 @@ def main() -> int:
         # a finish of its own); augment-off packs in the finish — each
         # column runs ITS production configuration
         step = build_train_step(
-            model, tx, mesh, weight_decay=5e-4,
+            model, mesh, 5e-4, plan_exchange(MeshConfig(), mesh, tx),
             device_finish=None if with_augment else finish_s2d,
             device_augment=augment if with_augment else None)
         return state, step

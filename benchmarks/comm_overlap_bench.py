@@ -89,24 +89,16 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
     import optax
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from distributed_vgg_f_tpu.config import ModelConfig
+    from distributed_vgg_f_tpu.config import MeshConfig, ModelConfig
     from distributed_vgg_f_tpu.models import build_model
-    from distributed_vgg_f_tpu.parallel.buckets import (
-        build_bucket_layout,
-        hlo_overlap_report,
-    )
+    from distributed_vgg_f_tpu.parallel.buckets import hlo_overlap_report
     from distributed_vgg_f_tpu.parallel.mesh import (
         MeshSpec,
         build_mesh,
         shard_host_batch,
     )
-    from distributed_vgg_f_tpu.parallel.zero import (
-        flat_param_count,
-        padded_flat_size,
-        train_state_specs,
-    )
+    from distributed_vgg_f_tpu.parallel.zero import plan_exchange
     from distributed_vgg_f_tpu.train.state import TrainState
     from distributed_vgg_f_tpu.train.step import build_train_step
 
@@ -122,47 +114,25 @@ def main() -> int:
     sample = jnp.zeros((1, args.image_size, args.image_size, 3), jnp.float32)
 
     def make(bucket_mb: float):
-        layout = None
-        specs = None
-        p_struct = None
-        if zero:
+        plan = plan_exchange(
+            MeshConfig(shard_opt_state=zero,
+                       shard_gradients=args.sharding in ("zero2", "zero3"),
+                       shard_params=zero3,
+                       comm_bucket_mb=bucket_mb),
+            mesh, tx, grad_accum_steps=args.grad_accum)
+        if plan.sharded:
             shapes = jax.eval_shape(
-                lambda r: TrainState.create(model, tx, r, sample,
-                                            zero1_shards=n_dev),
+                lambda r: TrainState.create(model, tx, r, sample),
                 jax.random.key(0))
-            p_struct = shapes.params  # the params TREE geometry (zero3)
-            if bucket_mb > 0:
-                layout = build_bucket_layout(
-                    shapes.params, n_dev, int(bucket_mb * 1024 * 1024))
-                padded = layout.total_padded
-            else:
-                padded = padded_flat_size(
-                    flat_param_count(shapes.params), n_dev)
-
-            def create(r):
-                return TrainState.create(model, tx, r, sample,
-                                         zero1_shards=n_dev,
-                                         bucket_layout=layout,
-                                         shard_params=zero3)
-
-            shapes = jax.eval_shape(create, jax.random.key(0))
-            specs = train_state_specs(shapes, padded, "data",
-                                      shard_params=zero3)
-            shardings = jax.tree.map(
-                lambda s: NamedSharding(mesh, s), specs,
-                is_leaf=lambda x: isinstance(x, P))
-            state = jax.jit(create,
-                            out_shardings=shardings)(jax.random.key(0))
+            plan = plan.bind(shapes.params, shapes.batch_stats)
+            shardings = plan.state_shardings(mesh)
+            state = jax.jit(
+                lambda r: TrainState.create(model, tx, r, sample,
+                                            exchange=plan),
+                out_shardings=shardings)(jax.random.key(0))
         else:
             state = TrainState.create(model, tx, jax.random.key(0), sample)
-        step = build_train_step(
-            model, tx, mesh, weight_decay=5e-4, zero1=zero,
-            state_specs=specs, grad_accum_steps=args.grad_accum,
-            shard_gradients=args.sharding in ("zero2", "zero3"),
-            shard_params=zero3,
-            params_struct=p_struct if zero3 else None,
-            comm_bucket_mb=bucket_mb)
-        return state, step
+        return state, build_train_step(model, mesh, 5e-4, plan)
 
     rng0 = np.random.default_rng(0)
     batch = shard_host_batch(

@@ -444,7 +444,7 @@ def phase_mesh4(sets=(), steps: int = 3) -> None:
         tr = four["tr"]
         quarter_on_each(four["batch"]["image"], "the batch")
         flat = [x for x in jax.tree_util.tree_leaves(four["state"].opt_state)
-                if x.ndim == 1 and x.shape[0] == tr._padded]
+                if x.ndim == 1 and x.shape[0] == tr.exchange.total_padded]
         check(bool(flat), "no flat optimiser vector in the ZeRO state")
         for x in flat:
             quarter_on_each(x, "a flat optimiser shard")

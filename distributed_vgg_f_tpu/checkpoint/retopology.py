@@ -33,82 +33,62 @@ refuses with a typed GeometryReceiptError, never a shape error.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Optional
 
 import jax
 
 from distributed_vgg_f_tpu.parallel.zero import (
+    Exchange,
     convert_opt_state,
     convert_params,
     flat_param_count,
+    layout_from_receipt,
     opt_state_layout,
     params_layout,
 )
+from distributed_vgg_f_tpu.resilience.errors import GeometryReceiptError
 
 
-def restore_any_topology(manager, template, tx, *,
-                         opt_shardings: Any,
-                         target_padded: Optional[int],
-                         step: Optional[int] = None,
-                         target_bucket_layout: Any = None,
-                         params_tree_struct: Any = None,
-                         params_shardings: Any = None) -> tuple:
+def restore_any_topology(manager, template, target: Exchange, *,
+                         step: Optional[int] = None) -> tuple:
     """Restore `manager`'s checkpoint into `template`'s topology and layout.
 
-    - `template`: concrete TrainState initialized for the CURRENT run (its
-      shardings define the target topology).
-    - `opt_shardings`: sharding (tree or single) for the target opt state —
-      the trainer's `_state_sharding().opt_state` under ZeRO-1, its
-      replicated sharding otherwise.
-    - `target_padded`: ZeRO-1 padded flat length for the current shard count
-      (the bucket layout's `total_padded` under the bucketed exchange), or
-      None for the replicated layout.
-    - `target_bucket_layout` (r14): the current run's
-      parallel/buckets.GradBucketLayout when the bucketed ZeRO exchange is
-      on — the saved vector is then PERMUTED into the bucket-major frame,
-      not just re-padded. The saved side's geometry comes from the
-      `opt_layout` receipt the trainer writes into every checkpoint's
-      `extra`; absent receipt = the canonical ZeRO-1 layout (true for
-      every pre-r14 checkpoint).
-    - `params_tree_struct` (r21, ZeRO-3): the params TREE geometry. Required
-      when `template.params` is the ZeRO-3 flat shard vector (the tree is
-      no longer recoverable from the template); under it, saved params/EMA
-      in ANY layout — replicated tree, canonical flat, bucket-major flat,
-      any shard count — are converted to the template's layout exactly like
-      the opt state (same receipts, same typed refusals). None keeps the
-      pre-r21 behavior: params restore as the tree they are.
-    - `params_shardings` (r21): target sharding for params (and EMA) when
-      they need layout conversion — the trainer's
-      `_state_sharding().params` under ZeRO-3. None = replicated.
+    - `template`: concrete TrainState initialized for the CURRENT run: its
+      shardings define the target topology.
+    - `target`: the current run's exchange plan (parallel/zero.py): the
+      layout the template's opt state (and, under ZeRO-3, its params and
+      EMA) is in, the params TREE geometry, the optimizer. The saved side's
+      geometry comes from the receipts the trainer writes into every
+      checkpoint's `extra` (`Exchange.receipts`): `opt_layout` for a
+      bucket-major flat vector (absent = the canonical layout, true for
+      every pre-r14 checkpoint), `param_layout` for flat params. Saved
+      state in ANY layout — replicated tree, canonical flat, bucket-major
+      flat, any shard count — is converted to the template's, with typed
+      refusals.
 
     Returns `(state, extra)` like `manager.restore`.
     """
     step = step if step is not None else manager.best_step()
+    # a dp plan meets the parameter shapes here at the latest
+    target = target.bind(template.params)
+    params_struct = target.params_struct
     saved_meta = manager.state_metadata(step)
     saved_opt_meta = saved_meta["opt_state"]
     saved_shapes = [tuple(l.shape) for l in jax.tree.leaves(saved_opt_meta)]
     tmpl_shapes = [tuple(l.shape) for l in jax.tree.leaves(template.opt_state)]
-    params_struct = (params_tree_struct if params_tree_struct is not None
-                     else jax.eval_shape(lambda p: p, template.params))
     total = flat_param_count(params_struct)
     layout, padded_src = opt_state_layout(saved_opt_meta, total)
     # The saved FLAT layout's geometry receipt: same-shape vectors can
     # still be differently PERMUTED (canonical vs bucket-major, or two
     # bucket sizes whose totals coincide) — shapes alone cannot
     # disambiguate, the receipt can.
-    src_bucket_layout = None
     saved_layout_receipt = None
     if layout == "flat":
         saved_layout_receipt = (manager.extra_at(step) or {}).get(
             "opt_layout")
         if saved_layout_receipt is not None:
-            from distributed_vgg_f_tpu.parallel.buckets import (
-                layout_from_receipt)
-            from distributed_vgg_f_tpu.resilience.errors import (
-                GeometryReceiptError)
             try:
-                src_bucket_layout = layout_from_receipt(
-                    params_struct, saved_layout_receipt)
+                layout_from_receipt(params_struct, saved_layout_receipt)
             except ValueError as e:
                 # r19: a receipt that names a non-reproducing geometry is
                 # WRONG LAYOUT, not corrupt bytes — the typed class lets
@@ -118,24 +98,20 @@ def restore_any_topology(manager, template, tx, *,
                 raise GeometryReceiptError(
                     f"opt-layout receipt at step {step} does not describe "
                     f"this run's geometry: {e}") from e
-    target_layout_receipt = (target_bucket_layout.describe()
-                             if target_bucket_layout is not None else None)
+    target_receipts = target.receipts()
+    target_layout_receipt = target_receipts.get("opt_layout")
 
     # -- params side (r21): detect the SAVED params layout (replicated tree
     # vs ZeRO-3 flat) and the template's, plus the `param_layout` receipt
     # that disambiguates canonical vs bucket-major flat (same shapes,
     # different permutation — exactly the opt-state ambiguity).
-    from distributed_vgg_f_tpu.resilience.errors import GeometryReceiptError
     saved_p_meta = saved_meta["params"]
     saved_p_shapes = [tuple(l.shape) for l in jax.tree.leaves(saved_p_meta)]
     tmpl_p_shapes = [tuple(l.shape)
                      for l in jax.tree.leaves(template.params)]
     s_p_layout, s_p_padded = params_layout(saved_p_meta, total)
-    t_p_layout, t_p_padded = (params_layout(template.params, total)
-                              if params_tree_struct is not None
-                              else ("tree", None))
     saved_param_receipt = None
-    src_param_bucket = None
+    param_source = None
     if s_p_layout == "flat":
         saved_param_receipt = (manager.extra_at(step) or {}).get(
             "param_layout")
@@ -149,12 +125,12 @@ def restore_any_topology(manager, template, tx, *,
         if kind == "bucketed_flat":
             # a bucketed flat params vector always rides with the bucketed
             # opt vector — ONE layout, described once by the opt receipt
-            if src_bucket_layout is None:
+            if saved_layout_receipt is None:
                 raise GeometryReceiptError(
                     f"param-layout receipt at step {step} says "
                     f"'bucketed_flat' but no opt-layout receipt describes "
                     f"the bucket geometry — cannot invert the permutation")
-            src_param_bucket = src_bucket_layout
+            param_source = saved_layout_receipt
     elif (manager.extra_at(step) or {}).get("param_layout") is not None:
         raise GeometryReceiptError(
             f"param-layout receipt present at step {step} but the saved "
@@ -169,9 +145,9 @@ def restore_any_topology(manager, template, tx, *,
     if s_p_layout == "flat":
         saved_p_key = ((saved_param_receipt or {}).get(
             "kind", "canonical_flat"), s_p_padded)
-    if t_p_layout == "flat":
-        target_p_key = (("bucketed_flat" if target_bucket_layout is not None
-                         else "canonical_flat"), t_p_padded)
+    if target.zero3:
+        target_p_key = (target_receipts["param_layout"]["kind"],
+                        target.total_padded)
     params_match = (saved_p_shapes == tmpl_p_shapes
                     and saved_p_key == target_p_key
                     and (saved_layout_receipt == target_layout_receipt
@@ -185,9 +161,10 @@ def restore_any_topology(manager, template, tx, *,
     # -- layout mismatch: rebuild the SAVED opt-state structure abstractly
     if layout == "flat":
         src_struct = jax.eval_shape(
-            tx.init, jax.ShapeDtypeStruct((padded_src,), jax.numpy.float32))
+            target.tx.init,
+            jax.ShapeDtypeStruct((padded_src,), jax.numpy.float32))
     else:
-        src_struct = jax.eval_shape(tx.init, params_struct)
+        src_struct = jax.eval_shape(target.tx.init, params_struct)
     src_shapes = [tuple(l.shape) for l in jax.tree.leaves(src_struct)]
     if src_shapes != saved_shapes:
         raise ValueError(
@@ -226,30 +203,20 @@ def restore_any_topology(manager, template, tx, *,
                         else template.ema_params))
     restored, extra = manager.restore(saved_template, step)
 
-    # convert the layout inside jit: out_shardings place the result straight
-    # into the target topology
+    # convert the layout inside jit: out_shardings (the template's own)
+    # place the result straight into the target topology
+    shardings_of = lambda tree: jax.tree.map(lambda l: l.sharding, tree)
     convert = jax.jit(
-        functools.partial(convert_opt_state, tx=tx,
-                          params_struct=params_struct,
-                          target_padded=target_padded,
-                          src_bucket_layout=src_bucket_layout,
-                          target_bucket_layout=target_bucket_layout),
-        out_shardings=opt_shardings)
-    new_opt = convert(restored.opt_state)
-    out = restored.replace(opt_state=new_opt)
+        functools.partial(convert_opt_state, source=saved_layout_receipt,
+                          target=target),
+        out_shardings=shardings_of(template.opt_state))
+    out = restored.replace(opt_state=convert(restored.opt_state))
     if not params_match:
-        p_shardings = (params_shardings if params_shardings is not None
-                       else replicated)
         conv_p = jax.jit(
-            functools.partial(
-                convert_params, params_struct=params_struct,
-                target_padded=(t_p_padded if t_p_layout == "flat" else None),
-                src_bucket_layout=src_param_bucket,
-                target_bucket_layout=(target_bucket_layout
-                                      if t_p_layout == "flat" else None)),
-            out_shardings=p_shardings)
-        new_params = conv_p(restored.params)
+            functools.partial(convert_params, source=param_source,
+                              target=target),
+            out_shardings=shardings_of(template.params))
         new_ema = (conv_p(restored.ema_params)
                    if template.ema_params is not None else restored.ema_params)
-        out = out.replace(params=new_params, ema_params=new_ema)
+        out = out.replace(params=conv_p(restored.params), ema_params=new_ema)
     return out, extra

@@ -773,14 +773,13 @@ class MeshConfig:
         receipt uses. The receipt reports the EFFECTIVE basis, which can
         downgrade below this label (single-shard meshes drop zero1, and
         `shard_gradients` without `shard_opt_state` has no 1/N frame to
-        live in — mirroring the trainer's downgrade, so the
+        live in — as the exchange plan has it, parallel/zero.py, so the
         README-documented `--set mesh.shard_opt_state=false` toggle stays
         valid on presets that ship ZeRO-2/3). Receipts/sentinel rows must
         key on the runtime `comm` block, not this property."""
         from distributed_vgg_f_tpu.parallel.buckets import sharding_basis
-        zero1 = self.shard_opt_state
-        zero2 = zero1 and self.shard_gradients
-        return sharding_basis(zero1, zero2, zero2 and self.shard_params)
+        return sharding_basis(self.shard_opt_state, self.shard_gradients,
+                              self.shard_params)
 
 
 @dataclass(frozen=True)

@@ -707,6 +707,12 @@ class _NativeJpegBase:
     async device_put whose lifetime the ring cannot see). Bench-only.
     """
 
+    #: Batches the native workers may have decoded beyond the last one
+    #: handed out (native/jpeg_loader.cc `kDepth`: an item is claimed while
+    #: its batch index is less than `kDepth` past the consumer's) — so the
+    #: counters (`decode_errors`) run ahead of the consumer by up to this.
+    decode_ahead_batches = 3
+
     def __init__(self, lib, batch: int, image_size: int, image_dtype: str):
         self._lib = lib
         self.batch = int(batch)

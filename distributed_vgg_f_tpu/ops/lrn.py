@@ -7,12 +7,11 @@ implemented directly. The implementations in this package:
 - `local_response_norm` (here): squared-sum over a sliding channel window via
   `lax.reduce_window`. Exact fp32 numerics — this is the test oracle. On the
   TPU its windows cross the 128-lane axis and `**0.75` lowers to exp/log.
-- `local_response_norm_matmul` (here): the channel-window sum recast as a banded
-  C×C matmul, `S = (x*x) @ B` with `B[i,j] = |i-j| <= r`, so that the window
-  sum rides the MXU; for `beta=0.75` the power is `rsqrt(d)*sqrt(rsqrt(d))`.
-- `local_response_norm_matmul_vjp` (here): the same with a hand-written VJP
-  whose only residual is `x`. The default wherever the kernel pair does not
-  apply. What it costs on the TPU, compiled (PERF.md, PR 29): its backward
+- `local_response_norm_matmul_vjp` (here): the channel-window sum recast as
+  a banded C×C matmul, `S = (x*x) @ B` with `B[i,j] = |i-j| <= r`, so that
+  the window sum rides the MXU; for `beta=0.75` the power is
+  `rsqrt(d)*sqrt(rsqrt(d))`; with a hand-written VJP whose only residual is
+  `x`. The default wherever the kernel pair does not apply. What it costs on the TPU, compiled (PERF.md, PR 29): its backward
   recomputes the normaliser, but XLA merges that recomputation with the
   forward's identical product, so the compiled forward fusion has a second,
   float32 output of the activation's size which the backward reads back; and
@@ -23,8 +22,12 @@ implemented directly. The implementations in this package:
   called through the view that is a bitcast of the layout XLA keeps the
   activation in. `lrn()` takes it where it applies (bf16, batch a multiple
   of 128, on a TPU): 6.4 ms for both sites, both ways (PERF.md, PR 29).
-- `local_response_norm_shift_vjp` (here): 2r+1 shifted slices and adds, a
-  measured non-win (`_band_sum`).
+
+Measured and gone (PR 30): the window sum as 2r+1 shifted slices and adds made
+the VGG-F step 74.2 ms against the band product's 50.1 at batch 1024 on a v5e
+(offset slices in the lane dimension cost the VPU per-element rotations, while
+the MXU eats the band's redundant FLOPs at HBM speed), and the band product
+under plain autodiff kept more residuals than the hand VJP.
 
 Two parameterizations exist in the wild; both are supported so parity oracles are
 exact:
@@ -103,104 +106,6 @@ def _pow_neg_beta(d: jnp.ndarray, beta: float) -> jnp.ndarray:
     if beta == 0.5:
         return lax.rsqrt(d)
     return d ** -beta
-
-
-def local_response_norm_matmul(x: jnp.ndarray,
-                               depth_radius: int = 2,
-                               bias: float = 2.0,
-                               alpha: float = 1e-4,
-                               beta: float = 0.75,
-                               *,
-                               alpha_scaled: bool = False) -> jnp.ndarray:
-    """LRN with the window sum as a banded matmul over the channel (last) axis.
-
-    Identical math to `local_response_norm` (window sums of x² are the same
-    fp32 values, matmul accumulates in fp32); only the power computation differs
-    (`_pow_neg_beta` fast path), measured < 2e-5 relative vs the oracle."""
-    n = 2 * depth_radius + 1
-    a = alpha / n if alpha_scaled else alpha
-    band = band_matrix(x.shape[-1], depth_radius)
-    xf = x.astype(jnp.float32)
-    sums = lax.dot_general(xf * xf, band, (((x.ndim - 1,), (0,)), ((), ())),
-                           precision=lax.Precision.HIGHEST)
-    scale = _pow_neg_beta(bias + a * sums, beta)
-    return (xf * scale).astype(x.dtype)
-
-
-def _band_sum(sq: jnp.ndarray, depth_radius: int) -> jnp.ndarray:
-    """Channel-window sum via 2r+1 shifted slices + adds.
-
-    MEASURED NON-WIN on TPU v5e (kept as the counter-example): although the
-    banded matmul does C× the useful FLOPs (band width 5 vs C=64/256 columns)
-    and profiling put its dot_generals at ~32% of the VGG-F step, replacing
-    them with these slice-adds made the whole step 74.2 vs 50.1 ms/step
-    (batch 1024). Offset slices in the minor (lane) dimension force per-element
-    lane rotations on the VPU — exactly the shuffle cost that sank the
-    reduce_window form — while the MXU eats the redundant band FLOPs at
-    HBM-bandwidth-bound speed. TPU lesson twice confirmed: prefer dense MXU
-    work over lane-crossing data movement, even at 50× the arithmetic."""
-    c = sq.shape[-1]
-    r = depth_radius
-    padded = jnp.pad(sq, [(0, 0)] * (sq.ndim - 1) + [(r, r)])
-    out = None
-    for k in range(2 * r + 1):
-        s = lax.slice_in_dim(padded, k, k + c, axis=-1)
-        out = s if out is None else out + s
-    return out
-
-
-def _lrn_shift_core(x: jnp.ndarray, depth_radius: int, bias: float, a: float,
-                    beta: float):
-    """Shared fwd math for the shifted-slice LRN: exact f32 window sums."""
-    xf = x.astype(jnp.float32)
-    S = _band_sum(xf * xf, depth_radius)
-    d = bias + a * S
-    t = _pow_neg_beta(d, beta)
-    return (xf * t).astype(x.dtype), d, t
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def _lrn_shift_vjp(x, depth_radius, bias, a, beta):
-    return _lrn_shift_core(x, depth_radius, bias, a, beta)[0]
-
-
-def _lrn_shift_vjp_fwd(x, depth_radius, bias, a, beta):
-    out, _, _ = _lrn_shift_core(x, depth_radius, bias, a, beta)
-    return out, (x,)
-
-
-def _lrn_shift_vjp_bwd(depth_radius, bias, a, beta, res, g):
-    """Residual-free backward (same derivation as the matmul form — the band
-    is symmetric, so the adjoint window sum is the same `_band_sum`):
-
-        grad_i = g_i * t_i - 2*a*beta * x_i * sum_j B_ij (g_j x_j t_j / d_j)
-    """
-    (x,) = res
-    _, d, t = _lrn_shift_core(x, depth_radius, bias, a, beta)
-    xf = x.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
-    v = _band_sum(gf * xf * (t / d), depth_radius)
-    grad = gf * t - 2.0 * a * beta * xf * v
-    return (grad.astype(x.dtype),)
-
-
-_lrn_shift_vjp.defvjp(_lrn_shift_vjp_fwd, _lrn_shift_vjp_bwd)
-
-
-def local_response_norm_shift_vjp(x: jnp.ndarray,
-                                  depth_radius: int = 2,
-                                  bias: float = 2.0,
-                                  alpha: float = 1e-4,
-                                  beta: float = 0.75,
-                                  *,
-                                  alpha_scaled: bool = False) -> jnp.ndarray:
-    """Shifted-slice LRN with the residual-free hand VJP. Exact f32 window
-    sums, but a measured NON-WIN vs the banded matmul on TPU (see `_band_sum`
-    docstring) — kept for oracle cross-checks and non-TPU backends. Not
-    twice-differentiable; use the autodiff forms for higher-order grads."""
-    n = 2 * depth_radius + 1
-    a = alpha / n if alpha_scaled else alpha
-    return _lrn_shift_vjp(x, depth_radius, float(bias), float(a), float(beta))
 
 
 def _lrn_mm_core(x: jnp.ndarray, depth_radius: int, bias: float, a: float,
@@ -297,12 +202,11 @@ def lrn_site_counts() -> dict:
 
 
 def set_lrn_impl(impl: str | None) -> None:
-    """Force an LRN implementation globally: 'shift_vjp' | 'matmul_vjp' |
-    'pallas' | 'matmul' | 'reduce_window' | None (auto: the fused kernel pair
-    where it applies, else the custom-VJP banded-matmul form — see `lrn`)."""
+    """Force an LRN implementation globally: 'matmul_vjp' | 'pallas' |
+    'reduce_window' | None (auto: the fused kernel pair where it applies,
+    else the custom-VJP banded-matmul form — see `lrn`)."""
     global _IMPL_OVERRIDE
-    if impl not in (None, "shift_vjp", "matmul_vjp", "pallas", "matmul",
-                    "reduce_window"):
+    if impl not in (None, "matmul_vjp", "pallas", "reduce_window"):
         raise ValueError(f"unknown LRN impl: {impl!r}")
     _IMPL_OVERRIDE = impl
 
@@ -340,14 +244,8 @@ def lrn(x: jnp.ndarray,
             relu_input=relu_input)
     if relu_input:
         x = jax.nn.relu(x)
-    if impl == "shift_vjp":
-        return local_response_norm_shift_vjp(x, depth_radius, bias, alpha,
-                                             beta, alpha_scaled=alpha_scaled)
     if impl == "matmul_vjp":
         return local_response_norm_matmul_vjp(x, depth_radius, bias, alpha,
                                               beta, alpha_scaled=alpha_scaled)
-    if impl == "matmul":
-        return local_response_norm_matmul(x, depth_radius, bias, alpha, beta,
-                                          alpha_scaled=alpha_scaled)
     return local_response_norm(x, depth_radius, bias, alpha, beta,
                                alpha_scaled=alpha_scaled)

@@ -270,25 +270,13 @@ class GradBucketLayout:
             off += s_b
         return self.unflatten(self._leaves_from_bucket_vectors(vecs))
 
-    # ------------------------------------------------------------- receipts
-    def wire_bytes_per_step(self, *, zero: bool, wire_dtype=None,
-                            shard_params: bool = False) -> Dict[str, int]:
-        """Logical collective payload bytes per step per replica — the ONE
-        accounting (`exchange_wire_bytes`) the monolithic paths share, so
-        the bucketed and unbucketed comm receipts can never drift (bucketing
-        changes the message schedule, never the byte totals)."""
-        return exchange_wire_bytes(sum(self.bucket_sizes()),
-                                   self.total_padded, zero=zero,
-                                   wire_dtype=wire_dtype,
-                                   shard_params=shard_params)
-
 
 def sharding_basis(zero1: bool, shard_gradients: bool,
                    shard_params: bool = False) -> str:
     """THE (dp | zero1 | zero2 | zero3) basis derivation — the single
-    source for the step's comm_meta receipt (which reports the EFFECTIVE
-    basis after the trainer's single-shard downgrade) and
-    config.MeshConfig's CONFIGURED label. The ladder is cumulative:
+    source for the exchange plan's EFFECTIVE basis (`zero.plan_exchange`,
+    after its single-shard downgrade; the step's comm_meta receipt reports
+    it) and config.MeshConfig's CONFIGURED label. The ladder is cumulative:
     zero3 implies zero2 implies zero1 (config validation enforces it;
     callers pass the post-downgrade flags)."""
     if zero1 and shard_gradients and shard_params:
@@ -310,9 +298,8 @@ def exchange_wire_bytes(n_elem: int, padded_total: int, *, zero: bool,
     but the gather is the just-in-time pre-forward param fetch and rides
     the wire dtype (the gathered replica is a step transient, not
     persistent state) — under a narrowed wire ZeRO-3 is the only basis
-    whose BOTH legs shrink. Shared by the bucketed layout's
-    `wire_bytes_per_step` and the monolithic paths in train/step.py —
-    one accounting, no drift."""
+    whose BOTH legs shrink. One accounting for both layouts
+    (`zero.Exchange.comm_meta`)."""
     wire_itemsize = (jnp.dtype(wire_dtype).itemsize
                      if wire_dtype is not None else 4)
     if not zero:

@@ -48,7 +48,7 @@ the `elastic_degraded_restart` flight class — never `unhandled_exception`.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Iterator, Optional, Sequence
+from typing import Iterator, Sequence
 
 import jax
 import numpy as np
@@ -163,73 +163,57 @@ def shrink_mesh(mesh: Mesh, data_axis: str, plan: ResizePlan) -> Mesh:
     return Mesh(dev_array, axis_names=tuple(mesh.axis_names))
 
 
-def reshard_train_state(state, tx, *, params_struct,
-                        target_padded: Optional[int],
-                        src_bucket_layout: Any,
-                        target_bucket_layout: Any,
-                        replicated, opt_shardings,
-                        target_params_padded: Optional[int] = None,
-                        params_shardings: Any = None):
-    """Live any-geometry reshard of a TrainState onto a new mesh.
+def reshard_train_state(state, source, target, mesh: Mesh):
+    """Live any-geometry reshard of a TrainState from the exchange plan
+    `source` (parallel/zero.py `Exchange`, the one it was trained under)
+    to `target`'s layout on the new `mesh`.
 
     The state is first pulled to host as its GLOBAL value (on a
     single-controller mesh every shard is addressable; `plan_resize`
     refused anything else — a multi-host fleet reads the same global view
     out of the forced preemption checkpoint via retopology restore). The
     opt state then flows through the SAME pure converter the checkpoint
-    path uses (`zero.convert_opt_state`, src/target bucket-layout receipts
-    included) under jit whose `out_shardings` place the result directly
-    into the new topology. Params and EMA (r21): replicated trees re-place
-    with one `device_put`; ZeRO-3 flat vectors flow through the matching
-    `zero.convert_params` (the N→M re-interleave is a real permutation
-    when bucketed, a re-pad when canonical) onto `params_shardings` —
-    `target_params_padded` None means the new topology holds params as the
-    replicated tree (the zero3 → zero2/dp downgrade, e.g. a resize to one
-    shard). Step/batch_stats are replicated in ALL layouts. Both the
-    elastic path and a restart control therefore apply the identical
-    conversion — which is what makes the chaos-grid trajectory equality a
-    meaningful pin rather than a coincidence."""
+    path uses (`zero.convert_opt_state`) under jit whose `out_shardings`
+    place the result directly into the new topology. Params and EMA (r21):
+    replicated trees re-place with one `device_put`; ZeRO-3 flat vectors
+    flow through the matching `zero.convert_params` (the N→M re-interleave
+    is a real permutation when bucketed, a re-pad when canonical) — also
+    back to the replicated tree where the new topology holds them so (the
+    zero3 → zero2/dp downgrade, e.g. a resize to one shard).
+    Step/batch_stats are replicated in ALL layouts. Both the elastic path
+    and a restart control therefore apply the identical conversion — which
+    is what makes the chaos-grid trajectory equality a meaningful pin
+    rather than a coincidence."""
     import functools
 
-    from distributed_vgg_f_tpu.parallel.zero import (convert_opt_state,
-                                                     convert_params,
-                                                     flat_param_count,
-                                                     params_layout)
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from distributed_vgg_f_tpu.parallel.zero import (convert_opt_state,
+                                                     convert_params)
+
+    # the params TREE geometry is a function of the model alone; a dp
+    # target that has not traced yet takes it from the source or the state
+    target = target.bind(source.params_struct if source.sharded
+                         else state.params)
+    replicated = NamedSharding(mesh, P())
+    shardings = target.state_shardings(mesh)
     host_state = jax.device_get(state)
-    convert = jax.jit(
-        functools.partial(convert_opt_state, tx=tx,
-                          params_struct=params_struct,
-                          target_padded=target_padded,
-                          src_bucket_layout=src_bucket_layout,
-                          target_bucket_layout=target_bucket_layout),
-        out_shardings=opt_shardings)
-    new_opt = convert(host_state.opt_state)
-    src_p_layout, _ = params_layout(host_state.params,
-                                    flat_param_count(params_struct))
-    if src_p_layout == "flat" or target_params_padded is not None:
+    new_opt = jax.jit(
+        functools.partial(convert_opt_state, source=source, target=target),
+        out_shardings=(shardings.opt_state if target.sharded
+                       else replicated))(host_state.opt_state)
+    converted = {"opt_state": new_opt}
+    if source.zero3 or target.zero3:
         conv_p = jax.jit(
-            functools.partial(convert_params, params_struct=params_struct,
-                              target_padded=target_params_padded,
-                              src_bucket_layout=src_bucket_layout,
-                              target_bucket_layout=(
-                                  target_bucket_layout
-                                  if target_params_padded is not None
-                                  else None)),
-            out_shardings=(params_shardings
-                           if params_shardings is not None else replicated))
-        new_params = conv_p(host_state.params)
-        new_ema = (conv_p(host_state.ema_params)
-                   if host_state.ema_params is not None
-                   else host_state.ema_params)
-        host_state = host_state.replace(params=None, ema_params=None)
-        placed = jax.tree.map(lambda l: jax.device_put(l, replicated),
-                              host_state.replace(opt_state=None))
-        return placed.replace(opt_state=new_opt, params=new_params,
-                              ema_params=new_ema)
+            functools.partial(convert_params, source=source, target=target),
+            out_shardings=shardings.params if target.zero3 else replicated)
+        converted["params"] = conv_p(host_state.params)
+        converted["ema_params"] = (
+            conv_p(host_state.ema_params)
+            if host_state.ema_params is not None else None)
     placed = jax.tree.map(lambda l: jax.device_put(l, replicated),
-                          host_state.replace(opt_state=None))
-    return placed.replace(opt_state=new_opt)
+                          host_state.replace(**{k: None for k in converted}))
+    return placed.replace(**converted)
 
 
 def trim_batches(source: Iterator, plan: ResizePlan,

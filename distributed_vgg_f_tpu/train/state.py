@@ -30,42 +30,20 @@ class TrainState:
 
     @classmethod
     def create(cls, model, tx, rng: jax.Array, sample_input: jnp.ndarray,
-               *, zero1_shards: int = 0, ema: bool = False,
-               bucket_layout=None, shard_params: bool = False) -> "TrainState":
-        """`zero1_shards > 1` initializes the optimizer state over the padded
-        flat parameter vector instead of the params pytree — the ZeRO-1 layout
-        (parallel/zero.py) whose vector leaves are then sharded over the data
-        axis. `bucket_layout` (parallel/buckets.GradBucketLayout, r14) swaps
-        that vector for the bucket-major replica-interleaved layout the
-        bucketed exchange scatters into — same length semantics, permuted
-        elements; must be the SAME layout the train step builds.
-        `shard_params=True` (ZeRO-3, r21; requires `zero1_shards > 1`) stores
-        the params themselves — and the EMA seed — as that SAME flat vector,
-        to be sharded over the data axis alongside the optimizer vectors.
-        `ema=True` starts the parameter EMA at the initial params (no
-        zero-debias needed)."""
+               *, ema: bool = False, exchange=None) -> "TrainState":
+        """`exchange` (parallel/zero.py `Exchange`, the run's exchange plan)
+        lays out what it shards: under ZeRO the optimizer state is
+        initialized over the flat parameter vector in the plan's layout, and
+        under ZeRO-3 the params themselves — and the EMA seed — are stored
+        as that same vector. None = the replicated tree layout with `tx`'s
+        state over it. `ema=True` starts the parameter EMA at the initial
+        params (no zero-debias needed)."""
         variables = model.init({"params": rng}, sample_input, train=False)
         params = variables["params"]
         batch_stats = variables.get("batch_stats", {})
-        if zero1_shards > 1:
-            if bucket_layout is not None:
-                flat_params = tx_input = bucket_layout.to_global(params)
-            else:
-                from jax.flatten_util import ravel_pytree
-                from distributed_vgg_f_tpu.parallel.zero import (
-                    padded_flat_size)
-                flat, _ = ravel_pytree(params)
-                padded = padded_flat_size(flat.size, zero1_shards)
-                flat_params = tx_input = jnp.pad(
-                    flat, (0, padded - flat.size))
-            opt_state = tx.init(tx_input)
-            if shard_params:
-                params = flat_params
+        if exchange is not None:
+            params, opt_state = exchange.layout_state(params)
         else:
-            if shard_params:
-                raise ValueError(
-                    "shard_params (ZeRO-3) requires zero1_shards > 1 — the "
-                    "flat param vector is sharded over the data axis")
             opt_state = tx.init(params)
         return cls(step=jnp.zeros((), jnp.int32), params=params,
                    batch_stats=batch_stats, opt_state=opt_state,

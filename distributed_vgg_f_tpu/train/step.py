@@ -14,7 +14,7 @@ batches and reads metrics (BASELINE.json north_star).
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Mapping, Tuple
+from typing import Callable, Mapping, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,22 +26,14 @@ from distributed_vgg_f_tpu.ops.losses import l2_regularization, softmax_cross_en
 from distributed_vgg_f_tpu.ops.lrn import lrn_site_counts
 from distributed_vgg_f_tpu.ops.metrics import topk_correct
 from distributed_vgg_f_tpu.parallel.collectives import (
-    all_reduce_gradients,
     cross_replica_mean,
     cross_replica_sum,
     fold_rng_per_replica,
 )
-from distributed_vgg_f_tpu.parallel.zero import padded_flat_size
+from distributed_vgg_f_tpu.parallel.zero import Exchange
 from distributed_vgg_f_tpu.train.state import TrainState
 
 Batch = Mapping[str, jnp.ndarray]
-
-
-def _clip_by_global_norm(tree, grad_norm, clip_norm):
-    """Scale a gradient pytree so its global norm is at most `clip_norm`.
-    Shared by both layouts so the replicated and ZeRO-1 paths cannot drift."""
-    scale = jnp.minimum(1.0, clip_norm / (grad_norm + 1e-12))
-    return jax.tree.map(lambda g: g * scale, tree)
 
 
 def _apply_model(model, params, batch_stats, images, *, train: bool,
@@ -77,21 +69,11 @@ def _expert_load_metrics(counts) -> dict:
     return metrics
 
 
-def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
-                     weight_decay: float,
+def build_train_step(model, mesh: Mesh, weight_decay: float,
+                     exchange: Exchange, *,
                      schedule: optax.Schedule | None = None,
-                     data_axis: str = "data",
-                     zero1: bool = False,
-                     state_specs=None,
                      grad_clip_norm: float = 0.0,
-                     grad_accum_steps: int = 1,
-                     grad_accum_shard: bool = False,
-                     shard_gradients: bool = False,
-                     shard_params: bool = False,
-                     params_struct=None,
-                     comm_bucket_mb: float = 0.0,
                      ema_decay: float = 0.0,
-                     reduce_dtype: str = "float32",
                      skip_nonfinite: bool = False,
                      device_finish: Callable | None = None,
                      device_augment: Callable | None = None,
@@ -100,40 +82,30 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                                    Tuple[TrainState, Mapping[str, jnp.ndarray]]]:
     """Returns jitted `train_step(state, batch, base_rng) -> (state, metrics)`.
 
-    - `state` and `base_rng` are replicated across the mesh; `batch` is sharded on
-      its leading dim over the data axis.
+    The body reads prologue → gradient (once, or the scan) → `exchange`
+    reduce and update → EMA → guard. Which collectives the reduce and the
+    update are, over which layout and wire, and what the state's leaves are
+    sharded over, is `exchange`'s to say (parallel/zero.py `Exchange`: the
+    dp | zero1 | zero2 | zero3 ladder, the bucketed exchange, the wire
+    dtype, the optimizer); nothing here names a basis or a layout.
+
+    - `state` and `base_rng` are replicated across the mesh (but for the
+      leaves `exchange.state_specs` shards); `batch` is sharded on its
+      leading dim over the data axis.
     - Per-replica dropout keys are derived with `fold_in(axis_index)`
       (SURVEY.md §7 hard parts).
-    - Plain DP (`zero1=False`): gradients are `pmean`-all-reduced before the optax
-      update, so every replica applies the identical update — synchronous
-      replicated SGD, the reference's semantics (SURVEY.md §2.4).
-    - `zero1=True`: optimizer-state sharding (parallel/zero.py) — gradients are
-      reduce-SCATTERED (`psum_scatter`), the optimizer updates only this
-      replica's 1/N flat shard against the sharded opt state, and the updated
-      parameter shards are all-gathered. `state_specs` must then be the
-      PartitionSpec tree from `zero.train_state_specs`.
-    - `grad_accum_steps=k>1`: the per-device batch is split into k
+    - `exchange.grad_accum_steps=k>1`: the per-device batch is split into k
       micro-batches folded through ONE `lax.scan` — only one micro-batch's
       activations are ever live, trading k× step latency for 1/k activation
       memory at an UNCHANGED optimizer batch (the logical global batch, LR
       schedule, and gradient sync point all stay identical; for BN-free
       models the summed micro-gradients equal the big-batch gradient
       exactly, tested). Gradients accumulate in the scan carry (O(params),
-      never k×); dropout keys fold per micro-batch; BN batch stats update
+      never k×; O(params/N) where the plan reduces inside the scan);
+      dropout keys fold per micro-batch; BN batch stats update
       sequentially per micro-batch (the standard accumulation semantics).
-      The cross-replica all-reduce still happens ONCE, on the accumulated
+      Otherwise the cross-replica reduce happens ONCE, on the accumulated
       gradient — accumulation also divides collective bandwidth per sample.
-    - `grad_accum_shard=True` (requires BOTH of the above): the ZeRO-2-
-      flavored composition — each micro-gradient is reduce-scattered
-      INSIDE the scan and only this replica's 1/N flat shard accumulates
-      in the carry, so the persistent accumulator is O(params/N) instead
-      of O(params) (the transient per-micro-batch gradient still
-      materializes, as in any backward pass). Cost: k scatter legs per
-      step instead of one — k× the scatter-leg wire bytes, the explicit
-      memory-for-bandwidth trade. The update it computes is the same mean
-      gradient (scatter-then-sum == sum-then-scatter up to fp summation
-      order; with a bf16 wire each micro-leg rounds once, k roundings
-      instead of one — both compositions tested).
     - `skip_nonfinite=True` (resilience layer): the step decides ON DEVICE
       whether loss and gradient norm are finite — both are cross-replica-
       reduced values, so a NaN/inf on ANY replica propagates to every
@@ -149,57 +121,6 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
       The verdict is reported as the `bad_step` metric (0/1) for the
       host-side NonFiniteGuard; cost is one `where` per state leaf,
       nothing cross-replica beyond what the step already reduces.
-    - `shard_gradients=True` (requires `zero1`): ZeRO-2 — gradient state is
-      held ONLY as this replica's 1/N flat shard. At `grad_accum_steps=1`
-      the (bucketed) reduce-scatter consumes each bucket's transient
-      gradients directly, so no persistent full-gradient buffer exists; at
-      `grad_accum_steps>1` the scan accumulator is the 1/N shard (the
-      `grad_accum_shard` composition, now implied — the accumulator drops
-      from O(params) to O(params/N), utils/scaling_model.py
-      `gradient_state_bytes_per_chip`). Grad-norm/clipping already ran on
-      the sharded form under ZeRO-1 (psum of shard partials); ZeRO-2 keeps
-      that exact expression.
-    - `shard_params=True` (requires `shard_gradients` + `params_struct`):
-      ZeRO-3 — `state.params` (and `state.ema_params`) are held ONLY as
-      this replica's 1/N flat shard of the padded flat vector
-      (bucket-major when bucketed, canonical ravel order otherwise). The
-      step [SYNC] all-gathers the full param tree ONCE up front — one
-      `all_gather` PER BUCKET under the bucketed exchange, each depending
-      only on the step's param-shard INPUT (zero compute ancestry), so
-      every gather is overlap-capable and the lowering carries gathers ==
-      buckets (`hlo_overlap_report` gather witness). The gathered replica
-      is a step TRANSIENT: XLA frees it after its last consumer, nothing
-      downstream persists it — per-chip persistent param bytes drop to
-      O(params/N) (utils/scaling_model.py `param_bytes_per_chip`). The
-      gradient side is byte-for-byte the ZeRO-2 scatter; the optimizer
-      updates the resident shard directly and the ZeRO-1/2 trailing
-      re-sync gather DISAPPEARS (next step's just-in-time gather plays
-      that role), so zero3 moves the same gather bytes per step as zero2
-      — earlier in the step, and on the `mesh.reduce_dtype` wire (the
-      single-sourced cast_to_wire/cast_from_wire; fp32 truth stays in the
-      shard). At the default fp32 wire the gathered tree is bit-identical
-      to the ZeRO-2 replicated params, so loss trajectories are EQUAL
-      (tests/test_zero3.py pins the grid); a narrowed wire trades that
-      strict equality for halved gather bytes — zero3 is the only basis
-      where BOTH legs narrow. `grad_accum_steps>1` gathers once OUTSIDE
-      the scan (the carry stays the 1/N gradient shard). Off (default):
-      the ZeRO-2 step, lowered-text-identical (kill-switch pin).
-    - `comm_bucket_mb>0` (parallel/buckets.py): bucketed, overlap-capable
-      gradient exchange — the param tree partitions into size-targeted
-      buckets in reverse-backward order and each bucket's collective
-      (per-bucket pmean in plain DP, per-bucket psum_scatter under
-      sharding) is emitted against ONLY that bucket's gradients, so the
-      lowered HLO carries >= 2 gradient collectives with no dependency
-      path to the rest of the backward — the structure XLA's
-      latency-hiding scheduler overlaps (committed assertion:
-      buckets.hlo_overlap_report, tests/test_comm_buckets.py,
-      benchmarks/comm_overlap_bench.py). Under sharding the opt-state
-      flat layout becomes bucket-major replica-interleaved
-      (GradBucketLayout.to_global; checkpoint migration via
-      parallel/zero.convert_opt_state + the geometry receipt in the
-      checkpoint's `extra`). Unset (0) keeps the pre-r14 monolithic
-      exchange and flat layout byte-for-byte — the kill-switch
-      lowered-text identity is pinned.
     - `device_augment` (r13, data/augment.py): the on-device
       augmentation stage, applied to the batch as it arrived inside the
       shard_map body off a constant fold of the per-replica train key
@@ -220,52 +141,16 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
       optimizer, guard, donation) is shared. An image batch traces to the
       step it traced to before the kind existed.
     """
-    if state_specs is None:
-        state_specs = P()
-    if grad_accum_shard and not (zero1 and grad_accum_steps > 1):
-        raise ValueError(
-            "grad_accum_shard requires zero1 optimizer-state sharding AND "
-            f"grad_accum_steps > 1 (got zero1={zero1}, "
-            f"grad_accum_steps={grad_accum_steps}) — without both there is "
-            "no sharded accumulator to build")
-    if shard_gradients and not zero1:
-        raise ValueError(
-            "shard_gradients (ZeRO-2) requires zero1 optimizer-state "
-            "sharding — there is no shard frame to hold gradients in")
-    if shard_params:
-        if not (zero1 and shard_gradients):
-            raise ValueError(
-                "shard_params (ZeRO-3) requires shard_gradients (ZeRO-2) — "
-                "the sharding ladder is cumulative; params sharded without "
-                "a sharded gradient frame would re-materialize O(params) "
-                "gradient state every step")
-        if params_struct is None:
-            raise ValueError(
-                "shard_params (ZeRO-3) requires params_struct — "
-                "state.params is the flat shard, so the step cannot "
-                "recover the tree geometry from it")
-    # ZeRO-2 implies the sharded scan accumulator whenever a scan exists
-    # (the explicit grad_accum_shard flag stays as the ZeRO-1 opt-in).
-    grad_accum_shard = grad_accum_shard or (shard_gradients
-                                            and grad_accum_steps > 1)
-    # Bucketed exchange (parallel/buckets.py): geometry is decided at trace
-    # time from the params tree — 0 keeps the monolithic pre-r14 paths.
-    bucket_bytes = int(round(comm_bucket_mb * 1024 * 1024)) \
-        if comm_bucket_mb else 0
-    # Static per-run exchange receipt, filled at first trace (the layout
-    # needs leaf shapes). Read by the trainer's per-window `comm` JSONL
-    # block and the comm/* counters below.
+    data_axis = exchange.axis
+    grad_accum_steps = exchange.grad_accum_steps
+    # Static per-run exchange receipt, filled at first trace (a dp plan
+    # learns the leaf shapes there). Read by the trainer's per-window
+    # `comm` JSONL block and the comm/* counters below.
     comm_meta: dict = {}
     # LRN call sites of the traced step by what they lowered to (ops/lrn.py:
     # the fused kernel pair, or an XLA form), filled at first trace like
     # comm_meta; gauges lrn/fused_sites and lrn/fallback_sites below.
     lrn_sites: dict = {}
-    num_shards = mesh.shape[data_axis]
-    # mesh.reduce_dtype: wire dtype for the gradient sync only (None = the
-    # gradients' own fp32). Halves collective bytes at ~16 mantissa bits of
-    # gradient precision; momentum/params/param-all-gather stay fp32.
-    wire_dtype = (None if reduce_dtype in ("float32", None)
-                  else jnp.dtype(reduce_dtype))
 
     # Named `train_step` and not `step_fn`: the jitted module's name
     # (`jit_train_step`) is what a trace's `XLA Modules` line shows, and it
@@ -345,120 +230,12 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 return loss, (new_batch_stats, metrics)
             return loss_fn
 
-        # Bucketed-exchange geometry (trace-time, pure function of leaf
-        # shapes — deterministic, so the trainer's separately-built layout
-        # for specs/init/checkpointing can never disagree with the step's).
-        # Under ZeRO-3 state.params IS the flat shard, so the tree geometry
-        # comes from params_struct instead (same leaves, same layout).
-        param_geom = params_struct if shard_params else state.params
-        bucket_layout = None
-        if bucket_bytes > 0:
-            from distributed_vgg_f_tpu.parallel.buckets import (
-                build_bucket_layout)
-            bucket_layout = build_bucket_layout(param_geom, num_shards,
-                                                bucket_bytes)
-
-        # ZeRO flat-shard geometry — computed ONCE so the scan carry shape,
-        # the scatter padding, and the param-shard slicing below can never
-        # disagree (they all derive from these three numbers).
-        if zero1:
-            from jax.flatten_util import ravel_pytree
-            n_elem = sum(x.size for x in jax.tree.leaves(param_geom))
-            if bucket_layout is not None:
-                shard_size = bucket_layout.shard_size
-            else:
-                padded = padded_flat_size(n_elem, num_shards)
-                shard_size = padded // num_shards
-
+        # the plan with the parameter shapes in it: a ZeRO plan came
+        # bound (the state's layout depends on them), a dp plan binds here
+        plan = exchange.bind(state.params)
         if not comm_meta:
-            from distributed_vgg_f_tpu.parallel.buckets import (
-                exchange_wire_bytes, sharding_basis)
-            n_all = sum(x.size for x in jax.tree.leaves(param_geom))
-            comm_meta.update({
-                # the EFFECTIVE basis: zero1/shard_gradients are already
-                # post-downgrade here (single source: buckets.sharding_basis)
-                "sharding": sharding_basis(zero1,
-                                           zero1 and shard_gradients,
-                                           shard_params),
-                "bucketed": bucket_layout is not None,
-                "buckets": (bucket_layout.num_buckets
-                            if bucket_layout is not None
-                            else (1 if zero1
-                                  else len(jax.tree.leaves(param_geom)))),
-                "bucket_mb": float(comm_bucket_mb or 0.0),
-                "reduce_dtype": reduce_dtype or "float32",
-                "grad_accum_steps": grad_accum_steps,
-                # all_gather collectives per step: 0 in plain DP; the single
-                # trailing (S,) re-sync gather under ZeRO-1/2; one PER
-                # BUCKET under bucketed ZeRO-3 (the just-in-time fetch —
-                # hlo_overlap_report's `gathers` witnesses this count)
-                "gathers": (0 if not zero1
-                            else (bucket_layout.num_buckets
-                                  if shard_params
-                                  and bucket_layout is not None else 1)),
-            })
-            # one shared byte accounting for bucketed AND monolithic
-            # (bucketing changes the schedule, never the byte totals)
-            padded_total = (bucket_layout.total_padded
-                            if bucket_layout is not None
-                            else (padded if zero1 else 0))
-            comm_meta.update(exchange_wire_bytes(
-                n_all, padded_total, zero=zero1, wire_dtype=wire_dtype,
-                shard_params=shard_params))
-            # scatter-leg bytes scale with the scan: k micro-scatters
-            if grad_accum_shard and grad_accum_steps > 1:
-                comm_meta["scatter_bytes"] *= grad_accum_steps
-                comm_meta["wire_bytes"] = (comm_meta["scatter_bytes"]
-                                           + comm_meta["gather_bytes"])
-
-        @jax.named_scope("exchange")
-        def scatter_mean_shard(g_tree):
-            """Ravel + pad + [SYNC] reduce-scatter one gradient pytree to
-            this replica's fp32 mean 1/N flat shard — PER BUCKET when the
-            bucketed exchange is on (each bucket's collective consumes only
-            its own gradients: the overlap-capable emission), one flat
-            monolith otherwise. mesh.reduce_dtype: the scatter leg may move
-            a narrower wire dtype through the single-sourced cast
-            (collectives.cast_to_wire; cast back for the mean and
-            everything downstream); the param all-gather below ALWAYS
-            stays fp32 — replicas must re-sync exactly."""
-            if bucket_layout is not None:
-                return bucket_layout.scatter_mean_shards(
-                    g_tree, data_axis, wire_dtype=wire_dtype)
-            from distributed_vgg_f_tpu.parallel.collectives import (
-                cast_from_wire, cast_to_wire)
-            flat_g, _ = ravel_pytree(g_tree)
-            send = cast_to_wire(jnp.pad(flat_g, (0, padded - n_elem)),
-                                wire_dtype)
-            return cast_from_wire(jax.lax.psum_scatter(
-                send, data_axis, scatter_dimension=0,
-                tiled=True), jnp.float32) / num_shards
-
-        # ZeRO-3 just-in-time parameter gather — ONCE, up front (and OUTSIDE
-        # the grad-accum scan: the scan carry stays the 1/N gradient shard;
-        # re-gathering per micro-batch would move k× the gather bytes for
-        # params that cannot have changed mid-step). Each bucket's
-        # all_gather consumes a static slice of the step's param-shard
-        # INPUT, so none has compute ancestry — the overlap license the
-        # committed gather witness asserts. The gathered tree is a step
-        # transient; at a fp32 wire it is bit-identical to the ZeRO-2
-        # replicated params (the equality-grid pin).
-        if shard_params:
-            from distributed_vgg_f_tpu.parallel.collectives import (
-                cast_from_wire, cast_to_wire)
-            from distributed_vgg_f_tpu.parallel.zero import _unflatten_like
-            with jax.named_scope("exchange"):
-                if bucket_layout is not None:
-                    full_params = bucket_layout.gather_param_tree(
-                        state.params, data_axis, wire_dtype=wire_dtype)
-                else:
-                    full = cast_from_wire(jax.lax.all_gather(
-                        cast_to_wire(state.params, wire_dtype), data_axis,
-                        tiled=True), jnp.float32)
-                    full_params = _unflatten_like(full[:n_elem],
-                                                  params_struct)
-        else:
-            full_params = state.params
+            comm_meta.update(plan.comm_meta())
+        full_params = plan.forward_params(state.params)
 
         if grad_accum_steps > 1:
             if batch_kind != "image":
@@ -478,16 +255,7 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # scalar per step, shared by every micro-batch.
             lb2 = (mix_labels.reshape(grad_accum_steps, micro)
                    if mix_labels is not None else None)
-
-            if grad_accum_shard:
-                # ZeRO-2-flavored carry: this replica's 1/N flat gradient
-                # shard, fp32 — each micro-gradient is scattered right away
-                # and only the shard persists across micro-batches.
-                accumulate = lambda g_acc, g: g_acc + scatter_mean_shard(g)
-                g_init = jnp.zeros((shard_size,), jnp.float32)
-            else:
-                accumulate = lambda g_acc, g: jax.tree.map(jnp.add, g_acc, g)
-                g_init = jax.tree.map(jnp.zeros_like, state.params)
+            g_init = plan.accum_init(state.params)
 
             def micro_step(carry, xs):
                 g_acc, bs = carry
@@ -500,18 +268,16 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                                        jax.random.fold_in(rng, i))
                 (_, (bs_new, m)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(full_params)
-                return (accumulate(g_acc, g), bs_new), m
+                return (plan.accum_add(g_acc, g), bs_new), m
 
             micro_xs = (im, lb) + (() if lb2 is None else (lb2,)) \
                 + (jnp.arange(grad_accum_steps),)
             (g_sum, new_batch_stats), metrics_stack = jax.lax.scan(
                 micro_step, (g_init, state.batch_stats), micro_xs)
-            if grad_accum_shard:
-                accum_grad_shard = g_sum / grad_accum_steps
-                grads = None   # never materialized whole past a micro-step
-            else:
-                grads = jax.tree.map(lambda g: g / grad_accum_steps, g_sum)
-                accum_grad_shard = None
+            grads = jax.tree.map(lambda g: g / grad_accum_steps, g_sum)
+            # where the scan's carry is the 1/N shard, each micro-gradient
+            # was reduced inside it and never materialized whole past it
+            reduced = plan.accum_reduced
             metrics = jax.tree.map(lambda m: jnp.mean(m, axis=0),
                                    metrics_stack)
         else:
@@ -519,99 +285,25 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                                    state.batch_stats, rng)
             (_, (new_batch_stats, metrics)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(full_params)
-            accum_grad_shard = None
+            reduced = False
         with jax.named_scope("step_metrics"):
             metrics = cross_replica_mean(metrics, data_axis)
 
-        if zero1:
-            if accum_grad_shard is not None:
-                # grad_accum_shard: the scatter already happened per
-                # micro-batch inside the scan; the mean shard is in hand.
-                grad_shard = accum_grad_shard
-            else:
-                # reduce-scatter half of the all-reduce: each replica owns
-                # the mean gradient for its contiguous 1/N flat shard.
-                grad_shard = scatter_mean_shard(grads)
-            with jax.named_scope("optimizer"):
-                grad_norm = jnp.sqrt(jax.lax.psum(
-                    jnp.sum(jnp.square(grad_shard)), data_axis))
-                if grad_clip_norm > 0:
-                    grad_shard = _clip_by_global_norm(grad_shard, grad_norm,
-                                                      grad_clip_norm)
-
-                if shard_params:
-                    # ZeRO-3: the resident (S,) flat shard IS the optimizer's
-                    # parameter frame — no slicing out of a replicated tree,
-                    # and (below) no trailing re-sync gather: the NEXT step's
-                    # just-in-time gather reconstitutes the tree from exactly
-                    # what the ZeRO-2 step would have stored.
-                    param_shard = state.params
-                    unravel = None
-                elif bucket_layout is not None:
-                    # bucket-major flat frame (parallel/buckets.py): the
-                    # param shard, the opt-state vectors, and the gathered
-                    # update all live in GradBucketLayout's
-                    # replica-interleaved layout
-                    param_shard = bucket_layout.local_param_shard(
-                        state.params, data_axis)
-                else:
-                    flat_params, unravel = ravel_pytree(state.params)
-                    offset = jax.lax.axis_index(data_axis) * shard_size
-                    param_shard = jax.lax.dynamic_slice_in_dim(
-                        jnp.pad(flat_params, (0, padded - n_elem)), offset,
-                        shard_size)
-                updates_shard, new_opt_state = tx.update(
-                    grad_shard, state.opt_state, param_shard)
-                new_param_shard = optax.apply_updates(param_shard,
-                                                      updates_shard)
-            if shard_params:
-                # ZeRO-3 persists the shard itself — params stay O(1/N).
-                new_params = new_param_shard
-            else:
-                # [SYNC] all-gather half: replicas re-sync the updated
-                # parameters.
-                with jax.named_scope("exchange"):
-                    if bucket_layout is not None:
-                        new_params = bucket_layout.gather_params(
-                            new_param_shard, data_axis)
-                    else:
-                        new_flat = jax.lax.all_gather(
-                            new_param_shard, data_axis, tiled=True)
-                        new_params = unravel(new_flat[:n_elem])
-            metrics["grad_norm"] = grad_norm
-        else:
-            # [SYNC] — the one cross-replica point per step (reference: NCCL/MPI
-            # ring all-reduce; here: XLA ICI all-reduce emitted from pmean).
-            # Bucketed: one pmean per size-targeted bucket instead of one
-            # per leaf — same elementwise math, ICI-friendly message sizes.
-            with jax.named_scope("exchange"):
-                if bucket_layout is not None:
-                    grads = bucket_layout.pmean_buckets(
-                        grads, data_axis, wire_dtype=wire_dtype)
-                else:
-                    grads = all_reduce_gradients(grads, data_axis,
-                                                 reduce_dtype=wire_dtype)
-            with jax.named_scope("optimizer"):
-                grad_norm = optax.global_norm(grads)
-                if grad_clip_norm > 0:
-                    grads = _clip_by_global_norm(grads, grad_norm,
-                                                 grad_clip_norm)
-                updates, new_opt_state = tx.update(grads, state.opt_state,
-                                                   state.params)
-                new_params = optax.apply_updates(state.params, updates)
-            metrics["grad_norm"] = grad_norm
+        # [SYNC] — the one cross-replica point per step, then the update
+        if not reduced:
+            grads = plan.reduce(grads)
+        new_params, new_opt_state, metrics["grad_norm"] = plan.update(
+            grads, state.opt_state, state.params, grad_clip_norm)
 
         if schedule is not None:
             with jax.named_scope("step_metrics"):
                 metrics["lr"] = schedule(state.step)
 
-        # Parameter EMA (train.ema_decay): stored like params — replicated
-        # tree under DP/ZeRO-1/2 (it tracks the post-all-gather params);
-        # under ZeRO-3 both sides are the resident (S,) flat shard, so the
-        # identical elementwise update shards for free. BN moving stats are
-        # averaged with the same decay (the TF recipe's
-        # moving_average_variables). Fused into the same XLA computation as
-        # the step.
+        # Parameter EMA (train.ema_decay): stored like params, whatever the
+        # exchange stores them as, so the elementwise update shards for
+        # free. BN moving stats are averaged with the same decay (the TF
+        # recipe's moving_average_variables). Fused into the same XLA
+        # computation as the step.
         new_ema = state.ema_params
         new_ema_bs = state.ema_batch_stats
         if ema_decay > 0.0:
@@ -658,8 +350,8 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
 
     sharded = shard_map(
         train_step, mesh=mesh,
-        in_specs=(state_specs, P(data_axis), P()),
-        out_specs=(state_specs, P()),
+        in_specs=(exchange.state_specs, P(data_axis), P()),
+        out_specs=(exchange.state_specs, P()),
         check_vma=False,
     )
     # State donation halves the step's peak state memory on accelerators:
@@ -720,23 +412,17 @@ def build_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     return dispatch
 
 
-def build_eval_step(model, mesh: Mesh, data_axis: str = "data",
-                    state_specs=None,
+def build_eval_step(model, mesh: Mesh, exchange: Exchange, *,
                     device_finish: Callable | None = None,
-                    param_gather: Callable | None = None,
                     ) -> Callable[[TrainState, Batch], Mapping[str, jnp.ndarray]]:
     """Jitted eval step returning psum-accumulated correct counts
     (SURVEY.md §3.4): {'top1': n_correct, 'top5': n_correct5, 'count': n}.
 
-    `state_specs` mirrors the train step's so a ZeRO-1-sharded state is consumed
-    in place (eval never touches opt state, so no gather is emitted).
-    `param_gather` (ZeRO-3, r21): a closure mapping the resident (S,) flat
-    param shard back to the full params tree INSIDE the shard_map body (the
-    trainer builds it over the same bucket layout the train step uses;
-    always fp32 — eval must score the exact weights). None = params are the
-    ordinary replicated tree."""
-    if state_specs is None:
-        state_specs = P()
+    `exchange` is the train step's plan, so a ZeRO-sharded state is consumed
+    in place (eval never touches opt state, so no gather is emitted for it)
+    and zero3's resident param shard is gathered back to the tree INSIDE the
+    shard_map body (`Exchange.eval_params`: always fp32)."""
+    data_axis = exchange.axis
 
     def eval_step(state: TrainState, batch: Batch):
         images, labels = batch["image"], batch["label"]
@@ -751,11 +437,7 @@ def build_eval_step(model, mesh: Mesh, data_axis: str = "data",
         # Exact eval (data/eval_pad.py): a "valid" mask marks padding rows in
         # the final partial batch; they contribute to neither hits nor count.
         valid = batch.get("valid")
-        if param_gather is not None:
-            with jax.named_scope("exchange"):
-                params = param_gather(state.params)
-        else:
-            params = state.params
+        params = exchange.eval_params(state.params)
         logits, _ = _apply_model(model, params, state.batch_stats, images,
                                  train=False)
         with jax.named_scope("step_metrics"):
@@ -770,7 +452,7 @@ def build_eval_step(model, mesh: Mesh, data_axis: str = "data",
             return cross_replica_sum(counts, data_axis)
 
     sharded = shard_map(eval_step, mesh=mesh,
-                        in_specs=(state_specs, P(data_axis)),
+                        in_specs=(exchange.state_specs, P(data_axis)),
                         out_specs=P(),
                         check_vma=False)
     return jax.jit(sharded)

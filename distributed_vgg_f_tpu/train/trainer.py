@@ -108,28 +108,9 @@ class Trainer:
         # and the step's prologue, loss and metrics
         from distributed_vgg_f_tpu.models.ingest import ingest_descriptor
         self.batch_kind = ingest_descriptor(cfg.model.name).kind
-        self.num_shards = int(self.mesh.shape[self.data_axis])
-        self.zero1 = bool(cfg.mesh.shard_opt_state) and self.num_shards > 1
-        # ZeRO-2 (r14): gradient state sharded like the opt state —
-        # downgrades with zero1 on single-shard meshes (no shard to own)
-        self.zero2 = self.zero1 and bool(cfg.mesh.shard_gradients)
-        # ZeRO-3 (r21): params (and EMA) persisted ONLY as 1/N flat shards,
-        # gathered just-in-time by the step — downgrades with the ladder
-        self.zero3 = self.zero2 and bool(cfg.mesh.shard_params)
-        # Bucketed exchange (r14, parallel/buckets.py): 0 = monolithic
-        # kill-switch. The layout itself (when ZeRO needs one for the
-        # opt-state frame) is built in _make_state_specs from the same
-        # deterministic geometry function the step uses at trace time.
-        self._bucket_bytes = int(round(cfg.mesh.comm_bucket_mb * 1024 * 1024))
-        self._bucket_layout = None
         self.tx, self.schedule = build_optimizer(cfg)
         self._replicated = NamedSharding(self.mesh, P())
-        self._state_specs = self._make_state_specs()
-        if cfg.train.grad_accum_shard and not (
-                cfg.mesh.shard_opt_state and cfg.train.grad_accum_steps > 1):
-            raise ValueError(
-                "train.grad_accum_shard requires mesh.shard_opt_state=true "
-                "AND train.grad_accum_steps > 1")
+        self._plan_exchange()
         # Device-finish prologue (data/device_ingest.py, data.wire='u8'):
         # normalize/cast/space-to-depth for uint8-wire batches, fused into
         # the jitted steps. Installed UNCONDITIONALLY — it dispatches on
@@ -283,29 +264,17 @@ class Trainer:
         construction, never a stale-closure bug."""
         cfg = self.cfg
         self.train_step = build_train_step(
-            self.model, self.tx, self.mesh, cfg.optim.weight_decay,
-            schedule=self.schedule, data_axis=self.data_axis,
-            zero1=self.zero1, state_specs=self._state_specs,
+            self.model, self.mesh, cfg.optim.weight_decay, self.exchange,
+            schedule=self.schedule,
             grad_clip_norm=cfg.optim.grad_clip_norm,
-            grad_accum_steps=cfg.train.grad_accum_steps,
-            # single-device meshes downgrade zero1 itself (no shard to
-            # own), so the sharded accumulator downgrades with it
-            grad_accum_shard=cfg.train.grad_accum_shard and self.zero1,
-            shard_gradients=self.zero2,
-            shard_params=self.zero3,
-            params_struct=self._params_struct if self.zero3 else None,
-            comm_bucket_mb=cfg.mesh.comm_bucket_mb,
             ema_decay=cfg.train.ema_decay,
-            reduce_dtype=cfg.mesh.reduce_dtype,
             skip_nonfinite=cfg.train.skip_nonfinite,
             device_finish=self.device_finish,
             device_augment=self.device_augment,
             batch_kind=self.batch_kind)
         self.eval_step = build_eval_step(self.model, self.mesh,
-                                         data_axis=self.data_axis,
-                                         state_specs=self._state_specs,
-                                         device_finish=self._eval_finish,
-                                         param_gather=self._param_gather())
+                                         self.exchange,
+                                         device_finish=self._eval_finish)
 
     # ------------------------------------------------------------------ state
     def _sample_input(self) -> jnp.ndarray:
@@ -317,115 +286,67 @@ class Trainer:
             (1, self.cfg.data.image_size, self.cfg.data.image_size, 3),
             jnp.float32)
 
-    def _make_state_specs(self):
-        """PartitionSpec tree for the TrainState: fully replicated for plain DP;
-        opt-state vectors sharded over the data axis under ZeRO-1/2. With
-        the bucketed exchange on, the flat frame is the bucket-major layout
-        (parallel/buckets.py) and `self._padded` is its `total_padded`.
-        Under ZeRO-3 the params (and EMA) leaves are that same flat vector,
-        sharded like the opt vectors; `self._params_struct` keeps the TREE
-        geometry the step/checkpoint/elastic layers need (the flat state no
-        longer carries it)."""
-        self._padded = None  # ZeRO flat length; None under replicated DP
-        self._params_struct = None  # params TREE struct; set under ZeRO-1+
-        if not self.zero1:
-            return None
-        from distributed_vgg_f_tpu.parallel.zero import (
-            flat_param_count, padded_flat_size, train_state_specs)
-        state_shapes = jax.eval_shape(
-            lambda r: TrainState.create(self.model, self.tx, r,
-                                        self._sample_input(),
-                                        zero1_shards=self.num_shards,
-                                        ema=self.cfg.train.ema_decay > 0.0),
-            jax.random.key(0))
-        self._params_struct = state_shapes.params
-        if self._bucket_bytes > 0:
-            from distributed_vgg_f_tpu.parallel.buckets import (
-                build_bucket_layout)
-            # the SAME deterministic geometry the step builds at trace time
-            self._bucket_layout = build_bucket_layout(
-                state_shapes.params, self.num_shards, self._bucket_bytes)
-            padded = self._bucket_layout.total_padded
-            # the bucketed opt struct is tx.init over a flat vector of the
-            # bucketed length — derive it abstractly instead of re-tracing
-            # the whole TrainState.create (model.init is the expensive part)
-            state_shapes = state_shapes.replace(opt_state=jax.eval_shape(
-                self.tx.init,
-                jax.ShapeDtypeStruct((padded,), jnp.float32)))
-        else:
-            padded = padded_flat_size(flat_param_count(state_shapes.params),
-                                      self.num_shards)
-        if self.zero3:
-            # ZeRO-3 state shape: params/EMA collapse to the flat vector
-            # (derived abstractly, same reason as the opt struct above)
-            flat = jax.ShapeDtypeStruct((padded,), jnp.float32)
-            state_shapes = state_shapes.replace(
-                params=flat,
-                ema_params=(flat if state_shapes.ema_params is not None
-                            else None))
-        self._padded = padded
-        return train_state_specs(state_shapes, padded, self.data_axis,
-                                 shard_params=self.zero3)
+    def _plan_exchange(self) -> None:
+        """Decide the exchange for the CURRENT mesh and optimizer
+        (parallel/zero.py `plan_exchange`: basis after the one-shard
+        downgrade, layout, wire) — at construction, and again by the
+        elastic resize. A ZeRO plan needs the parameter shapes now (its
+        state's layout depends on them): one abstract trace of the model's
+        init. A dp plan learns them at the step's first trace, so plain DP
+        pays no `eval_shape` here."""
+        cfg = self.cfg
+        from distributed_vgg_f_tpu.parallel.zero import plan_exchange
+        self.exchange = plan_exchange(
+            cfg.mesh, self.mesh, self.tx,
+            grad_accum_steps=cfg.train.grad_accum_steps,
+            grad_accum_shard=cfg.train.grad_accum_shard)
+        if self.exchange.sharded:
+            shapes = jax.eval_shape(
+                lambda r: TrainState.create(self.model, self.tx, r,
+                                            self._sample_input()),
+                jax.random.key(0))
+            self.exchange = self.exchange.bind(
+                shapes.params, shapes.batch_stats,
+                ema=cfg.train.ema_decay > 0.0)
+
+    # What the benchmark's drivers (chipbench/drivers/) read of the plan.
+    @property
+    def zero1(self) -> bool:
+        return self.exchange.sharded
+
+    @property
+    def zero2(self) -> bool:
+        return self.exchange.zero2
+
+    @property
+    def zero3(self) -> bool:
+        return self.exchange.zero3
+
+    @property
+    def _bucket_layout(self):
+        """The bucket-major layout of a ZeRO state; None under the
+        canonical layout and under plain DP."""
+        plan = self.exchange
+        return plan.layout if plan.sharded and plan.bucket_mb > 0 else None
 
     def _state_sharding(self):
-        if self._state_specs is None:
-            return self._replicated
-        return jax.tree.map(lambda spec: NamedSharding(self.mesh, spec),
-                            self._state_specs,
-                            is_leaf=lambda x: isinstance(x, P))
-
-    def _param_gather(self):
-        """ZeRO-3 eval hook: a closure mapping the resident (S,) flat param
-        shard back to the full params tree INSIDE a shard_map body — always
-        fp32 (eval/predict must score the exact weights; the train step's
-        wire-narrowing is a train-only trade). None for every other basis
-        (eval consumes the replicated tree in place, pre-r21 behavior)."""
-        if not self.zero3:
-            return None
-        layout = self._bucket_layout
-        axis = self.data_axis
-        if layout is not None:
-            return lambda shard: layout.gather_param_tree(shard, axis)
-        from distributed_vgg_f_tpu.parallel.zero import (
-            _unflatten_like, flat_param_count)
-        struct = self._params_struct
-        n_elem = flat_param_count(struct)
-
-        def gather(shard):
-            full = jax.lax.all_gather(shard, axis, tiled=True)
-            return _unflatten_like(full[:n_elem], struct)
-        return gather
+        return self.exchange.state_shardings(self.mesh)
 
     def params_tree(self, params):
-        """Host-side inverse of the ZeRO-3 flat params layout: the global
-        (T,) flat vector → the params tree; identity for every other basis
-        (params already ARE the tree). The offline surfaces (predict /
-        serving restore) run outside the mesh, so they invert the layout
-        here instead of through the step's in-mesh gathers."""
-        if not self.zero3:
-            return params
-        vec = jnp.asarray(params)
-        if self._bucket_layout is not None:
-            return self._bucket_layout.from_global(vec)
-        from distributed_vgg_f_tpu.parallel.zero import (
-            _unflatten_like, flat_param_count)
-        return _unflatten_like(vec[:flat_param_count(self._params_struct)],
-                               self._params_struct)
+        """The params TREE of a state's `params`, on the host (predict /
+        serving restore run outside the mesh)."""
+        return self.exchange.params_tree(params)
 
     def init_state(self, rng: jax.Array | None = None) -> TrainState:
-        """Initialize params on-device: replicated over the mesh, except the
-        ZeRO-1 opt-state vectors which land sharded over the data axis."""
+        """Initialize params on-device: replicated over the mesh, except
+        what the exchange shards over the data axis."""
         rng = rng if rng is not None else jax.random.key(self.cfg.train.seed)
         sample = self._sample_input()
-        shards = self.num_shards if self.zero1 else 0
-        layout = self._bucket_layout if self.zero1 else None
 
         def init_fn(rng):
             return TrainState.create(self.model, self.tx, rng, sample,
-                                     zero1_shards=shards,
                                      ema=self.cfg.train.ema_decay > 0.0,
-                                     bucket_layout=layout,
-                                     shard_params=self.zero3)
+                                     exchange=self.exchange)
 
         return jax.jit(init_fn, out_shardings=self._state_sharding())(rng)
 
@@ -468,13 +389,6 @@ class Trainer:
             # (checkpoint/retopology.py; BASELINE north_star v4-8 → v4-128).
             from distributed_vgg_f_tpu.checkpoint.retopology import (
                 restore_any_topology)
-            opt_sh = (self._state_sharding().opt_state if self.zero1
-                      else self._replicated)
-            # ZeRO-3 (r21): params/EMA are the sharded flat vector — the
-            # restore converts any saved layout onto this sharding; None
-            # keeps the pre-r21 replicated-tree path
-            params_sh = (self._state_sharding().params if self.zero3
-                         else None)
             # EMA presence is decided from the SAVED tree's metadata, not by
             # try/except (an exception-driven retry buried unrelated restore
             # failures under a misleading structure-mismatch — code-review
@@ -509,24 +423,12 @@ class Trainer:
             restore_extra = {}
             if saved_has_ema == want_ema:
                 state, restore_extra = restore_any_topology(
-                    source, state, self.tx,
-                    opt_shardings=opt_sh,
-                    target_padded=self._padded,
-                    target_bucket_layout=self._bucket_layout,
-                    params_tree_struct=self._params_struct,
-                    params_shardings=params_sh,
-                    step=restore_step)
+                    source, state, self.exchange, step=restore_step)
             elif want_ema:
                 # pre-EMA checkpoint into an EMA-enabled run
                 tmpl = state.replace(ema_params=None, ema_batch_stats=None)
                 restored, restore_extra = restore_any_topology(
-                    source, tmpl, self.tx,
-                    opt_shardings=opt_sh,
-                    target_padded=self._padded,
-                    target_bucket_layout=self._bucket_layout,
-                    params_tree_struct=self._params_struct,
-                    params_shardings=params_sh,
-                    step=restore_step)
+                    source, tmpl, self.exchange, step=restore_step)
                 # jnp.copy: the seed must be DISTINCT buffers — sharing the
                 # params' buffers trips the train step's donation ("attempt
                 # to donate the same buffer twice")
@@ -541,13 +443,7 @@ class Trainer:
                 tmpl = state.replace(ema_params=state.params,
                                      ema_batch_stats=state.batch_stats)
                 restored, restore_extra = restore_any_topology(
-                    source, tmpl, self.tx,
-                    opt_shardings=opt_sh,
-                    target_padded=self._padded,
-                    target_bucket_layout=self._bucket_layout,
-                    params_tree_struct=self._params_struct,
-                    params_shardings=params_sh,
-                    step=restore_step)
+                    source, tmpl, self.exchange, step=restore_step)
                 state = restored.replace(ema_params=None,
                                          ema_batch_stats=None)
                 ema_event = "ema_dropped_on_restore"
@@ -571,31 +467,6 @@ class Trainer:
                                 {"step": restored_step,
                                  "best": source is not self.checkpoints})
         return state
-
-    def _opt_layout_extra(self) -> dict:
-        """The ZeRO-2 bucket-geometry receipt that rides EVERY checkpoint's
-        `extra` JSON when the bucketed sharded exchange is on: a saved flat
-        opt-state vector in the bucket-major layout is indistinguishable
-        from the canonical one by shape, so restore
-        (checkpoint/retopology.py) reads this to pick the right inverse
-        permutation. Absent receipt = canonical layout (every pre-r14
-        checkpoint). ZeRO-3 (r21) adds the `param_layout` receipt: the
-        SAVED params are the flat vector too, and its kind
-        (canonical_flat | bucketed_flat — the bucket geometry itself is the
-        opt_layout receipt, one layout for both vectors) tells restore how
-        to invert them; absent = params are a tree (every pre-r21
-        checkpoint)."""
-        extra = {}
-        if self._bucket_layout is not None and self.zero1:
-            extra["opt_layout"] = self._bucket_layout.describe()
-        if self.zero3:
-            extra["param_layout"] = {
-                "kind": ("bucketed_flat" if self._bucket_layout is not None
-                         else "canonical_flat"),
-                "num_shards": self.num_shards,
-                "total_padded": int(self._padded),
-            }
-        return extra
 
     def base_rng(self) -> jax.Array:
         # Built inside jit so the replicated output sharding also works
@@ -650,13 +521,13 @@ class Trainer:
             label=cfg.data.service.label)
 
     def _save_extra(self, next_step: int) -> dict:
-        """The host-state JSON riding every checkpoint's `extra`: the r14
-        opt-layout receipt plus (r18) the schema-validated iterator-state
+        """The host-state JSON riding every checkpoint's `extra`: the
+        exchange's layout receipts (`Exchange.receipts`) plus (r18) the schema-validated iterator-state
         blob captured at the step barrier — `next_step` is the batch the
         restored run will consume first."""
         extra = {"examples_seen":
                  next_step * self.cfg.data.global_batch_size,
-                 **self._opt_layout_extra()}
+                 **self.exchange.receipts()}
         if self._ingest is not None:
             from distributed_vgg_f_tpu.telemetry import schema
             blob = self._ingest.capture_state(next_step)
@@ -757,52 +628,28 @@ class Trainer:
 
         # Evacuation accounting against the OLD geometry: each dead rank
         # owned one 1/N slice of every data-axis-sharded opt-state leaf.
-        old_layout = self._bucket_layout
-        old_specs = self._state_specs
+        old = self.exchange
         evac = 0
-        if old_specs is not None:
+        if old.sharded:
             evac = len(plan.dead_ranks) * sum(
                 1 for s in jax.tree.leaves(
-                    old_specs.opt_state,
+                    old.state_specs.opt_state,
                     is_leaf=lambda x: isinstance(x, P))
                 if s == P(self.data_axis))
 
         # --- survivor topology: rebuild exactly what __init__ built, in
-        # the same order (mesh → flags → specs → steps), so the resized
-        # trainer is indistinguishable from one constructed at size N−k.
-        old_params_struct = self._params_struct
+        # the same order (mesh → optimizer → exchange → steps), so the
+        # resized trainer is indistinguishable from one constructed at
+        # size N−k.
         self.mesh = elastic.shrink_mesh(self.mesh, self.data_axis, plan)
-        self.num_shards = plan.new_size
-        self.zero1 = bool(cfg.mesh.shard_opt_state) and self.num_shards > 1
-        self.zero2 = self.zero1 and bool(cfg.mesh.shard_gradients)
-        self.zero3 = self.zero2 and bool(cfg.mesh.shard_params)
         self._replicated = NamedSharding(self.mesh, P())
-        # _make_state_specs only assigns the layout on the bucketed
-        # branch — reset first or a dp/zero1 resize would keep the stale
-        # bucket geometry in the checkpoint receipts
-        self._bucket_layout = None
         if plan.lr_scale != 1.0:
             self.tx, self.schedule = build_optimizer(
                 cfg, lr_scale=plan.lr_scale)
-        self._state_specs = self._make_state_specs()
+        self._plan_exchange()
         self._build_steps()
-        # the params TREE geometry: under ZeRO-3 state.params is the flat
-        # shard vector, so the tree comes from the specs build (identical
-        # across topologies — it is a function of the model alone); the
-        # pre-resize struct covers a zero1+ → dp downgrade to one shard
-        params_struct = (self._params_struct or old_params_struct
-                         or jax.eval_shape(lambda p: p, state.params))
-        opt_sh = (self._state_sharding().opt_state if self.zero1
-                  else self._replicated)
-        state = elastic.reshard_train_state(
-            state, self.tx, params_struct=params_struct,
-            target_padded=self._padded,
-            src_bucket_layout=old_layout,
-            target_bucket_layout=self._bucket_layout,
-            replicated=self._replicated, opt_shardings=opt_sh,
-            target_params_padded=self._padded if self.zero3 else None,
-            params_shardings=(self._state_sharding().params if self.zero3
-                              else None))
+        state = elastic.reshard_train_state(state, old, self.exchange,
+                                            self.mesh)
 
         # --- feed over the new mesh: tear down the old chain, clear the
         # fired preempt injector (its >= predicate stays true forever), and

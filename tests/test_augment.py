@@ -335,10 +335,17 @@ def _build_step(mesh, model, device_augment, **kw):
 
     from distributed_vgg_f_tpu.train.step import build_train_step
     tx = optax.sgd(0.05, momentum=0.9)
-    step = build_train_step(model, tx, mesh, weight_decay=1e-4,
+    step = build_train_step(model, mesh, 1e-4, _dp_plan(mesh, tx),
                             device_finish=make_device_finish(MEAN, STD),
                             device_augment=device_augment, **kw)
     return tx, step
+
+
+def _dp_plan(mesh, tx=None):
+    """Plain data parallelism as an exchange plan (parallel/zero.py)."""
+    from distributed_vgg_f_tpu.config import MeshConfig
+    from distributed_vgg_f_tpu.parallel.zero import plan_exchange
+    return plan_exchange(MeshConfig(), mesh, tx)
 
 
 def _mini_state(model, tx):
@@ -376,7 +383,8 @@ def test_eval_never_augments(devices8):
     import optax
     state = _mini_state(model, optax.sgd(0.1))
     finish = make_device_finish(MEAN, STD)
-    eval_step = build_eval_step(model, mesh, device_finish=finish)
+    eval_step = build_eval_step(model, mesh, _dp_plan(mesh),
+                                device_finish=finish)
     batch = shard_host_batch(
         {"image": np.random.default_rng(5).integers(
             0, 256, size=(16, 16, 16, 3)).astype(np.uint8),
@@ -392,7 +400,8 @@ def test_eval_never_augments(devices8):
     # eval computation (proven on the lowered text, which includes every
     # op), and produce identical counts
     low = eval_step.lower(state, batch).as_text()
-    eval_step2 = build_eval_step(model, mesh, device_finish=finish)
+    eval_step2 = build_eval_step(model, mesh, _dp_plan(mesh),
+                                device_finish=finish)
     assert eval_step2.lower(state, batch).as_text() == low
     counts2 = {k: int(v) for k, v in
                jax.device_get(eval_step2(state, batch)).items()}
@@ -449,7 +458,6 @@ def test_augment_composes_with_zero1_and_accum(devices8):
     replicated DP step-for-step, and grad accumulation slices the mixup
     label pairing correctly (BN-free model: summed micro-grads equal the
     big-batch gradient exactly)."""
-    from jax.sharding import PartitionSpec as P
     mesh = _mesh8(devices8)
     model = _MiniNet()
     aug = make_device_augment(FLAGS_ON, MEAN, STD)
@@ -464,34 +472,28 @@ def test_augment_composes_with_zero1_and_accum(devices8):
     def run(zero1=False, accum=1):
         import optax
 
-        from distributed_vgg_f_tpu.parallel.zero import (
-            flat_param_count, padded_flat_size, train_state_specs)
+        from distributed_vgg_f_tpu.config import MeshConfig
+        from distributed_vgg_f_tpu.parallel.zero import plan_exchange
         from distributed_vgg_f_tpu.train.state import TrainState
         from distributed_vgg_f_tpu.train.step import build_train_step
         tx = optax.sgd(0.05, momentum=0.9)
-        specs = None
+        sample = jnp.zeros((1, 16, 16, 3), jnp.float32)
+        plan = plan_exchange(MeshConfig(shard_opt_state=zero1), mesh, tx,
+                             grad_accum_steps=accum)
         if zero1:
             shapes = jax.eval_shape(
-                lambda r: TrainState.create(
-                    model, tx, r, jnp.zeros((1, 16, 16, 3), jnp.float32),
-                    zero1_shards=8),
+                lambda r: TrainState.create(model, tx, r, sample),
                 jax.random.key(0))
-            padded = padded_flat_size(flat_param_count(shapes.params), 8)
-            specs = train_state_specs(shapes, padded, "data")
+            plan = plan.bind(shapes.params, shapes.batch_stats)
         step = build_train_step(
-            model, tx, mesh, weight_decay=1e-4, zero1=zero1,
-            state_specs=specs, grad_accum_steps=accum,
+            model, mesh, 1e-4, plan,
             device_finish=make_device_finish(MEAN, STD),
             device_augment=aug)
         if zero1:
-            from jax.sharding import NamedSharding
-            shardings = jax.tree.map(
-                lambda s: NamedSharding(mesh, s), specs,
-                is_leaf=lambda x: isinstance(x, P))
+            shardings = plan.state_shardings(mesh)
             state = jax.jit(
-                lambda r: TrainState.create(
-                    model, tx, r, jnp.zeros((1, 16, 16, 3), jnp.float32),
-                    zero1_shards=8),
+                lambda r: TrainState.create(model, tx, r, sample,
+                                            exchange=plan),
                 out_shardings=shardings)(jax.random.key(0))
         else:
             state = _mini_state(model, tx)
@@ -546,7 +548,7 @@ def test_zoo_wire_parity_with_augment(model_name, devices8):
             model, tx, jax.random.key(0),
             jnp.zeros((1, size, size, 3), jnp.float32))
         step = build_train_step(
-            model, tx, mesh, weight_decay=1e-4,
+            model, mesh, 1e-4, _dp_plan(mesh, tx),
             device_finish=make_device_finish(MEAN, STD),
             device_augment=aug)
         base = jax.jit(lambda: jax.random.key(1))()

@@ -7,13 +7,13 @@ two bucket sizes, the clip-after-cast x reduce_dtype pin (ISSUE 11
 bugfix satellite), checkpoint layout migration, comm telemetry, and the
 scaling-model memory claims."""
 
+import dataclasses
 import io
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_vgg_f_tpu.config import (
     DataConfig,
@@ -37,7 +37,7 @@ from distributed_vgg_f_tpu.parallel.mesh import (
 from distributed_vgg_f_tpu.parallel.zero import (
     flat_param_count,
     padded_flat_size,
-    train_state_specs,
+    plan_exchange,
 )
 from distributed_vgg_f_tpu.train.state import TrainState
 from distributed_vgg_f_tpu.train.step import build_train_step
@@ -104,13 +104,16 @@ def test_flagship_ships_zero2_bucketed():
         assert get_config(name).mesh.sharding_label == "zero2"
 
 
-def test_step_rejects_zero2_without_zero1():
+def test_plan_downgrades_zero2_without_zero1():
+    """`shard_gradients` without `shard_opt_state` has no 1/N frame to live
+    in: the exchange plan runs it as plain dp, as the config's label says
+    (the README's `--set mesh.shard_opt_state=false` toggle on presets that
+    ship ZeRO-2)."""
     import optax
-    model = _MiniNet()
     mesh = build_mesh(MeshSpec(("data",), (0,)))
-    with pytest.raises(ValueError, match="shard_gradients"):
-        build_train_step(model, optax.sgd(0.1), mesh, weight_decay=0.0,
-                         shard_gradients=True)
+    asked = MeshConfig(shard_gradients=True)
+    plan = plan_exchange(asked, mesh, optax.sgd(0.1))
+    assert plan.basis == asked.sharding_label == "dp"
 
 
 # ----------------------------------------------------------- layout geometry
@@ -185,41 +188,42 @@ def test_layout_receipt_roundtrip_and_mismatch():
 
 
 # -------------------------------------------------- step builders for grids
+def _plan(mesh, model, tx, sample, *, basis="dp", bucket_mb=0.0, accum=1,
+          reduce_dtype="float32", ema=False, accum_shard=False):
+    """The exchange plan (parallel/zero.py) of one grid cell, bound to the
+    model's parameter shapes where the basis shards anything."""
+    plan = plan_exchange(
+        MeshConfig(shard_opt_state=basis != "dp",
+                   shard_gradients=basis in ("zero2", "zero3"),
+                   shard_params=basis == "zero3",
+                   comm_bucket_mb=bucket_mb, reduce_dtype=reduce_dtype),
+        mesh, tx, grad_accum_steps=accum, grad_accum_shard=accum_shard)
+    assert plan.basis == basis
+    if plan.sharded:
+        shapes = jax.eval_shape(
+            lambda r: TrainState.create(model, tx, r, sample),
+            jax.random.key(0))
+        plan = plan.bind(shapes.params, shapes.batch_stats, ema=ema)
+    return plan
+
+
 def _build(mesh, model, *, zero=False, zero2=False, bucket_mb=0.0,
            accum=1, reduce_dtype="float32", clip=0.0, sample_hw=16):
     import optax
     tx = optax.sgd(0.05, momentum=0.9)
     sample = jnp.zeros((1, sample_hw, sample_hw, 3), jnp.float32)
-    specs = None
-    state = None
+    plan = _plan(mesh, model, tx, sample,
+                 basis="zero2" if zero2 else "zero1" if zero else "dp",
+                 bucket_mb=bucket_mb, accum=accum, reduce_dtype=reduce_dtype)
     if zero:
-        layout = None
-        shapes = jax.eval_shape(
-            lambda r: TrainState.create(model, tx, r, sample,
-                                        zero1_shards=8),
-            jax.random.key(0))
-        if bucket_mb > 0:
-            layout = build_bucket_layout(shapes.params, 8,
-                                         int(bucket_mb * 1024 * 1024))
-            padded = layout.total_padded
-        else:
-            padded = padded_flat_size(flat_param_count(shapes.params), 8)
-
         def create(r):
-            return TrainState.create(model, tx, r, sample, zero1_shards=8,
-                                     bucket_layout=layout)
+            return TrainState.create(model, tx, r, sample, exchange=plan)
 
-        specs = train_state_specs(jax.eval_shape(create, jax.random.key(0)),
-                                  padded, "data")
-        shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                                 is_leaf=lambda x: isinstance(x, P))
+        shardings = plan.state_shardings(mesh)
         state = jax.jit(create, out_shardings=shardings)(jax.random.key(0))
     else:
         state = TrainState.create(model, tx, jax.random.key(0), sample)
-    step = build_train_step(model, tx, mesh, weight_decay=1e-4, zero1=zero,
-                            state_specs=specs, grad_accum_steps=accum,
-                            shard_gradients=zero2, comm_bucket_mb=bucket_mb,
-                            reduce_dtype=reduce_dtype, grad_clip_norm=clip)
+    step = build_train_step(model, mesh, 1e-4, plan, grad_clip_norm=clip)
     return state, step
 
 
@@ -449,9 +453,17 @@ def test_convert_opt_state_bucketed_roundtrip(devices8):
     from distributed_vgg_f_tpu.parallel.zero import convert_opt_state
     model, params = _mini_params()
     tx = optax.sgd(0.05, momentum=0.9)
+    mesh = _mesh8(devices8)
+    zero1 = MeshConfig(shard_opt_state=True)
+    canonical = plan_exchange(zero1, mesh, tx).bind(params)
+    bucketed = plan_exchange(
+        dataclasses.replace(zero1, comm_bucket_mb=1024 / 2 ** 20),
+        mesh, tx).bind(params)
     n = flat_param_count(params)
     padded = padded_flat_size(n, 8)
+    assert canonical.total_padded == padded
     layout = build_bucket_layout(params, 8, 1024)
+    assert bucketed.layout == layout
     # a canonical flat state with a recognizable momentum pattern
     rng = np.random.default_rng(3)
     canon_vec = jnp.asarray(
@@ -462,17 +474,23 @@ def test_convert_opt_state_bucketed_roundtrip(devices8):
     canon = jax.tree.map(
         lambda l: (canon_vec if l.ndim == 1 and l.shape[0] == padded
                    else jnp.zeros(l.shape, l.dtype)), canon)
-    bucketed = convert_opt_state(canon, tx, params,
-                                 layout.total_padded,
-                                 target_bucket_layout=layout)
-    back = convert_opt_state(bucketed, tx, params, padded,
-                             src_bucket_layout=layout)
-    for a, b in zip(jax.tree.leaves(canon), jax.tree.leaves(back)):
+    # the source as the plan that held the state, and as the receipt a
+    # checkpoint of it carries (none for the canonical layout)
+    there = convert_opt_state(canon, canonical, bucketed)
+    same = convert_opt_state(canon, None, bucketed)
+    back = convert_opt_state(there, bucketed.receipts()["opt_layout"],
+                             canonical)
+    for a, b, c in zip(jax.tree.leaves(canon), jax.tree.leaves(back),
+                       jax.tree.leaves(
+                           convert_opt_state(same, bucketed, canonical))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # mismatched geometry must fail loudly
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+    # mismatched geometry must fail loudly: a saved vector of another
+    # length than the source layout's
+    longer = jax.tree.map(
+        lambda l: jnp.pad(l, (0, 8)) if l.ndim == 1 else l, there)
     with pytest.raises(ValueError, match="total_padded"):
-        convert_opt_state(canon, tx, params, layout.total_padded + 8,
-                          target_bucket_layout=layout)
+        convert_opt_state(longer, bucketed, canonical)
 
 
 # --------------------------------------------------------------- telemetry
@@ -715,7 +733,7 @@ def test_zero2_bucketed_checkpoint_migration(tmp_path):
                       tmp_path / "bucketed")
     tr_b, state_b, _ = _trainer_run(cfg_b, n_steps=2)
     tr_b.checkpoints.save(state_b, force=True,
-                          extra=tr_b._opt_layout_extra())
+                          extra=tr_b.exchange.receipts())
     tr_b.checkpoints.wait()
     # (a) same-layout roundtrip
     restored = tr_b.restore_or_init()
@@ -730,9 +748,9 @@ def test_zero2_bucketed_checkpoint_migration(tmp_path):
     tr_c = Trainer(cfg_c, logger=MetricLogger(stream=io.StringIO()))
     rest_c = tr_c.restore_or_init()
     mom_b = [l for l in jax.tree.leaves(jax.device_get(state_b.opt_state))
-             if getattr(l, "ndim", 0) == 1 and l.size == tr_b._padded][0]
+             if getattr(l, "ndim", 0) == 1 and l.size == tr_b.exchange.total_padded][0]
     mom_c = [l for l in jax.tree.leaves(jax.device_get(rest_c.opt_state))
-             if getattr(l, "ndim", 0) == 1 and l.size == tr_c._padded][0]
+             if getattr(l, "ndim", 0) == 1 and l.size == tr_c.exchange.total_padded][0]
     canon_from_b = jax.flatten_util.ravel_pytree(
         tr_b._bucket_layout.from_global(jnp.asarray(mom_b)))[0]
     np.testing.assert_array_equal(np.asarray(canon_from_b),
@@ -751,10 +769,10 @@ def test_zero2_bucketed_checkpoint_migration(tmp_path):
     rest_b2 = tr_b2.restore_or_init()
     mom_z1 = [l for l in
               jax.tree.leaves(jax.device_get(state_z1.opt_state))
-              if getattr(l, "ndim", 0) == 1 and l.size == tr_z1._padded][0]
+              if getattr(l, "ndim", 0) == 1 and l.size == tr_z1.exchange.total_padded][0]
     mom_b2 = [l for l in
               jax.tree.leaves(jax.device_get(rest_b2.opt_state))
-              if getattr(l, "ndim", 0) == 1 and l.size == tr_b2._padded][0]
+              if getattr(l, "ndim", 0) == 1 and l.size == tr_b2.exchange.total_padded][0]
     canon_from_b2 = jax.flatten_util.ravel_pytree(
         tr_b2._bucket_layout.from_global(jnp.asarray(mom_b2)))[0]
     np.testing.assert_array_equal(

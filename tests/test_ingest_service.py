@@ -344,7 +344,17 @@ def test_fault_worker_kill_through_live_client():
             assert np.array_equal(next(wrapped)["image"],
                                   next(local)["image"]), b
         assert reg.counter_value("fault/worker_kill", 0) == before + 1
-        assert client.describe()["workers_live"] == 1
+        # The client DISCOVERS the death on a failed request, in a
+        # fetch-ahead thread: the killed worker's cursors 8 and 10 were
+        # scheduled by the draws above and must reach it, but nothing
+        # orders that failure before this line (asserting it at once raced
+        # the thread on a loaded host). Wait for the event itself.
+        import time
+        deadline = time.monotonic() + 30.0
+        while client.describe()["workers_live"] != 1:
+            assert time.monotonic() < deadline, \
+                "the client never discovered the killed worker"
+            time.sleep(0.01)
     finally:
         client.close()
         for w in workers:

@@ -261,9 +261,10 @@ def test_kill_switch_rule_ignores_tuning_knobs(tmp_path):
 
 def test_kill_switch_rule_covers_config_plane_switches(tmp_path):
     """r18/r19: every declared config-plane switch
-    (data.iterator_state.enabled, mesh.elastic.enabled) needs a boolean
-    config field AND a tier-1 test naming the dotted switch — each absence
-    is its own violation; a complete set is clean."""
+    (`rules.CONFIG_KILL_SWITCHES`: data.iterator_state.enabled,
+    mesh.elastic.enabled, mesh.shard_params, serving.tiers.enabled) needs a
+    boolean config field AND a tier-1 test naming the dotted switch — each
+    absence is its own violation; a complete set is clean."""
     cc = _COMPLETE_SWITCH
     good_cfg = """\
         from dataclasses import dataclass
@@ -279,10 +280,20 @@ def test_kill_switch_rule_covers_config_plane_switches(tmp_path):
         @dataclass(frozen=True)
         class MeshConfig:
             shard_params: bool = False
+
+        @dataclass(frozen=True)
+        class ServingTiersConfig:
+            enabled: bool = False
     """
     good_test = ('SWITCH = "data.iterator_state.enabled"\n'
                  'ELASTIC = "mesh.elastic.enabled"\n'
-                 'ZERO3 = "mesh.shard_params"\n')
+                 'ZERO3 = "mesh.shard_params"\n'
+                 'TIERS = "serving.tiers.enabled"\n')
+    # the fixture names every switch the rule declares: a new one fails
+    # here by name, not as a violation count on a "clean" tree
+    from tools.lint.rules import CONFIG_KILL_SWITCHES
+    assert all(f"class {cls}" in good_cfg and dotted in good_test
+               for dotted, cls, _ in CONFIG_KILL_SWITCHES)
     _write(tmp_path, "native/x.cc", cc)
     _write(tmp_path, "distributed_vgg_f_tpu/config.py", good_cfg)
     _write(tmp_path, "tests/test_x.py", good_test)

@@ -75,26 +75,6 @@ def test_matmul_vjp_gradient_matches_autodiff_oracle():
                                rtol=1e-4, atol=1e-6)
 
 
-def test_shift_vjp_matches_oracle_fwd_and_bwd():
-    """The shifted-slice form (kept as a measured TPU non-win / oracle
-    cross-check) must still be numerically exact."""
-    import jax
-
-    from distributed_vgg_f_tpu.ops.lrn import local_response_norm_shift_vjp
-
-    rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.standard_normal((2, 5, 5, 48), dtype=np.float32))
-    cot = jnp.asarray(rng.standard_normal((2, 5, 5, 48), dtype=np.float32))
-    np.testing.assert_allclose(
-        np.asarray(local_response_norm_shift_vjp(x)),
-        np.asarray(local_response_norm(x)), rtol=1e-5, atol=1e-6)
-    g_oracle = jax.grad(lambda v: (local_response_norm(v) * cot).sum())(x)
-    g_shift = jax.grad(
-        lambda v: (local_response_norm_shift_vjp(v) * cot).sum())(x)
-    np.testing.assert_allclose(np.asarray(g_shift), np.asarray(g_oracle),
-                               rtol=1e-4, atol=1e-6)
-
-
 def test_dispatcher_default_is_custom_vjp():
     import jax
 

@@ -18,7 +18,6 @@ import distributed_vgg_f_tpu.ops.lrn_pallas as lrn_pallas
 from distributed_vgg_f_tpu.ops import lrn as lrn_mod
 from distributed_vgg_f_tpu.ops.lrn import (
     local_response_norm,
-    local_response_norm_matmul,
     local_response_norm_matmul_vjp,
     lrn,
     lrn_site_counts,
@@ -145,18 +144,18 @@ def test_dispatcher_takes_the_pair_where_it_applies():
 def test_matmul_forward_matches_oracle():
     x = jax.random.normal(jax.random.key(1), (2, 5, 5, 64), jnp.float32) * 2.0
     want = local_response_norm(x)
-    got = local_response_norm_matmul(x)
+    got = local_response_norm_matmul_vjp(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
 
 
 def test_matmul_gradient_matches_oracle():
-    """Autodiff of the matmul form equals autodiff of the reduce_window
-    oracle."""
+    """The matmul form's hand-written VJP equals autodiff of the
+    reduce_window oracle."""
     x = jax.random.normal(jax.random.key(2), (2, 4, 4, 64), jnp.float32)
     cot = jax.random.normal(jax.random.key(3), x.shape, jnp.float32)
     _, want = _value_and_grad(local_response_norm, x, cot)
-    _, got = _value_and_grad(local_response_norm_matmul, x, cot)
+    _, got = _value_and_grad(local_response_norm_matmul_vjp, x, cot)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-4, atol=3e-6)
 
@@ -166,7 +165,7 @@ def test_dispatcher_override():
     try:
         set_lrn_impl("reduce_window")
         a = lrn(x)
-        set_lrn_impl("matmul")
+        set_lrn_impl("matmul_vjp")
         b = lrn(x)
     finally:
         set_lrn_impl(None)
@@ -176,10 +175,11 @@ def test_dispatcher_override():
 
 def test_set_lrn_impl_names_are_what_they_were():
     try:
-        for name in ("shift_vjp", "matmul_vjp", "pallas", "matmul",
-                     "reduce_window", None):
+        for name in ("matmul_vjp", "pallas", "reduce_window", None):
             set_lrn_impl(name)
-        for name in ("fused", "rows", "sublanes", "auto", ""):
+        # "matmul" and "shift_vjp" went with their code (PR 30)
+        for name in ("matmul", "shift_vjp", "fused", "rows", "sublanes",
+                     "auto", ""):
             with pytest.raises(ValueError):
                 set_lrn_impl(name)
     finally:

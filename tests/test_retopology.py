@@ -26,6 +26,7 @@ from distributed_vgg_f_tpu.parallel.zero import (
     convert_opt_state,
     flat_param_count,
     padded_flat_size,
+    plan_exchange,
 )
 from distributed_vgg_f_tpu.train.trainer import Trainer
 from distributed_vgg_f_tpu.utils.logging import MetricLogger
@@ -70,9 +71,10 @@ def _train_and_save(cfg, mesh_size: int, steps: int = 2):
 
 def _canonical_opt(trainer, state):
     """The opt state in the layout-independent params-tree form (host)."""
-    params_struct = jax.eval_shape(lambda p: p, state.params)
-    canon = convert_opt_state(jax.device_get(state.opt_state), trainer.tx,
-                              params_struct, None)
+    tree = plan_exchange(MeshConfig(), trainer.mesh, trainer.tx).bind(
+        state.params)
+    canon = convert_opt_state(jax.device_get(state.opt_state),
+                              trainer.exchange, tree)
     return jax.tree.leaves(jax.device_get(canon))
 
 
@@ -254,7 +256,4 @@ def test_mismatched_optimizer_chain_fails_loudly(devices8, tmp_path):
     mgr.wait()
 
     with pytest.raises(ValueError, match="optimizer chain"):
-        restore_any_topology(
-            mgr, template, tr.tx,
-            opt_shardings=tr._state_sharding().opt_state,
-            target_padded=tr._padded)
+        restore_any_topology(mgr, template, tr.exchange)

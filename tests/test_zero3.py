@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_vgg_f_tpu.config import (
     DataConfig,
@@ -30,7 +29,6 @@ from distributed_vgg_f_tpu.config import (
     get_config,
 )
 from distributed_vgg_f_tpu.parallel.buckets import (
-    build_bucket_layout,
     hlo_overlap_report,
     sharding_basis,
 )
@@ -38,13 +36,13 @@ from distributed_vgg_f_tpu.parallel.mesh import MeshSpec, build_mesh
 from distributed_vgg_f_tpu.parallel.zero import (
     flat_param_count,
     padded_flat_size,
-    train_state_specs,
+    plan_exchange,
 )
 from distributed_vgg_f_tpu.resilience.errors import GeometryReceiptError
 from distributed_vgg_f_tpu.train.state import TrainState
 from distributed_vgg_f_tpu.train.step import build_train_step
 
-from test_comm_buckets import _batches, _mesh8, _MiniNet
+from test_comm_buckets import _batches, _mesh8, _MiniNet, _plan
 
 
 # ------------------------------------------------------------------- config
@@ -69,23 +67,35 @@ def test_config_zero3_ladder():
     assert sharding_basis(True, True, False) == "zero2"
 
 
-def test_state_create_rejects_shard_params_without_zero1():
+def test_one_shard_plan_stores_the_params_tree(devices8):
+    """The ladder downgrades in the plan alone: on a one-shard mesh a
+    configured zero3 is plain dp, and `TrainState.create` given that plan
+    keeps the params tree and the tree-shaped optimizer state."""
     import optax
     model = _MiniNet()
-    with pytest.raises(ValueError, match="shard_params"):
-        TrainState.create(model, optax.sgd(0.1), jax.random.key(0),
-                          jnp.zeros((1, 16, 16, 3), jnp.float32),
-                          shard_params=True)
+    tx = optax.sgd(0.1, momentum=0.9)
+    mesh1 = build_mesh(MeshSpec(("data",), (1,)), devices8[:1])
+    plan = plan_exchange(MeshConfig(shard_opt_state=True,
+                                    shard_gradients=True, shard_params=True),
+                         mesh1, tx)
+    assert plan.basis == "dp" and not plan.zero3
+    sample = jnp.zeros((1, 16, 16, 3), jnp.float32)
+    with_plan = TrainState.create(model, tx, jax.random.key(0), sample,
+                                  exchange=plan)
+    plain = TrainState.create(model, tx, jax.random.key(0), sample)
+    assert jax.tree.structure(with_plan) == jax.tree.structure(plain)
 
 
-def test_step_rejects_shard_params_without_zero2():
+def test_plan_rejects_shard_params_without_zero2():
     import optax
-    model = _MiniNet()
     mesh = build_mesh(MeshSpec(("data",), (0,)))
+    # MeshConfig refuses this ladder itself; the plan checks what reaches
+    # it from anywhere else
+    asked = types.SimpleNamespace(
+        data_axis="data", shard_opt_state=True, shard_gradients=False,
+        shard_params=True, comm_bucket_mb=0.0, reduce_dtype="float32")
     with pytest.raises(ValueError, match="shard_params"):
-        build_train_step(model, optax.sgd(0.1), mesh, weight_decay=0.0,
-                         zero1=True, shard_gradients=False,
-                         shard_params=True)
+        plan_exchange(asked, mesh, optax.sgd(0.1))
 
 
 # ------------------------------------------------- step builders for grids
@@ -96,36 +106,20 @@ def _build(mesh, model, *, zero3=False, bucket_mb=0.0, accum=1,
     import optax
     tx = optax.sgd(0.05, momentum=0.9)
     sample = jnp.zeros((1, sample_hw, sample_hw, 3), jnp.float32)
-    shapes = jax.eval_shape(
-        lambda r: TrainState.create(model, tx, r, sample, zero1_shards=8),
-        jax.random.key(0))
-    p_struct = shapes.params
-    layout = None
-    if bucket_mb > 0:
-        layout = build_bucket_layout(p_struct, 8,
-                                     int(bucket_mb * 1024 * 1024))
-        padded = layout.total_padded
-    else:
-        padded = padded_flat_size(flat_param_count(p_struct), 8)
+    plan = _plan(mesh, model, tx, sample,
+                 basis="zero3" if zero3 else "zero2", bucket_mb=bucket_mb,
+                 accum=accum, reduce_dtype=reduce_dtype, ema=ema > 0)
 
     def create(r):
-        return TrainState.create(model, tx, r, sample, zero1_shards=8,
-                                 bucket_layout=layout, shard_params=zero3,
-                                 ema=ema > 0)
+        return TrainState.create(model, tx, r, sample, ema=ema > 0,
+                                 exchange=plan)
 
-    specs = train_state_specs(jax.eval_shape(create, jax.random.key(0)),
-                              padded, "data", shard_params=zero3)
-    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                             is_leaf=lambda x: isinstance(x, P))
+    shardings = plan.state_shardings(mesh)
     state = jax.jit(create, out_shardings=shardings)(jax.random.key(0))
-    step = build_train_step(model, tx, mesh, weight_decay=1e-4, zero1=True,
-                            state_specs=specs, grad_accum_steps=accum,
-                            shard_gradients=True, shard_params=zero3,
-                            params_struct=p_struct if zero3 else None,
-                            comm_bucket_mb=bucket_mb,
-                            reduce_dtype=reduce_dtype, grad_clip_norm=clip,
+    step = build_train_step(model, mesh, 1e-4, plan, grad_clip_norm=clip,
                             ema_decay=ema)
-    return state, step, p_struct, layout
+    layout = plan.layout if bucket_mb > 0 else None
+    return state, step, plan.params_struct, layout
 
 
 def _run(mesh, model, batches, base, n=3, **kw):
@@ -299,21 +293,17 @@ def test_geometry_receipt_refusals(devices8):
     model = _MiniNet()
     tx = optax.sgd(0.05, momentum=0.9)
     sample = jnp.zeros((1, 16, 16, 3), jnp.float32)
-    shapes = jax.eval_shape(
-        lambda r: TrainState.create(model, tx, r, sample, zero1_shards=8),
-        jax.random.key(0))
-    p_struct = shapes.params
+    plan = _plan(mesh, model, tx, sample, basis="zero3")
+    p_struct = plan.params_struct
     padded = padded_flat_size(flat_param_count(p_struct), 8)
+    assert padded == plan.total_padded
     flat = jax.ShapeDtypeStruct((padded,), jnp.float32)
     opt_meta = jax.eval_shape(tx.init, flat)
 
     def create():
         return TrainState.create(model, tx, jax.random.key(0), sample,
-                                 zero1_shards=8, shard_params=True)
-    specs = train_state_specs(jax.eval_shape(create), padded, "data",
-                              shard_params=True)
-    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                             is_leaf=lambda x: isinstance(x, P))
+                                 exchange=plan)
+    shardings = plan.state_shardings(mesh)
     template = jax.jit(create, out_shardings=shardings)()
 
     from distributed_vgg_f_tpu.checkpoint.retopology import (
@@ -323,25 +313,19 @@ def test_geometry_receipt_refusals(devices8):
         "param_layout": {"kind": "canonical_flat", "num_shards": 8,
                          "total_padded": padded + 8}})
     with pytest.raises(GeometryReceiptError, match="total_padded"):
-        restore_any_topology(mgr, template, tx, opt_shardings=None,
-                             target_padded=padded,
-                             params_tree_struct=p_struct)
+        restore_any_topology(mgr, template, plan)
     # (b) bucketed_flat kind with no opt receipt naming the geometry
     mgr = _fake_manager(opt_meta, flat, {
         "param_layout": {"kind": "bucketed_flat", "num_shards": 8,
                          "total_padded": padded}})
     with pytest.raises(GeometryReceiptError, match="bucket"):
-        restore_any_topology(mgr, template, tx, opt_shardings=None,
-                             target_padded=padded,
-                             params_tree_struct=p_struct)
+        restore_any_topology(mgr, template, plan)
     # (c) receipt present but the saved params are a TREE
     mgr = _fake_manager(opt_meta, p_struct, {
         "param_layout": {"kind": "canonical_flat", "num_shards": 8,
                          "total_padded": padded}})
     with pytest.raises(GeometryReceiptError, match="tree"):
-        restore_any_topology(mgr, template, tx, opt_shardings=None,
-                             target_padded=padded,
-                             params_tree_struct=p_struct)
+        restore_any_topology(mgr, template, plan)
 
 
 # ------------------------------------------------------- trainer-level
@@ -418,9 +402,9 @@ def test_zero3_checkpoint_retopology(tmp_path):
     # (a) + (b): zero3 write, zero3 + zero2 reads
     tr3, st3, _ = _trainer_run(_trainer_cfg(ema=0.9, ckpt=tmp_path / "z3",
                                             **Z3), n_steps=2)
-    tr3.checkpoints.save(st3, force=True, extra=tr3._opt_layout_extra())
+    tr3.checkpoints.save(st3, force=True, extra=tr3.exchange.receipts())
     tr3.checkpoints.wait()
-    assert tr3._opt_layout_extra()["param_layout"]["kind"] \
+    assert tr3.exchange.receipts()["param_layout"]["kind"] \
         == "bucketed_flat"
     r3 = tr3.restore_or_init()
     np.testing.assert_array_equal(
@@ -439,7 +423,7 @@ def test_zero3_checkpoint_retopology(tmp_path):
     tr2b, st2b, _ = _trainer_run(_trainer_cfg(ckpt=tmp_path / "z2", **Z2),
                                  n_steps=2)
     tr2b.checkpoints.save(st2b, force=True,
-                          extra=tr2b._opt_layout_extra())
+                          extra=tr2b.exchange.receipts())
     tr2b.checkpoints.wait()
     tr3c = Trainer(_trainer_cfg(ckpt=tmp_path / "z2", **Z3),
                    logger=MetricLogger(stream=io.StringIO()))
