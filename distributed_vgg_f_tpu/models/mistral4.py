@@ -21,13 +21,13 @@ chosen experts, held or not, and adds only its own experts' terms: what one
 chip of an expert-parallel group computes between the exchanges. What the
 absent experts would add is left out and the partial result goes on; no
 code stands in for the absent chips. With every expert held it is the whole
-layer. Nothing is dropped: the routed buffers hold every assignment a batch
-can make (tokens x top-k rows), sorted by expert, and the grouped products
-(`jax.lax.ragged_dot`, which the TPU compiler turns into its grouped-matmul
-kernel) visit the held experts' rows only. (Smaller buffers for the usual
-load, with these behind a `lax.cond` for the rest, would move a quarter of
-the rows; compiled for a v5e that form needs 1.6-2.9 GiB more temporaries
-than the chip has left beside this model's state: PERF.md, PR 28.)
+layer. Nothing is dropped, and the routed buffers are sized to the rows
+that are live: the held assignments, sorted by expert, go through the
+grouped products (`jax.lax.ragged_dot`, which the TPU compiler turns into
+its grouped-matmul kernel) `routed_capacity` rows at a time, in as many
+passes as the batch's load needs (`routed_experts`): one where the router
+is anywhere near balanced, `tokens x top-k / capacity` where every
+assignment falls on this share.
 
 Training only: serving (a latent cache), checkpoint re-topology and ZeRO's
 flat vector for this model are out of scope.
@@ -35,6 +35,7 @@ flat vector for this model are out of scope.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -209,6 +210,150 @@ def route(probs, top_k: int, first_expert: int, experts_held: int):
     return weights, jnp.where(held, local, experts_held)
 
 
+#: rows the routed buffers hold over the rows a balanced router sends this
+#: share. One expert layer of the benchmark's cell, forward and backward on
+#: a v5e (PERF.md, PR 31): 13.55 ms with buffers of 1,536 rows, 13.66 with
+#: 2,048, 13.78 with 3,072, 14.27 with 4,096, 19.55 with all 16,384; a
+#: second pass costs 4.9 ms. So room for twice the balanced load costs
+#: 0.11 ms a layer, a forty-fifth of the pass it saves.
+ROUTED_HEADROOM = 2.0
+#: the row tile of XLA:TPU's grouped-product kernel; a buffer is whole tiles
+ROUTED_ROW_TILE = 512
+
+
+def routed_capacity(assignments: int, experts_held: int,
+                    n_routed_experts: int) -> int:
+    """Rows of the routed buffers, from shapes alone: the share's part of
+    the batch's `assignments` (tokens x top-k) with headroom, in whole
+    tiles, and never more than there are assignments. A share that holds
+    every expert gets them all and makes one pass."""
+    balanced = assignments * experts_held / n_routed_experts
+    tiles = math.ceil(ROUTED_HEADROOM * balanced / ROUTED_ROW_TILE)
+    return min(assignments, tiles * ROUTED_ROW_TILE)
+
+
+def routed_passes(load, capacity: int):
+    """Passes of `capacity` rows that take every held assignment of `load`
+    (experts_held,); the first is made whatever the load."""
+    held = jnp.sum(load, dtype=jnp.int32)
+    return jax.lax.max(jax.lax.div(held + (capacity - 1), jnp.int32(capacity)),
+                       jnp.int32(1))
+
+
+def _window(x, weights, order, load, start, capacity):
+    """The held assignments `order[start:start + capacity]`: (their
+    indices, each one's token, the experts' group sizes clipped to this
+    window, which rows are a held assignment at all, the tokens' rows of
+    `x` with zeros behind the last held one, each row's weight)."""
+    with jax.named_scope("moe_dispatch"):
+        picked = jax.lax.dynamic_slice(order, (start,), (capacity,))
+        token = jax.lax.div(picked, jnp.int32(weights.shape[1]))
+        ends = jnp.cumsum(load)
+        sizes = jnp.clip(ends, start, start + capacity) \
+            - jnp.clip(ends - load, start, start + capacity)
+        live = (start + jnp.arange(capacity) < ends[-1])[:, None]
+        rows = jnp.where(live, x.at[token].get(mode="promise_in_bounds"), 0)
+    with jax.named_scope("moe_combine"):
+        weight = weights.reshape(-1).at[picked].get(
+            mode="promise_in_bounds")[:, None]
+    return picked, token, sizes, live, rows, weight
+
+
+def _hidden(rows, gate, up, sizes):
+    """silu(rows W_g) * rows W_u, each row through its own expert."""
+    return nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) \
+        * jax.lax.ragged_dot(rows, up, sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def routed_experts(x, gate, up, down, weights, order, load, capacity: int):
+    """sum_k w_k E_k(x) over the held experts, (tokens, hidden) float32.
+
+    `x` (tokens, hidden) and the held experts' `gate`, `up`, `down` in the
+    compute dtype, `weights` (tokens, top-k) float32, `order` the batch's
+    assignments (token x top-k + choice) sorted by held expert, those of
+    absent experts last, padded to whole windows, `load` (experts_held,).
+    `routed_passes` windows of `capacity` rows each, a loop whose length
+    the device reads from `load`. JAX never differentiates that choice:
+    the backward pass below is a second such loop, and nothing of the
+    forward pass is kept but its inputs. Rows behind the last held
+    assignment are zeros going in and coming out: what the grouped
+    products leave unwritten there reaches nothing."""
+    def one(c, routed):
+        _, token, sizes, live, rows, weight = _window(
+            x, weights, order, load, c * capacity, capacity)
+        with jax.named_scope("moe_experts"):
+            outs = jax.lax.ragged_dot(_hidden(rows, gate, up, sizes), down,
+                                      sizes)
+        with jax.named_scope("moe_combine"):
+            return routed.at[token].add(
+                jnp.where(live, outs, 0).astype(jnp.float32) * weight,
+                mode="promise_in_bounds")
+    return jax.lax.fori_loop(0, routed_passes(load, capacity), one,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+def _routed_forward(x, gate, up, down, weights, order, load, capacity):
+    return (routed_experts(x, gate, up, down, weights, order, load, capacity),
+            (x, gate, up, down, weights, order, load))
+
+
+def _routed_backward(capacity, inputs, d_routed):
+    x, gate, up, down, weights, order, load = inputs
+    dtype = x.dtype
+
+    def window_gradients(c):
+        """Window `c`: its hidden rows again, then the gradients of x,
+        gate, up, down and weights, each in its own dtype. With g a row's
+        cotangent, w its weight and h its hidden row, the row's output
+        h W_d is not needed again: q = g W_d^T gives dh = w q and
+        dw = q . h, and dW_d takes (w h)^T g."""
+        picked, token, sizes, live, rows, weight = _window(
+            x, weights, order, load, c * capacity, capacity)
+        with jax.named_scope("moe_combine"):
+            g = jnp.where(live, d_routed.at[token].get(
+                mode="promise_in_bounds"), 0).astype(dtype)
+        with jax.named_scope("moe_experts"):
+            h, pull = jax.vjp(functools.partial(_hidden, sizes=sizes), rows,
+                              gate, up)
+            by_down = lambda lhs, rhs: jax.lax.ragged_dot(lhs, rhs, sizes)
+            q, = jax.linear_transpose(lambda h: by_down(h, down), h)(g)
+            q = jnp.where(live, q, 0).astype(jnp.float32)
+            weighted = (h.astype(jnp.float32) * weight).astype(dtype)
+            d_down, = jax.linear_transpose(
+                lambda down: by_down(weighted, down), down)(g)
+            d_rows, d_gate, d_up = pull((q * weight).astype(dtype))
+        with jax.named_scope("moe_combine"):
+            d_weight = jnp.sum(
+                jnp.where(live, q * h.astype(jnp.float32), 0), axis=-1)
+            d_weights = jnp.zeros(weights.size, weights.dtype).at[picked].add(
+                d_weight, mode="promise_in_bounds").reshape(weights.shape)
+        with jax.named_scope("moe_dispatch"):
+            d_x = jnp.zeros_like(x).at[token].add(
+                jnp.where(live, d_rows, 0), mode="promise_in_bounds")
+        return d_x, d_gate, d_up, d_down, d_weights
+
+    def one_more(c, so_far):
+        # the cell never comes here; where a skewed batch does, the sums
+        # are float32 and the running totals keep the gradients' dtypes
+        scopes = ("moe_dispatch", "moe_experts", "moe_experts", "moe_experts",
+                  "moe_combine")
+        out = []
+        for scope, a, b in zip(scopes, so_far, window_gradients(c)):
+            with jax.named_scope(scope):
+                out.append((a.astype(jnp.float32)
+                            + b.astype(jnp.float32)).astype(a.dtype))
+        return tuple(out)
+
+    # the first window outside the loop: its gradients are the totals, with
+    # nothing to zero and nothing to add (0.4 GB of expert gradients)
+    return (*jax.lax.fori_loop(1, routed_passes(load, capacity), one_more,
+                               window_gradients(0)), None, None)
+
+
+routed_experts.defvjp(_routed_forward, _routed_backward)
+
+
 class ExpertShare(nn.Module):
     """The routed experts this chip holds, and the shared expert."""
     n_routed_experts: int
@@ -224,6 +369,7 @@ class ExpertShare(nn.Module):
         b, t, d = u.shape
         tokens, k, held = b * t, self.num_experts_per_tok, self.experts_held
         width, dtype = self.moe_intermediate_size, self.compute_dtype
+        capacity = routed_capacity(tokens * k, held, self.n_routed_experts)
         x = u.reshape(tokens, d)
         with jax.named_scope("moe_router"):
             router = self.param(
@@ -238,20 +384,16 @@ class ExpertShare(nn.Module):
         with jax.named_scope("moe_dispatch"):
             # every assignment of the batch, sorted by held expert; those
             # of experts that live elsewhere sort behind the last group,
-            # where the grouped products do not go
+            # where no window goes
             flat = local.reshape(tokens * k)
             order = jnp.argsort(flat, stable=True)
             load = jnp.bincount(flat, length=held + 1)[:held].astype(
                 jnp.int32)
-            # assignments to a held expert that no group took: 0 by
-            # construction (the buffers hold every assignment)
+            # assignments to a held expert that no window took: 0 by
+            # construction (the passes go on until the last of them)
             dropped = jnp.sum(flat < held).astype(jnp.int32) - jnp.sum(load)
-            in_group = (jnp.arange(tokens * k) < jnp.sum(load))[:, None]
-            # rows behind the last group are read by nothing: zeros there
-            # keep what the grouped products leave unwritten out of the
-            # backward pass
-            rows = jnp.where(in_group, x.at[order // k].get(
-                mode="promise_in_bounds"), 0)
+            # whole windows, so that the last one's slice is never shifted
+            order = jnp.pad(order, (0, -(tokens * k) % capacity))
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
         gate = self.param("experts_gate_proj", init, (held, d, width),
@@ -261,22 +403,15 @@ class ExpertShare(nn.Module):
         down = self.param("experts_down_proj", init, (held, width, d),
                           jnp.float32)
         with jax.named_scope("moe_experts"):
-            grouped = lambda lhs, rhs: jax.lax.ragged_dot(
-                lhs, rhs.astype(dtype), load)
-            outs = grouped(nn.silu(grouped(rows, gate)) * grouped(rows, up),
-                           down)
-        with jax.named_scope("moe_combine"):
-            outs = jnp.where(in_group, outs, 0)
-            # assignment (token, choice) -> its sorted row; one gather of
-            # `tokens` rows a choice, so that no (tokens x k)-row buffer
-            # has to be retiled into (tokens, k, d)
-            place = jnp.argsort(order).reshape(tokens, k)
-            routed = sum(
-                weights[:, j, None].astype(jnp.float32)
-                * outs.at[place[:, j]].get(
-                    mode="promise_in_bounds",
-                    unique_indices=True).astype(jnp.float32)
-                for j in range(k))
+            # cast before the routed path: the experts' gradients leave it
+            # in the compute dtype
+            gate, up, down = (w.astype(dtype) for w in (gate, up, down))
+        routed = routed_experts(x, gate, up, down, weights, order, load,
+                                capacity)
+        # the routed path's own receipts, for a caller that asks for them
+        # (`mutable=["counters"]`): 1 pass = the compact buffers held all
+        self.sow("counters", "passes", routed_passes(load, capacity))
+        self.sow("counters", "capacity", capacity)
         with jax.named_scope("moe_shared"):
             shared_width = width * self.n_shared_experts
             mid = nn.silu(_dense(shared_width, dtype, "shared_gate_proj")(x)) \

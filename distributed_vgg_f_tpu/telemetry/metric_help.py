@@ -33,7 +33,8 @@ NAMESPACE_HELP = {
     "fault": "chaos injectors (injected nan/stall/crash/preempt/kills)",
     "step": "jitted train-step dispatch wrapper",
     "moe": "language-model expert routing (assignments held, expert "
-           "load extremes, dropped assignments)",
+           "load extremes, dropped assignments, passes over the routed "
+           "buffers and the rows they hold)",
     "eval": "trainer evaluation passes",
     "distributed": "cross-process coordination barriers",
     "telemetry": "the telemetry registry itself (poller faults)",

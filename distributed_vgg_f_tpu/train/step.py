@@ -52,12 +52,14 @@ def _apply_model(model, params, batch_stats, images, *, train: bool,
     return logits, batch_stats
 
 
-def _expert_load_metrics(counts) -> dict:
+def _expert_load_metrics(counts, counters) -> dict:
     """A language model's routing counters as step metrics. `counts` is
     (layers, experts held + 1): the assignments each held expert took in
-    this replica's batch, then the dropped ones (models/mistral4.py). Per
+    this replica's batch, then the dropped ones; `counters` is what the
+    layers sowed (models/mistral4.py), a dict for each `layer_N`. Per
     layer: assignments held, the largest and smallest held expert's load,
-    dropped assignments; and the whole table as `moe_load`."""
+    dropped assignments, the passes the routed path made over its
+    buffers and the rows those hold; and the whole table as `moe_load`."""
     counts = counts.astype(jnp.float32)
     load, dropped = counts[:, :-1], counts[:, -1]
     metrics = {"moe_load": load}
@@ -66,6 +68,10 @@ def _expert_load_metrics(counts) -> dict:
         metrics[f"moe_load_max/layer_{i}"] = jnp.max(load[i])
         metrics[f"moe_load_min/layer_{i}"] = jnp.min(load[i])
         metrics[f"moe_dropped/layer_{i}"] = dropped[i]
+        for module in counters[f"layer_{i}"].values():
+            for name, (value,) in module.items():
+                metrics[f"moe_{name}/layer_{i}"] = jnp.asarray(
+                    value, jnp.float32)
     return metrics
 
 
@@ -189,12 +195,14 @@ def build_train_step(model, mesh: Mesh, weight_decay: float,
         def make_loss_fn(images, labels, mix_labels, batch_stats,
                          dropout_rng):
             def token_loss_fn(params):
-                ce, counts = model.apply({"params": params}, images, labels,
-                                         method="next_token_loss")
+                (ce, counts), sown = model.apply(
+                    {"params": params}, images, labels,
+                    method="next_token_loss", mutable=["counters"])
                 with jax.named_scope("loss"):
                     l2 = l2_regularization(params, weight_decay)
                     metrics = {"loss": ce, "l2_loss": l2,
-                               **_expert_load_metrics(counts)}
+                               **_expert_load_metrics(counts,
+                                                      sown["counters"])}
                 return ce + l2, (batch_stats, metrics)
 
             if batch_kind == "tokens":

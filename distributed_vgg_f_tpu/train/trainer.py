@@ -1096,6 +1096,14 @@ class Trainer:
                             reg.set_gauge("moe/dropped_assignments", sum(
                                 v for k, v in last_metrics.items()
                                 if k.startswith("moe_dropped/")))
+                            # 1 = the routed buffers (`routed_capacity`
+                            # rows) held every layer's load in one pass
+                            for gauge, metric in (
+                                    ("moe/routed_passes", "moe_passes/"),
+                                    ("moe/routed_capacity", "moe_capacity/")):
+                                reg.set_gauge(gauge, max(
+                                    v for k, v in last_metrics.items()
+                                    if k.startswith(metric)))
                         entry = {"step": step + 1, **last_metrics,
                                  **meter.snapshot(),
                                  # host_wait_fraction: share of wall time this

@@ -5,7 +5,8 @@ on seeded weights, whole and as a share of the experts; the shares add up
 to the uncut layer; routing under skew drops nothing; the rotary
 frequencies, the interleaved rotation and the llama-4 query scale against
 hand values; `python train.py` with the tiny preset through `Trainer`
-against the reference's steps; and the image step left as it was."""
+against the reference's steps; the routed path at loads that take one pass
+over its buffers and more; and the image step left as it was."""
 
 import io
 import math
@@ -160,6 +161,120 @@ def test_routing_under_skew_drops_nothing(favoured, load):
     if not any(load):                  # to none: the shared expert alone
         shared = ref.experts(p, u, arch, (0, 0), Ops("float32"))[0]
         assert float(jnp.max(jnp.abs(out - shared))) < 1e-5
+
+
+# ---- the routed path in passes ----------------------------------------------
+
+#: tokens of the passes test: 2 of 8 experts held and top-2 make 1024
+#: assignments and buffers of 512 rows, so a load can pass them
+ROWS = 512
+#: (tokens with both choices held, tokens with one choice held) -> passes;
+#: the rest of the batch goes to two absent experts. `None`: a random router
+PASSES = {
+    "none_held": ((0, 0), 1),
+    "usual": (None, 1),
+    "exactly_the_buffers": ((256, 0), 1),
+    "one_more_row": ((256, 1), 2),
+    "every_assignment": ((512, 0), 2),
+}
+
+
+def _steered(p, u, kinds):
+    """Input and router under which the first `kinds[0]` tokens choose
+    experts (2, 3), the next `kinds[1]` experts (3, 5), the others (0, 6):
+    three indicator features that only the router's first rows read."""
+    both, one = kinds
+    kind = jnp.where(jnp.arange(ROWS) < both, 0,
+                     jnp.where(jnp.arange(ROWS) < both + one, 1, 2))
+    u = u.at[:, :3].set(jax.nn.one_hot(kind, 3))
+    router = jnp.zeros_like(p["router"])
+    for row, (first, second) in enumerate([(2, 3), (3, 5), (0, 6)]):
+        router = router.at[row, first].set(2.0).at[row, second].set(1.0)
+    return {**p, "router": router}, u
+
+
+@pytest.mark.parametrize("case", list(PASSES))
+def test_routed_path_takes_every_assignment_in_as_many_passes_as_it_needs(
+        case):
+    """The share (experts 2 and 3 of 8) against the plain reference in
+    value, input gradient and the three expert-weight gradients, at loads
+    below, at and above what its buffers hold; the passes it counted, and
+    nothing dropped."""
+    model, arch = _model()
+    p = _cut(_seeded(model, 5)[0]["layer_0"]["moe"], 2, 2)
+    u = jax.random.normal(jax.random.key(7), (ROWS, arch["hidden_size"]))
+    kinds, want_passes = PASSES[case]
+    if kinds is not None:
+        p, u = _steered(p, u, kinds)
+    layer = mistral4.ExpertShare(
+        **{k: arch[k] for k in mistral4._EXPERTS}, first_expert=2,
+        experts_held=2, compute_dtype=jnp.float32)
+    cotangent = jax.random.normal(jax.random.key(8), u.shape)
+
+    def program(p, u):
+        (out, counts), sown = layer.apply({"params": p}, u[None],
+                                          mutable=["counters"])
+        return jnp.sum(out[0] * cotangent), (out[0], counts,
+                                             sown["counters"])
+
+    def reference(p, u):
+        out, loads = ref.experts(p, u, arch, (2, 2), Ops("float32"))
+        return jnp.sum(out * cotangent), (out, loads)
+
+    (_, (out, counts, counters)), grads = jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True)(p, u)
+    (_, (want, loads)), want_grads = jax.value_and_grad(
+        reference, argnums=(0, 1), has_aux=True)(p, u)
+    held = int(np.asarray(loads).sum())
+    if kinds is not None:
+        assert held == 2 * kinds[0] + kinds[1]
+    assert counters["capacity"] == (512,)
+    assert int(counters["passes"][0]) == want_passes == max(
+        1, -(-held // 512))
+    assert list(np.asarray(counts)) == list(np.asarray(loads)) + [0]
+    assert float(jnp.max(jnp.abs(out - want))) < 1e-5
+    assert _rel(grads[1], want_grads[1]) < 1e-5
+    for leaf in ("experts_gate_proj", "experts_up_proj",
+                 "experts_down_proj"):
+        assert _rel(grads[0][leaf], want_grads[0][leaf]) < 1e-5, leaf
+
+
+def test_routed_capacity_follows_the_share():
+    """Rows of the routed buffers from shapes alone: twice the balanced
+    load in whole tiles, everything where every expert is held."""
+    assert mistral4.routed_capacity(4096 * 4, 8, 128) == 2048    # the cell
+    assert mistral4.routed_capacity(4096 * 4, 128, 128) == 4096 * 4
+    assert mistral4.routed_capacity(1024, 2, 8) == 512
+    assert mistral4.routed_capacity(64, 2, 8) == 64   # under one tile: all
+    assert mistral4.routed_capacity(4096 * 4, 9, 128) == 2560
+
+
+def test_a_step_through_the_trainer_publishes_the_routed_passes():
+    """One logged step of the tiny preset with telemetry on: the step's
+    metrics carry each layer's passes and the trainer's gauges their
+    largest, beside the rows the routed buffers hold (every expert is held
+    there: all 128 assignments, one pass)."""
+    from distributed_vgg_f_tpu import telemetry
+    cfg = apply_overrides(TINY, {"train.steps": 1, "train.log_every": 1})
+    mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
+                      jax.devices()[:1])
+    stream = io.StringIO()
+    telemetry.reset()
+    telemetry.configure(enabled=True)
+    try:
+        Trainer(cfg, mesh=mesh, logger=MetricLogger(stream=stream)).fit()
+        gauges = telemetry.get_registry().snapshot_split()["gauges"]
+    finally:
+        telemetry.reset()
+        telemetry.configure(enabled=True)
+    assignments = cfg.data.global_batch_size * SEQ \
+        * cfg.model.extra["num_experts_per_tok"]
+    assert gauges["moe/routed_passes"] == 1
+    assert gauges["moe/routed_capacity"] == assignments
+    assert gauges["moe/assignments_held"] \
+        == assignments * cfg.model.extra["num_hidden_layers"]
+    assert gauges["moe/dropped_assignments"] == 0
+    assert "moe_passes/layer_1=1" in stream.getvalue()
 
 
 # ---- rotary embedding against hand values ----------------------------------

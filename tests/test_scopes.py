@@ -182,6 +182,46 @@ def test_fused_lrn_kernels_keep_their_layers_names():
                      ("lrn2", False), ("lrn2", True)], calls
 
 
+def test_routed_experts_backward_keeps_the_three_names():
+    """The routed path's hand-written backward (models/mistral4.py, a
+    custom_vjp over two loops), lowered for a TPU: the benchmark's reader
+    gives its gathers, grouped products and scatter-adds, inside the loop
+    and outside it, to `moe_dispatch`, `moe_experts` and `moe_combine`,
+    backward."""
+    import json
+    from chipbench import scope_reduce
+    cfg = get_config("mistral_small4_tiny")
+    mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
+                      jax.devices()[:1])
+    trainer = Trainer(cfg, mesh=mesh, logger=MetricLogger(stream=io.StringIO()))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (cfg.data.global_batch_size, cfg.model.extra["seq_len"] + 1),
+        jnp.int32)}
+    text = trainer.train_step.__wrapped__.trace(
+        jax.eval_shape(trainer.init_state), batch, trainer.base_rng()).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    with open(os.path.join(os.path.dirname(scope_reduce.__file__),
+                           "lm_scopes.json")) as f:
+        names = json.load(f)
+    found = {}
+    for stack in set(re.findall(r'loc\("([^"]*)"', text)):
+        primitive = stack.rpartition("/")[2]
+        if "/layer_0/moe/" in stack and "transpose(" in stack.split(
+                "/layer_0/")[0] and primitive in (
+                    "gather", "ragged_dot_general", "scatter-add"):
+            scope, backward = scope_reduce.scope_of(
+                "jit(train_step)/" + stack + ":", names)
+            assert backward, stack
+            found.setdefault(primitive, set()).add(
+                (scope, "/while/body/" in stack))
+    both = lambda *scopes: {(s, loop) for s in scopes for loop in (0, 1)}
+    found["scatter-add"].discard(("moe_router", False))    # top-k's own
+    assert found == {
+        "gather": both("moe_dispatch", "moe_combine"),
+        "ragged_dot_general": both("moe_experts"),
+        "scatter-add": both("moe_dispatch", "moe_combine")}, found
+
+
 def test_jitted_steps_are_named_for_what_they_are(lowered):
     """The module's name is what a trace's `XLA Modules` line shows and,
     unlike the scopes, part of the persistent compile cache's key."""
