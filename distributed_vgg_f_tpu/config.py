@@ -1299,10 +1299,11 @@ MISTRAL_SMALL4_PUBLISHED = {
 }
 
 
-def _mistral4(name: str, extra: dict, vocab_rows: int,
-              steps: int) -> ExperimentConfig:
-    """The language-model presets' common frame: packed int32 tokens from
-    the seeded source, one sequence a step, bf16 compute on float32
+def _language_model(name: str, extra: dict, vocab_rows: int, steps: int,
+                    model: str = "mistral4") -> ExperimentConfig:
+    """The language-model presets' common frame (`model`: the registry's
+    name, models/mistral4.py or models/nemotron_h.py): packed int32 tokens
+    from the seeded source, one sequence a step, bf16 compute on float32
     weights, SGD-momentum 0.9 at a constant rate, no weight decay, no
     dropout, no augmentation (the catalog gives no recipe). `model.extra`
     carries the published widths, the share (`experts_held`,
@@ -1312,7 +1313,7 @@ def _mistral4(name: str, extra: dict, vocab_rows: int,
     for this model are out of scope (mesh flags stay off)."""
     return ExperimentConfig(
         name=name,
-        model=ModelConfig(name="mistral4", num_classes=vocab_rows,
+        model=ModelConfig(name=model, num_classes=vocab_rows,
                           dropout_rate=0.0, extra=extra),
         optim=OptimConfig(base_lr=0.01, reference_batch_size=1, momentum=0.9,
                           weight_decay=0.0, schedule="constant"),
@@ -1329,7 +1330,7 @@ def _mistral_small4_119b_ep16() -> ExperimentConfig:
     1.15 B parameters: 12 bytes each (weights, momentum, gradients) fill
     the chip before the first activation, so every block is recomputed in
     the backward pass and the loss goes over the sequence in chunks."""
-    return _mistral4(
+    return _language_model(
         "mistral_small4_119b_ep16",
         {**MISTRAL_SMALL4_PUBLISHED, "num_hidden_layers": 4,
          "first_expert": 0, "experts_held": 8, "seq_len": 4096},
@@ -1341,7 +1342,7 @@ def _mistral_small4_tiny() -> ExperimentConfig:
     hidden 64, 8 experts top-2, 2 layers, vocabulary 256, sequences of 32,
     float32 compute (off a TPU the attention core is explicit scores, not
     the Pallas kernel: models/mistral4.py)."""
-    cfg = _mistral4(
+    cfg = _language_model(
         "mistral_small4_tiny",
         {"hidden_size": 64, "num_attention_heads": 4, "q_lora_rank": 32,
          "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
@@ -1351,6 +1352,64 @@ def _mistral_small4_tiny() -> ExperimentConfig:
          "rope_parameters": MISTRAL_SMALL4_PUBLISHED["rope_parameters"],
          "num_hidden_layers": 2, "seq_len": 32},
         vocab_rows=256, steps=3)
+    return _replace(
+        cfg, model=_replace(cfg.model, compute_dtype="float32"),
+        data=_replace(cfg.data, global_batch_size=2),
+        optim=_replace(cfg.optim, reference_batch_size=2),
+        train=_replace(cfg.train, log_every=1))
+
+
+#: `NVIDIA-Nemotron-3-Nano-30B-A3B-BF16`'s published config
+#: (https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16/blob/
+#: main/config.json, `model_type: nemotron_h`): every width as published.
+#: The pattern is the model's 52 letters; a preset runs a prefix of it.
+NEMOTRON3_NANO_PUBLISHED = {
+    "hidden_size": 2688, "mamba_num_heads": 64, "mamba_head_dim": 64,
+    "n_groups": 8, "ssm_state_size": 128, "conv_kernel": 4,
+    "chunk_size": 128, "time_step_min": 0.001, "time_step_max": 0.1,
+    "time_step_floor": 0.0001, "num_attention_heads": 32,
+    "num_key_value_heads": 2, "head_dim": 128, "n_routed_experts": 128,
+    "num_experts_per_tok": 6, "moe_intermediate_size": 1856,
+    "moe_shared_expert_intermediate_size": 3712, "n_shared_experts": 1,
+    "routed_scaling_factor": 2.5, "layer_norm_epsilon": 1e-5,
+    "hybrid_override_pattern":
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+}
+
+
+def _nemotron3_nano_30b_ep8() -> ExperimentConfig:
+    """One chip's share of Nemotron-3-Nano-30B-A3B under 8-way expert
+    parallelism: the first 9 of 52 layers (`MEMEM*EME`: four Mamba-2
+    mixers, four expert layers, one attention layer), experts [0, 16) of
+    128 (the router stays 128 wide, top-6), 16384 of 131072 vocabulary
+    rows, two sequences of 8192 a step. 0.99 B parameters at 12 bytes each
+    (weights, momentum, gradients): recomputation per block and the loss
+    in chunks, as the Mistral preset and for its reason."""
+    cfg = _language_model(
+        "nemotron3_nano_30b_ep8",
+        {**NEMOTRON3_NANO_PUBLISHED, "hybrid_override_pattern":
+         NEMOTRON3_NANO_PUBLISHED["hybrid_override_pattern"][:9],
+         "first_expert": 0, "experts_held": 16, "seq_len": 8192},
+        vocab_rows=16384, steps=100, model="nemotron_h")
+    return _replace(cfg, data=_replace(cfg.data, global_batch_size=2),
+                    optim=_replace(cfg.optim, reference_batch_size=2))
+
+
+def _nemotron3_nano_tiny() -> ExperimentConfig:
+    """The same stack at a size a CPU test holds, every expert held, every
+    kind of layer (`MEM*E`): hidden 64, 4 Mamba heads of 8 in 2 groups with
+    a state of 16, chunks of 8 (sequences of 32: four chunks), 4 query
+    heads on 2 key heads, 8 experts top-2, vocabulary 256, float32."""
+    cfg = _language_model(
+        "nemotron3_nano_tiny",
+        {**NEMOTRON3_NANO_PUBLISHED, "hidden_size": 64, "mamba_num_heads": 4,
+         "mamba_head_dim": 8, "n_groups": 2, "ssm_state_size": 16,
+         "chunk_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2,
+         "head_dim": 16, "n_routed_experts": 8, "num_experts_per_tok": 2,
+         "moe_intermediate_size": 32,
+         "moe_shared_expert_intermediate_size": 48,
+         "hybrid_override_pattern": "MEM*E", "seq_len": 32},
+        vocab_rows=256, steps=3, model="nemotron_h")
     return _replace(
         cfg, model=_replace(cfg.model, compute_dtype="float32"),
         data=_replace(cfg.data, global_batch_size=2),
@@ -1368,6 +1427,8 @@ PRESETS = {
     "vggf_teacher": _vggf_teacher,
     "mistral_small4_119b_ep16": _mistral_small4_119b_ep16,
     "mistral_small4_tiny": _mistral_small4_tiny,
+    "nemotron3_nano_30b_ep8": _nemotron3_nano_30b_ep8,
+    "nemotron3_nano_tiny": _nemotron3_nano_tiny,
 }
 
 

@@ -97,10 +97,13 @@ INGEST_DESCRIPTORS: Dict[str, IngestDescriptor] = {
     # stands in for, but never a training preset
     "vggf_student": IngestDescriptor("vggf_student", space_to_depth=True,
                                      serving_only=True),
-    # the decoder-only language model (models/mistral4.py): packed int32
-    # tokens, no pixel wire; `zoo_model_names` (the image grids, the
-    # serving router) leaves it out by its kind
+    # the decoder-only language models (models/mistral4.py,
+    # models/nemotron_h.py): packed int32 tokens, no pixel wire;
+    # `zoo_model_names` (the image grids, the serving router) leaves them
+    # out by their kind
     "mistral4": IngestDescriptor("mistral4", kind="tokens", wire="tokens"),
+    "nemotron_h": IngestDescriptor("nemotron_h", kind="tokens",
+                                   wire="tokens"),
 }
 
 

@@ -31,6 +31,15 @@ assignment falls on this share.
 
 Training only: serving (a latent cache), checkpoint re-topology and ZeRO's
 flat vector for this model are out of scope.
+
+**Shared with `models/nemotron_h.py`**, which builds another stack on the
+same pieces: `RMSNorm`, `_dense`, `Head` and `chunked_next_token_loss`; and
+the whole expert share (`route`, `routed_capacity`, `routed_passes`,
+`_window`, `routed_experts` with its hand-written backward pass,
+`ExpertShare`), which takes the scoring (`softmax` | `sigmoid`, the latter
+with a selection bias and a scale) and the expert's function (`swiglu`:
+gate, up, down | `relu2`: up, down) as static arguments. The windows, the
+sort, the grouped products and the counters are one code path for both.
 """
 
 from __future__ import annotations
@@ -198,13 +207,24 @@ class LatentAttention(nn.Module):
                 ctx.reshape(b, t, h * dv))
 
 
-def route(probs, top_k: int, first_expert: int, experts_held: int):
-    """Top-k of `probs` (tokens, experts) and this share's view of it:
+def route(scores, top_k: int, first_expert: int, experts_held: int, *,
+          bias=None, scale: float = 1.0):
+    """Top-k of `scores` (tokens, experts) and this share's view of it:
     `(weights, local)` of shape (tokens, top_k), weights normalised over
     all chosen experts, `local` the held experts' index in
-    [0, experts_held) and `experts_held` for one that lives elsewhere."""
-    top_p, top_e = jax.lax.top_k(probs, top_k)
-    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    [0, experts_held) and `experts_held` for one that lives elsewhere.
+    With a selection `bias` (experts,) the choice is by `scores + bias`,
+    the weights are the chosen scores themselves over their sum, times
+    `scale`, and no gradient reaches the bias (the sigmoid scoring of
+    DeepSeek-V3's router, which models/nemotron_h.py takes)."""
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(scores, top_k)
+        weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    else:
+        _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+        weights = scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True)
+                                   + 1e-20)
     local = top_e - first_expert
     held = (local >= 0) & (local < experts_held)
     return weights, jnp.where(held, local, experts_held)
@@ -219,6 +239,19 @@ def route(probs, top_k: int, first_expert: int, experts_held: int):
 ROUTED_HEADROOM = 2.0
 #: the row tile of XLA:TPU's grouped-product kernel; a buffer is whole tiles
 ROUTED_ROW_TILE = 512
+#: and the tile of its other two widths: a product whose inner and outer
+#: widths are whole tiles runs three times as fast as one whose are not
+#: (12,288 live rows in 16 groups on a v5e, forward: 2688 x 1856 5.43 ms,
+#: 2688 x 2048 1.95, 3072 x 2048 1.50; 1856 x 2688 4.24, 2048 x 3072 1.58;
+#: the gradients alike: PERF.md, PR 32). So widths over one tile that are
+#: not whole tiles are padded with zeros on their way into the routed path
+#: (`ExpertShare`): rows of zeros in, columns of zeros out, the same sums.
+ROUTED_WIDTH_TILE = 512
+
+
+def _to_whole_tiles(width: int) -> int:
+    """Zeros to append to a width of the grouped products."""
+    return -width % ROUTED_WIDTH_TILE if width > ROUTED_WIDTH_TILE else 0
 
 
 def routed_capacity(assignments: int, experts_held: int,
@@ -259,18 +292,30 @@ def _window(x, weights, order, load, start, capacity):
     return picked, token, sizes, live, rows, weight
 
 
-def _hidden(rows, gate, up, sizes):
-    """silu(rows W_g) * rows W_u, each row through its own expert."""
-    return nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) \
-        * jax.lax.ragged_dot(rows, up, sizes)
+#: the experts' functions: what stands before the last matrix
+EXPERTS = ("swiglu", "relu2")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def routed_experts(x, gate, up, down, weights, order, load, capacity: int):
+def _hidden(rows, *ins, sizes, expert: str = "swiglu"):
+    """A row's hidden vector, each row through its own expert: `swiglu`
+    silu(rows W_g) * rows W_u of `ins` = (gate, up); `relu2`
+    relu(rows W_u)^2 of `ins` = (up,)."""
+    if expert == "swiglu":
+        gate, up = ins
+        return nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) \
+            * jax.lax.ragged_dot(rows, up, sizes)
+    up, = ins
+    return jnp.square(nn.relu(jax.lax.ragged_dot(rows, up, sizes)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def routed_experts(x, mats, weights, order, load, capacity: int,
+                   expert: str = "swiglu"):
     """sum_k w_k E_k(x) over the held experts, (tokens, hidden) float32.
 
-    `x` (tokens, hidden) and the held experts' `gate`, `up`, `down` in the
-    compute dtype, `weights` (tokens, top-k) float32, `order` the batch's
+    `x` (tokens, hidden) and the held experts' matrices `mats` in the
+    compute dtype (`swiglu`: gate, up, down; `relu2`: up, down),
+    `weights` (tokens, top-k) float32, `order` the batch's
     assignments (token x top-k + choice) sorted by held expert, those of
     absent experts last, padded to whole windows, `load` (experts_held,).
     `routed_passes` windows of `capacity` rows each, a loop whose length
@@ -279,12 +324,14 @@ def routed_experts(x, gate, up, down, weights, order, load, capacity: int):
     forward pass is kept but its inputs. Rows behind the last held
     assignment are zeros going in and coming out: what the grouped
     products leave unwritten there reaches nothing."""
+    *ins, down = mats
+
     def one(c, routed):
         _, token, sizes, live, rows, weight = _window(
             x, weights, order, load, c * capacity, capacity)
         with jax.named_scope("moe_experts"):
-            outs = jax.lax.ragged_dot(_hidden(rows, gate, up, sizes), down,
-                                      sizes)
+            outs = jax.lax.ragged_dot(
+                _hidden(rows, *ins, sizes=sizes, expert=expert), down, sizes)
         with jax.named_scope("moe_combine"):
             return routed.at[token].add(
                 jnp.where(live, outs, 0).astype(jnp.float32) * weight,
@@ -293,18 +340,18 @@ def routed_experts(x, gate, up, down, weights, order, load, capacity: int):
                              jnp.zeros(x.shape, jnp.float32))
 
 
-def _routed_forward(x, gate, up, down, weights, order, load, capacity):
-    return (routed_experts(x, gate, up, down, weights, order, load, capacity),
-            (x, gate, up, down, weights, order, load))
+def _routed_forward(x, mats, weights, order, load, capacity, expert):
+    return (routed_experts(x, mats, weights, order, load, capacity, expert),
+            (x, mats, weights, order, load))
 
 
-def _routed_backward(capacity, inputs, d_routed):
-    x, gate, up, down, weights, order, load = inputs
+def _routed_backward(capacity, expert, inputs, d_routed):
+    x, (*ins, down), weights, order, load = inputs
     dtype = x.dtype
 
     def window_gradients(c):
         """Window `c`: its hidden rows again, then the gradients of x,
-        gate, up, down and weights, each in its own dtype. With g a row's
+        the matrices and weights, each in its own dtype. With g a row's
         cotangent, w its weight and h its hidden row, the row's output
         h W_d is not needed again: q = g W_d^T gives dh = w q and
         dw = q . h, and dW_d takes (w h)^T g."""
@@ -314,15 +361,15 @@ def _routed_backward(capacity, inputs, d_routed):
             g = jnp.where(live, d_routed.at[token].get(
                 mode="promise_in_bounds"), 0).astype(dtype)
         with jax.named_scope("moe_experts"):
-            h, pull = jax.vjp(functools.partial(_hidden, sizes=sizes), rows,
-                              gate, up)
+            h, pull = jax.vjp(functools.partial(
+                _hidden, sizes=sizes, expert=expert), rows, *ins)
             by_down = lambda lhs, rhs: jax.lax.ragged_dot(lhs, rhs, sizes)
             q, = jax.linear_transpose(lambda h: by_down(h, down), h)(g)
             q = jnp.where(live, q, 0).astype(jnp.float32)
             weighted = (h.astype(jnp.float32) * weight).astype(dtype)
             d_down, = jax.linear_transpose(
                 lambda down: by_down(weighted, down), down)(g)
-            d_rows, d_gate, d_up = pull((q * weight).astype(dtype))
+            d_rows, *d_ins = pull((q * weight).astype(dtype))
         with jax.named_scope("moe_combine"):
             d_weight = jnp.sum(
                 jnp.where(live, q * h.astype(jnp.float32), 0), axis=-1)
@@ -331,12 +378,12 @@ def _routed_backward(capacity, inputs, d_routed):
         with jax.named_scope("moe_dispatch"):
             d_x = jnp.zeros_like(x).at[token].add(
                 jnp.where(live, d_rows, 0), mode="promise_in_bounds")
-        return d_x, d_gate, d_up, d_down, d_weights
+        return (d_x, *d_ins, d_down, d_weights)
 
     def one_more(c, so_far):
         # the cell never comes here; where a skewed batch does, the sums
         # are float32 and the running totals keep the gradients' dtypes
-        scopes = ("moe_dispatch", "moe_experts", "moe_experts", "moe_experts",
+        scopes = ("moe_dispatch", *["moe_experts"] * (len(ins) + 1),
                   "moe_combine")
         out = []
         for scope, a, b in zip(scopes, so_far, window_gradients(c)):
@@ -347,15 +394,22 @@ def _routed_backward(capacity, inputs, d_routed):
 
     # the first window outside the loop: its gradients are the totals, with
     # nothing to zero and nothing to add (0.4 GB of expert gradients)
-    return (*jax.lax.fori_loop(1, routed_passes(load, capacity), one_more,
-                               window_gradients(0)), None, None)
+    d_x, *d_mats, d_weights = jax.lax.fori_loop(
+        1, routed_passes(load, capacity), one_more, window_gradients(0))
+    return d_x, tuple(d_mats), d_weights, None, None
 
 
 routed_experts.defvjp(_routed_forward, _routed_backward)
 
 
 class ExpertShare(nn.Module):
-    """The routed experts this chip holds, and the shared expert."""
+    """The routed experts this chip holds, and the shared expert.
+    `scoring` "softmax" (over all experts) or "sigmoid" (each expert's own,
+    chosen with the selection bias `router_bias`, the weights times
+    `routed_scaling_factor`); `expert` one of `EXPERTS`, for the routed
+    experts and the shared one alike; the shared expert is
+    `shared_intermediate_size` wide (default: `moe_intermediate_size` x
+    `n_shared_experts`)."""
     n_routed_experts: int
     num_experts_per_tok: int
     moe_intermediate_size: int
@@ -363,6 +417,10 @@ class ExpertShare(nn.Module):
     first_expert: int
     experts_held: int
     compute_dtype: Any
+    scoring: str = "softmax"
+    expert: str = "swiglu"
+    routed_scaling_factor: float = 1.0
+    shared_intermediate_size: int | None = None
 
     @nn.compact
     def __call__(self, u):
@@ -379,8 +437,15 @@ class ExpertShare(nn.Module):
             # over these logits flips near-ties
             logits = jnp.dot(x.astype(jnp.float32), router,
                              precision=jax.lax.Precision.HIGHEST)
-            weights, local = route(jax.nn.softmax(logits, axis=-1), k,
-                                   self.first_expert, held)
+            if self.scoring == "softmax":
+                weights, local = route(jax.nn.softmax(logits, axis=-1), k,
+                                       self.first_expert, held)
+            else:
+                bias = self.param("router_bias", nn.initializers.normal(0.01),
+                                  (self.n_routed_experts,), jnp.float32)
+                weights, local = route(
+                    jax.nn.sigmoid(logits), k, self.first_expert, held,
+                    bias=bias, scale=self.routed_scaling_factor)
         with jax.named_scope("moe_dispatch"):
             # every assignment of the batch, sorted by held expert; those
             # of experts that live elsewhere sort behind the last group,
@@ -396,26 +461,45 @@ class ExpertShare(nn.Module):
             order = jnp.pad(order, (0, -(tokens * k) % capacity))
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
-        gate = self.param("experts_gate_proj", init, (held, d, width),
-                          jnp.float32)
-        up = self.param("experts_up_proj", init, (held, d, width),
-                        jnp.float32)
-        down = self.param("experts_down_proj", init, (held, width, d),
-                          jnp.float32)
+        if self.expert not in EXPERTS:
+            raise ValueError(f"expert {self.expert!r} is none of {EXPERTS}")
+        gated = self.expert == "swiglu"
+        mats = [self.param(f"experts_{name}_proj", init, (held, d, width),
+                           jnp.float32)
+                for name in (("gate", "up") if gated else ("up",))]
+        mats.append(self.param("experts_down_proj", init, (held, width, d),
+                               jnp.float32))
         with jax.named_scope("moe_experts"):
             # cast before the routed path: the experts' gradients leave it
             # in the compute dtype
-            gate, up, down = (w.astype(dtype) for w in (gate, up, down))
-        routed = routed_experts(x, gate, up, down, weights, order, load,
-                                capacity)
+            mats = tuple(w.astype(dtype) for w in mats)
+        rows_in, more_d, more_w = x, _to_whole_tiles(d), _to_whole_tiles(width)
+        if more_d or more_w:
+            with jax.named_scope("moe_experts"):
+                *ins, down = mats
+                mats = (*(jnp.pad(w, ((0, 0), (0, more_d), (0, more_w)))
+                          for w in ins),
+                        jnp.pad(down, ((0, 0), (0, more_w), (0, more_d))))
+            with jax.named_scope("moe_dispatch"):
+                rows_in = jnp.pad(x, ((0, 0), (0, more_d)))
+        routed = routed_experts(rows_in, mats, weights, order, load, capacity,
+                                self.expert)
+        if more_d:
+            routed = routed[:, :d]
         # the routed path's own receipts, for a caller that asks for them
         # (`mutable=["counters"]`): 1 pass = the compact buffers held all
         self.sow("counters", "passes", routed_passes(load, capacity))
         self.sow("counters", "capacity", capacity)
         with jax.named_scope("moe_shared"):
-            shared_width = width * self.n_shared_experts
-            mid = nn.silu(_dense(shared_width, dtype, "shared_gate_proj")(x)) \
-                * _dense(shared_width, dtype, "shared_up_proj")(x)
+            shared_width = self.shared_intermediate_size \
+                or width * self.n_shared_experts
+            if gated:
+                mid = nn.silu(
+                    _dense(shared_width, dtype, "shared_gate_proj")(x)) \
+                    * _dense(shared_width, dtype, "shared_up_proj")(x)
+            else:
+                mid = jnp.square(nn.relu(
+                    _dense(shared_width, dtype, "shared_up_proj")(x)))
             shared = _dense(d, dtype, "shared_down_proj")(mid)
         out = (routed + shared.astype(jnp.float32)).astype(u.dtype)
         return out.reshape(b, t, d), jnp.append(load, dropped)
@@ -452,6 +536,22 @@ def _chunk_loss(kernel, h_rows, ids):
     return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked)
 
 
+def chunked_next_token_loss(kernel, h, targets, chunk_rows: int, dtype):
+    """Mean cross-entropy of `targets` (B, T) under the head `kernel` at the
+    final hidden states `h` (B, T, hidden): float32 logits, `chunk_rows`
+    rows at a time, each chunk's logits made again in the backward pass."""
+    rows = h.reshape(-1, h.shape[-1])
+    wanted = targets.reshape(-1)
+    chunk = math.gcd(rows.shape[0], chunk_rows)
+    with jax.named_scope("lm_head"):
+        total = 0.0
+        for start in range(0, rows.shape[0], chunk):
+            total = total + _chunk_loss(
+                kernel, rows[start:start + chunk].astype(dtype),
+                wanted[start:start + chunk])
+    return total / rows.shape[0]
+
+
 class Head(nn.Module):
     """The untied output head, `hidden x vocabulary`."""
     hidden_size: int
@@ -479,6 +579,11 @@ class Mistral4LM(nn.Module):
     compute_dtype: Any = jnp.bfloat16
     rms_norm_eps: float = 1e-6
     loss_chunk_rows: int = 1024
+
+    @property
+    def expert_layers(self) -> tuple:
+        """The layers the rows of `hidden`'s counts stand for: all."""
+        return tuple(range(self.num_hidden_layers))
 
     def setup(self):
         self.embed = nn.Embed(self.vocab_size, self.hidden_size,
@@ -517,17 +622,9 @@ class Mistral4LM(nn.Module):
         `loss_chunk_rows` rows at a time, each chunk's logits made again in
         the backward pass."""
         h, counts = self.hidden(tokens)
-        rows = h.reshape(-1, h.shape[-1])
-        wanted = targets.reshape(-1)
-        chunk = math.gcd(rows.shape[0], self.loss_chunk_rows)
-        with jax.named_scope("lm_head"):
-            total = 0.0
-            for start in range(0, rows.shape[0], chunk):
-                total = total + _chunk_loss(
-                    self.lm_head.kernel,
-                    rows[start:start + chunk].astype(self.compute_dtype),
-                    wanted[start:start + chunk])
-        return total / rows.shape[0], counts
+        return chunked_next_token_loss(
+            self.lm_head.kernel, h, targets, self.loss_chunk_rows,
+            self.compute_dtype), counts
 
 
 #: keys of `ModelConfig.extra` (the preset's own) and of the published

@@ -95,3 +95,10 @@ def _build_mistral4(cfg: ModelConfig) -> nn.Module:
     # widths and the share (models/mistral4.py `build`)
     from distributed_vgg_f_tpu.models import mistral4
     return mistral4.build(cfg.num_classes, _dtype(cfg), cfg.extra)
+
+
+@register("nemotron_h")
+def _build_nemotron_h(cfg: ModelConfig) -> nn.Module:
+    # as `mistral4`: vocabulary rows held, published widths and the share
+    from distributed_vgg_f_tpu.models import nemotron_h
+    return nemotron_h.build(cfg.num_classes, _dtype(cfg), cfg.extra)

@@ -430,11 +430,32 @@ def _bthd_layout(x, b, h):
     return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
+def _kv_row(group: int):
+    """Row of the (B*H_kv, T, D) keys and values that row `b` of the
+    (B*H, T, D) queries reads: query head j reads key head j // group, and
+    B*H_kv rows follow the same order, so nothing is repeated in HBM. With
+    one query head a key head the row is its own."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
+def _sum_over_group(d3, group: int):
+    """(B*H, T, D) per-query-head dK or dV -> (B*H_kv, T, D): the kernels
+    write one partial a query head, summed here over each group in float32
+    (16 x 2 MB a key head at 8,192 tokens, against a kernel that would have
+    to visit a key block once a query head to keep its accumulator)."""
+    if group == 1:
+        return d3
+    bh, t, d = d3.shape
+    return jnp.sum(d3.reshape(bh // group, group, t, d).astype(jnp.float32),
+                   axis=1).astype(d3.dtype)
+
+
 @functools.lru_cache(maxsize=32)
 def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
-             kv_len: int | None, causal_skip: str = "mxu"):
+             kv_len: int | None, causal_skip: str = "mxu", group: int = 1):
     jagged = (causal_skip == "dma" and causal and kv_len is None
               and block_q == block_k)
+    kv_row = _kv_row(group)
 
     def _fwd_call(q3, k3, v3):
         bh, t, d = q3.shape
@@ -453,10 +474,10 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
                 grid=(bh, len(qi_np)),
                 in_specs=[pl.BlockSpec((1, block_q, d),
                                        lambda b, s, qi, ki: (b, qi[s], 0)),
-                          pl.BlockSpec((1, block_k, d),
-                                       lambda b, s, qi, ki: (b, ki[s], 0)),
-                          pl.BlockSpec((1, block_k, d),
-                                       lambda b, s, qi, ki: (b, ki[s], 0))],
+                          pl.BlockSpec((1, block_k, d), lambda b, s, qi, ki:
+                                       (kv_row(b), ki[s], 0)),
+                          pl.BlockSpec((1, block_k, d), lambda b, s, qi, ki:
+                                       (kv_row(b), ki[s], 0))],
                 out_specs=[pl.BlockSpec((1, block_q, d),
                                         lambda b, s, qi, ki: (b, qi[s], 0)),
                            pl.BlockSpec((1, block_q, 1),
@@ -476,7 +497,8 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
             return out, lse
         grid = (bh, nq, nk)
         q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-        kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+        kv_spec = pl.BlockSpec((1, block_k, d),
+                               lambda b, i, j: (kv_row(b), j, 0))
         out, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
                               block_k=block_k, causal=causal, kv_len=kv_len),
@@ -520,7 +542,7 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
             qs = pl.BlockSpec((1, block_q, d),
                               lambda b_, s, a, c: (b_, a[s], 0))
             ks = pl.BlockSpec((1, block_k, d),
-                              lambda b_, s, a, c: (b_, c[s], 0))
+                              lambda b_, s, a, c: (kv_row(b_), c[s], 0))
             rs = pl.BlockSpec((1, block_q, 1),
                               lambda b_, s, a, c: (b_, a[s], 0))
             # dQ: same tril order as the forward — (qi, ki), ki = 0..qi
@@ -546,7 +568,10 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
             qs_t = pl.BlockSpec((1, block_q, d),
                                 lambda b_, s, c, a: (b_, a[s], 0))
             ks_t = pl.BlockSpec((1, block_k, d),
-                                lambda b_, s, c, a: (b_, c[s], 0))
+                                lambda b_, s, c, a: (kv_row(b_), c[s], 0))
+            # dK/dV leave the kernel one partial a query head
+            dkv_t = pl.BlockSpec((1, block_k, d),
+                                 lambda b_, s, c, a: (b_, c[s], 0))
             rs_t = pl.BlockSpec((1, block_q, 1),
                                 lambda b_, s, c, a: (b_, a[s], 0))
             dk3, dv3 = pl.pallas_call(
@@ -556,20 +581,22 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
                     num_scalar_prefetch=2,
                     grid=(bh, len(ki_arr)),
                     in_specs=[qs_t, ks_t, ks_t, qs_t, rs_t, rs_t],
-                    out_specs=[ks_t, ks_t],
+                    out_specs=[dkv_t, dkv_t],
                     scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                                     pltpu.VMEM((block_k, d), jnp.float32)]),
-                out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
-                           jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
+                out_shape=[jax.ShapeDtypeStruct(q3.shape, k3.dtype),
+                           jax.ShapeDtypeStruct(q3.shape, v3.dtype)],
                 interpret=interpret,
             )(jnp.asarray(ki_arr.astype(np.int32)),
               jnp.asarray(qi_arr.astype(np.int32)), q3, k3, v3, do3, lse,
               delta)
-            return (_bthd_layout(dq3, b, h), _bthd_layout(dk3, b, h),
-                    _bthd_layout(dv3, b, h))
+            return (_bthd_layout(dq3, b, h),
+                    _bthd_layout(_sum_over_group(dk3, group), b, h // group),
+                    _bthd_layout(_sum_over_group(dv3, group), b, h // group))
 
         q_spec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
-        kv_spec = pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0))
+        kv_spec = pl.BlockSpec((1, block_k, d),
+                               lambda b_, i, j: (kv_row(b_), j, 0))
         row_spec = pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0))
         dq3 = pl.pallas_call(
             functools.partial(_dq_kernel, scale=scale, block_q=block_q,
@@ -584,7 +611,10 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
 
         # transposed grid: KV block outer, Q blocks accumulate innermost
         q_spec_t = pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0))
-        kv_spec_t = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
+        kv_spec_t = pl.BlockSpec((1, block_k, d),
+                                 lambda b_, j, i: (kv_row(b_), j, 0))
+        dkv_spec_t = pl.BlockSpec((1, block_k, d),
+                                  lambda b_, j, i: (b_, j, 0))
         row_spec_t = pl.BlockSpec((1, block_q, 1), lambda b_, j, i: (b_, i, 0))
         dk3, dv3 = pl.pallas_call(
             functools.partial(_dkv_kernel, scale=scale, block_q=block_q,
@@ -592,15 +622,16 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
             grid=(bh, nk, nq),
             in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
                       row_spec_t],
-            out_specs=[kv_spec_t, kv_spec_t],
-            out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
-                       jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
+            out_specs=[dkv_spec_t, dkv_spec_t],
+            out_shape=[jax.ShapeDtypeStruct(q3.shape, k3.dtype),
+                       jax.ShapeDtypeStruct(q3.shape, v3.dtype)],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
             interpret=interpret,
         )(q3, k3, v3, do3, lse, delta)
-        return (_bthd_layout(dq3, b, h), _bthd_layout(dk3, b, h),
-                _bthd_layout(dv3, b, h))
+        return (_bthd_layout(dq3, b, h),
+                _bthd_layout(_sum_over_group(dk3, group), b, h // group),
+                _bthd_layout(_sum_over_group(dv3, group), b, h // group))
 
     op.defvjp(op_fwd, op_bwd)
     return op
@@ -870,6 +901,12 @@ def flash_self_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          interpret: bool | None = None) -> jnp.ndarray:
     """Exact self-attention, O(T·D) HBM footprint. (B, T, H, D) in and out.
 
+    Grouped queries: `k` and `v` may hold fewer heads than `q`, (B, T, H_kv,
+    D) with H a multiple of H_kv; query head j then reads key head
+    j // (H / H_kv) through the kernels' block index maps, and no key or
+    value is repeated in HBM. dK and dV leave the backward kernel one
+    partial a query head and are summed over each group after it.
+
     Block sizes default to the largest ≤128 divisor of T (None = auto); when
     that divisor would fall below 64 on a multi-block sequence (prime-ish T,
     e.g. 197), the inputs are padded internally to the next 128-multiple
@@ -905,9 +942,10 @@ def flash_self_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if causal_skip == "dma" and not causal:
         raise ValueError("causal_skip='dma' only applies to causal "
                          "attention — drop it or set causal=True")
-    if q.shape != k.shape or q.shape != v.shape:
+    if k.shape != v.shape or q.shape[:2] != k.shape[:2] \
+            or q.shape[3] != k.shape[3] or q.shape[2] % k.shape[2]:
         raise ValueError(f"q/k/v shapes differ: {q.shape} {k.shape} {v.shape}")
-    t = q.shape[1]
+    t, group = q.shape[1], q.shape[2] // k.shape[2]
     if causal_skip == "auto":
         causal_skip = resolve_causal_skip_auto(causal, t)
     t_pad = t
@@ -938,5 +976,5 @@ def flash_self_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         #                       so it shares the mxu op-cache entry instead
         #                       of duplicating an identical compiled op
     out = _make_op(causal, block_q, block_k, interpret, kv_len,
-                   causal_skip)(q, k, v)
+                   causal_skip, group)(q, k, v)
     return out[:, :t] if t_pad != t else out

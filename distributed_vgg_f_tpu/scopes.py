@@ -26,11 +26,21 @@ PHASES = ("finish_u8", "augment", "flip", "crop_jitter", "rand_ops", "mix",
 LAYERS = ("lrn1", "lrn2", "pool1", "pool2", "pool3", "pool4", "pool5",
           "pool_init", "gap", "embed_tokens")
 
-#: The language model's layers (models/mistral4.py; `embed_tokens` above is
-#: shared): latent attention, the expert share, the head. A list of their
-#: own: the benchmark's first names file (`chipbench/scopes.json`) is held
-#: equal to `LAYERS`, and a traced run of the language model's cell is
-#: reduced by a second file, `chipbench/lm_scopes.json`.
+#: The layers of models/mistral4.py (`embed_tokens` above is shared):
+#: latent attention, the expert share, the head. A list of their own: the
+#: benchmark's first names file (`chipbench/scopes.json`) is held equal to
+#: `LAYERS`, and a traced run of that model's cell is reduced by a second
+#: file, `chipbench/lm_scopes.json`.
 LM_LAYERS = ("mla_q", "mla_kv", "mla_core", "mla_out", "moe_router",
              "moe_dispatch", "moe_experts", "moe_combine", "moe_shared",
              "lm_head")
+
+#: The layers of models/nemotron_h.py that `LM_LAYERS` lacks (the `moe_*`
+#: names, `embed_tokens` and `lm_head` are the expert share's and the
+#: head's own, shared with the list above): the Mamba-2 mixer (`ssm_in`
+#: its input projection, `ssm_conv`, `ssm_scan` the recurrence of
+#: ops/ssd.py, `ssm_gate_out` the gated norm and the output projection)
+#: and grouped-query attention. Its cell's traces are reduced by a third
+#: file, `chipbench/hybrid_lm_scopes.json`.
+HYBRID_LM_LAYERS = ("ssm_in", "ssm_conv", "ssm_scan", "ssm_gate_out",
+                    "gqa_qkv", "gqa_core", "gqa_out")

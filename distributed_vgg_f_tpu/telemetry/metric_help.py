@@ -35,6 +35,8 @@ NAMESPACE_HELP = {
     "moe": "language-model expert routing (assignments held, expert "
            "load extremes, dropped assignments, passes over the routed "
            "buffers and the rows they hold)",
+    "ssm": "language-model state-space layers (chunks scanned a step, "
+           "smallest decay of any layer)",
     "eval": "trainer evaluation passes",
     "distributed": "cross-process coordination barriers",
     "telemetry": "the telemetry registry itself (poller faults)",

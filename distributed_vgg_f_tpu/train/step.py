@@ -52,26 +52,39 @@ def _apply_model(model, params, batch_stats, images, *, train: bool,
     return logits, batch_stats
 
 
-def _expert_load_metrics(counts, counters) -> dict:
+def _expert_load_metrics(counts, counters, expert_layers) -> dict:
     """A language model's routing counters as step metrics. `counts` is
-    (layers, experts held + 1): the assignments each held expert took in
-    this replica's batch, then the dropped ones; `counters` is what the
-    layers sowed (models/mistral4.py), a dict for each `layer_N`. Per
-    layer: assignments held, the largest and smallest held expert's load,
-    dropped assignments, the passes the routed path made over its
-    buffers and the rows those hold; and the whole table as `moe_load`."""
+    (expert layers, experts held + 1): the assignments each held expert
+    took in this replica's batch, then the dropped ones, a row for each
+    layer of `expert_layers` (the model says which of its layers have
+    experts: all of models/mistral4.py's, four of nine in
+    models/nemotron_h.py's cell); `counters` is what the layers sowed, a
+    dict for each `layer_N`. Per expert layer, under the layer's own
+    index: assignments held, the largest and smallest held expert's load,
+    dropped assignments, the passes the routed path made over its buffers
+    and the rows those hold; and the whole table as `moe_load`. What a
+    layer without experts sowed keeps the name it was sown under
+    (`ssm_chunks/layer_0`)."""
     counts = counts.astype(jnp.float32)
     load, dropped = counts[:, :-1], counts[:, -1]
     metrics = {"moe_load": load}
-    for i in range(counts.shape[0]):
-        metrics[f"moe_held/layer_{i}"] = jnp.sum(load[i])
-        metrics[f"moe_load_max/layer_{i}"] = jnp.max(load[i])
-        metrics[f"moe_load_min/layer_{i}"] = jnp.min(load[i])
-        metrics[f"moe_dropped/layer_{i}"] = dropped[i]
-        for module in counters[f"layer_{i}"].values():
+
+    def sown(layer: str, prefix: str) -> None:
+        for module in counters.get(layer, {}).values():
             for name, (value,) in module.items():
-                metrics[f"moe_{name}/layer_{i}"] = jnp.asarray(
+                metrics[f"{prefix}{name}/{layer}"] = jnp.asarray(
                     value, jnp.float32)
+
+    for row, i in enumerate(expert_layers):
+        metrics[f"moe_held/layer_{i}"] = jnp.sum(load[row])
+        metrics[f"moe_load_max/layer_{i}"] = jnp.max(load[row])
+        metrics[f"moe_load_min/layer_{i}"] = jnp.min(load[row])
+        metrics[f"moe_dropped/layer_{i}"] = dropped[row]
+        sown(f"layer_{i}", "moe_")
+    with_experts = {f"layer_{i}" for i in expert_layers}
+    for layer in counters:
+        if layer not in with_experts:
+            sown(layer, "")
     return metrics
 
 
@@ -201,8 +214,9 @@ def build_train_step(model, mesh: Mesh, weight_decay: float,
                 with jax.named_scope("loss"):
                     l2 = l2_regularization(params, weight_decay)
                     metrics = {"loss": ce, "l2_loss": l2,
-                               **_expert_load_metrics(counts,
-                                                      sown["counters"])}
+                               **_expert_load_metrics(
+                                   counts, sown["counters"],
+                                   model.expert_layers)}
                 return ce + l2, (batch_stats, metrics)
 
             if batch_kind == "tokens":

@@ -1084,8 +1084,9 @@ class Trainer:
                         last_metrics = {k: float(v) for k, v in
                                         fetched.items() if np.ndim(v) == 0}
                         if "moe_load" in fetched and tele.enabled:
-                            # routing receipts (models/mistral4.py), over
-                            # the layers of the step just logged
+                            # routing receipts (models/mistral4.py's
+                            # expert share), over the expert layers of the
+                            # step just logged
                             load = np.asarray(fetched["moe_load"])
                             reg.set_gauge("moe/assignments_held",
                                           float(load.sum()))
@@ -1104,6 +1105,16 @@ class Trainer:
                                 reg.set_gauge(gauge, max(
                                     v for k, v in last_metrics.items()
                                     if k.startswith(metric)))
+                            # the state-space layers' receipts
+                            # (models/nemotron_h.py): chunks scanned in the
+                            # step, and the smallest decay of any layer
+                            decays = [v for k, v in last_metrics.items()
+                                      if k.startswith("ssm_decay_min/")]
+                            if decays:
+                                reg.set_gauge("ssm/decay_min", min(decays))
+                                reg.set_gauge("ssm/chunks", sum(
+                                    v for k, v in last_metrics.items()
+                                    if k.startswith("ssm_chunks/")))
                         entry = {"step": step + 1, **last_metrics,
                                  **meter.snapshot(),
                                  # host_wait_fraction: share of wall time this

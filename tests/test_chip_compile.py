@@ -167,6 +167,56 @@ def test_flash_attention_compiles_for_v5e(chip, case, backward):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_grouped_query_core_reads_two_key_heads_not_thirty_two(chip):
+    """The attention core of `nemotron3_nano_30b_ep8` (2 sequences of 8,192
+    tokens, 32 query heads on 2 key/value heads of 128, blocks of 1,024,
+    bf16) with its three gradients: three kernels, no array of the keys or
+    values at the query heads' count anywhere but the per-query-head dK and
+    dV the backward kernel writes (two, summed over each group after it)."""
+    q = (2, 8192, 32, 128)
+    kv = (2, 8192, 2, 128)
+
+    def fn(q, k, v):
+        return fa.flash_self_attention(q, k, v, causal=True, block_q=1024,
+                                       block_k=1024)
+
+    compiled = _compile(_sum_grad(fn, 3), chip, q, kv, kv)
+    text = compiled.as_text()
+    assert len(re.findall(r"= .*custom-call.*tpu_custom_call", text)) == 3
+    # a K or V repeated in HBM would be a broadcast or gather of the 2-head
+    # array to 64 rows of (8192, 128) outside the kernels (the sum's own
+    # cotangent is a broadcast constant)
+    repeated = [line[:160] for line in text.splitlines()
+                if re.search(r"= bf16\[64,8192,128\]\S* (broadcast|gather|"
+                             r"concatenate)\(%(?!constant)", line)]
+    assert not repeated, repeated
+
+
+def test_chunked_scan_compiles_at_the_cell_s_widths(chip):
+    """`ops/ssd.py` at `nemotron3_nano_30b_ep8`'s widths (2 x 8,192
+    positions, 64 heads of 64 in 8 groups, state 128, chunks of 128, bf16)
+    with its gradients: plain XLA products, no kernel of the repo's own,
+    and the temporaries at its fullest under 3 GiB (the masked decay
+    product is 134 M elements a layer: 0.25 GiB in bf16, 0.5 in float32)."""
+    from distributed_vgg_f_tpu.ops import ssd
+    b, t, h, p, g, n = 2, 8192, 64, 64, 8, 128
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=chip)
+
+    def loss(x, dt, a, b_in, c_out, d):
+        return jnp.sum(ssd.ssd(x, dt, a, b_in, c_out, d, chunk=128))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        arg((b, t, h, p), jnp.bfloat16), arg((b, t, h), jnp.float32),
+        arg((h,), jnp.float32), arg((b, t, g, n), jnp.bfloat16),
+        arg((b, t, g, n), jnp.bfloat16), arg((h,), jnp.float32)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    memory = compiled.memory_analysis()
+    temporaries = (memory.peak_memory_in_bytes - memory.argument_size_in_bytes
+                   - memory.output_size_in_bytes) / 2 ** 30
+    assert temporaries < 3.0, f"{temporaries:.2f} GiB of temporaries"
+
+
 def test_expert_share_moves_only_the_rows_its_buffers_hold(chip):
     """One expert layer of `mistral_small4_119b_ep16` (4096 tokens, hidden
     4096, width 2048, 8 of 128 experts held, top-4, bf16) with its gradient:

@@ -357,3 +357,53 @@ def test_prime_length_pads_not_block1(causal):
     for g, r, name in zip(grads, ref_grads, "qkv"):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                    rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
+
+
+# ---- grouped queries: fewer key/value heads than query heads ---------------
+
+@pytest.mark.parametrize("skip", ["mxu", "dma"])
+@pytest.mark.parametrize("group", [1, 4, 16])
+def test_grouped_queries_match_naive_on_repeated_keys(group, skip):
+    """16 query heads on 16 / group key heads (16, 4, 1), causal, on the
+    rectangular grids and the jagged ones: the output and the three
+    gradients against the naive oracle on keys and values repeated in
+    memory, whose dK and dV summed over each group are what the kernel
+    owes. Query head j reads key head j // group."""
+    heads, t, d = 16, 128, 32
+    kq, kk, kv, kc = jax.random.split(jax.random.key(11), 4)
+    q = jax.random.normal(kq, (2, t, heads, d))
+    k = jax.random.normal(kk, (2, t, heads // group, d))
+    v = jax.random.normal(kv, (2, t, heads // group, d))
+    cot = jax.random.normal(kc, q.shape)
+
+    def flash_loss(q, k, v):
+        out = flash_self_attention(q, k, v, causal=True, block_q=64,
+                                   block_k=64, causal_skip=skip,
+                                   interpret=True)
+        return jnp.vdot(out, cot), out
+
+    def naive_loss(q, k, v):
+        out = naive_attention(q, jnp.repeat(k, group, axis=2),
+                              jnp.repeat(v, group, axis=2), causal=True)
+        return jnp.vdot(out, cot), out
+
+    (_, out), grads = jax.value_and_grad(
+        flash_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, ref), ref_grads = jax.value_and_grad(
+        naive_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for g, r, name in zip(grads, ref_grads, "qkv"):
+        assert g.shape == r.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
+
+
+def test_grouped_queries_refuse_heads_that_do_not_divide():
+    q = jnp.zeros((1, 64, 6, 32))
+    kv = jnp.zeros((1, 64, 4, 32))
+    with pytest.raises(ValueError, match="shapes differ"):
+        flash_self_attention(q, kv, kv, causal=True, interpret=True)
+    with pytest.raises(ValueError, match="shapes differ"):
+        flash_self_attention(q, kv, jnp.zeros((1, 64, 2, 32)), causal=True,
+                             interpret=True)

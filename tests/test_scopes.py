@@ -39,9 +39,9 @@ def _lowered_steps(preset: str, chips: int, **overrides):
     return (train.as_text(debug_info=True), evaluate.as_text(debug_info=True))
 
 
-def _lowered_token_step() -> str:
-    """The language model's train step (the tiny preset) as lowered text."""
-    cfg = get_config("mistral_small4_tiny")
+def _lowered_token_step(preset: str = "mistral_small4_tiny") -> str:
+    """A language model's train step (a tiny preset) as lowered text."""
+    cfg = get_config(preset)
     mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
                       jax.devices()[:1])
     trainer = Trainer(cfg, mesh=mesh, logger=MetricLogger(stream=io.StringIO()))
@@ -75,7 +75,8 @@ def lowered():
     return {"vggf": vggf, "vggf_eval": vggf_eval, "resnet50": resnet,
             "vgg16": _lowered_forward("vgg16"),
             "vit_s16": _lowered_forward("vit_s16", depth=1),
-            "mistral4": _lowered_token_step()}
+            "mistral4": _lowered_token_step(),
+            "nemotron_h": _lowered_token_step("nemotron3_nano_tiny")}
 
 
 def _stacks(text: str) -> set:
@@ -96,21 +97,63 @@ HOME = {**{name: "vggf" for name in scopes.PHASES},
         "pool5": "vggf", "pool3": "vgg16", "pool4": "vgg16",
         "pool_init": "resnet50", "gap": "resnet50",
         "embed_tokens": "vit_s16",
-        **{name: "mistral4" for name in scopes.LM_LAYERS}}
+        **{name: "mistral4" for name in scopes.LM_LAYERS},
+        **{name: "nemotron_h" for name in scopes.HYBRID_LM_LAYERS}}
 
 
 def test_every_declared_name_has_a_home():
     assert set(HOME) == set(scopes.PHASES) | set(scopes.LAYERS) \
-        | set(scopes.LM_LAYERS)
+        | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS)
     assert not set(scopes.PHASES) & set(scopes.LAYERS)
     assert not (set(scopes.PHASES) | set(scopes.LAYERS)) \
         & set(scopes.LM_LAYERS)
+    assert not (set(scopes.PHASES) | set(scopes.LAYERS)
+                | set(scopes.LM_LAYERS)) & set(scopes.HYBRID_LM_LAYERS)
 
 
 @pytest.mark.parametrize("name", scopes.PHASES + scopes.LAYERS
-                         + scopes.LM_LAYERS)
+                         + scopes.LM_LAYERS + scopes.HYBRID_LM_LAYERS)
 def test_declared_name_reaches_the_lowered_program(lowered, name):
     assert _holds(_stacks(lowered[HOME[name]]), name)
+
+
+def test_the_hybrid_model_s_names_file_equals_the_declared_lists(lowered):
+    """`chipbench/hybrid_lm_scopes.json`, the benchmark's own copy: its
+    layers are the hybrid model's own names and the ones it shares (the
+    expert share's, the embedding, the head), each of which reaches that
+    model's lowered step, forward and backward; its phases are the first
+    names file's."""
+    import json
+    from chipbench import scope_reduce
+    here = os.path.dirname(scope_reduce.__file__)
+    with open(os.path.join(here, "hybrid_lm_scopes.json")) as f:
+        names = json.load(f)
+    shared = {n for n in scopes.LM_LAYERS if not n.startswith("mla_")}
+    assert set(names["layers"]) == set(scopes.HYBRID_LM_LAYERS) | shared \
+        | {"embed_tokens"}
+    assert names["phases"] == scope_reduce.declared()["phases"]
+    assert set(names["ssm"]) | set(names["gqa"]) \
+        == set(scopes.HYBRID_LM_LAYERS)
+    assert set(names["moe"]) == {n for n in shared if n.startswith("moe_")}
+    for group in ("ssm_scan", "gqa_core", "moe_experts"):
+        assert names[group] == [group]
+    stacks = _stacks(lowered["nemotron_h"])
+    for name in names["layers"]:
+        assert _holds(stacks, name), name
+    for name in ("ssm_scan", "ssm_conv", "gqa_core", "moe_experts"):
+        assert any(s.endswith(name) and "transpose(" not in s
+                   for s in stacks), name
+        assert any(s.endswith(name) and "transpose(" in s
+                   for s in stacks), name
+    # a stack of that model reduces to its layer by the file's own rules
+    loss = "NemotronHLM.next_token_loss"
+    assert scope_reduce.scope_of(
+        f"jit(train_step)/transpose(jvp({loss}))/NemotronHLM.hidden/"
+        f"jvp({loss})/NemotronHLM.hidden/checkpoint/rematted_computation/"
+        "layer_0/mixer/ssm_scan/dot_general:", names) == ("ssm_scan", True)
+    assert scope_reduce.scope_of(
+        f"jit(train_step)/jvp({loss})/NemotronHLM.hidden/checkpoint/layer_0/"
+        "norm/mul:", names) == ("checkpoint/layer_0/norm", False)
 
 
 @pytest.mark.parametrize("model", ["resnet50", "vgg16", "vit_s16"])
@@ -246,4 +289,4 @@ def test_call_sites_and_declared_lists_agree():
     assert "pool{b}" in found          # models/vgg16.py, one for each block
     found = (found - {"pool{b}"}) | {f"pool{b}" for b in range(1, 6)}
     assert found == set(scopes.PHASES) | set(scopes.LAYERS) \
-        | set(scopes.LM_LAYERS)
+        | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS)
