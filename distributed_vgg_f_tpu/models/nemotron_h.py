@@ -15,7 +15,8 @@ Equations (eps 1e-5; no bias but the convolution's):
                              group serving heads / g consecutive heads
         dt = softplus(dt + dt_bias);  A = -exp(A_log)     one scalar a head
         h_t = exp(dt_t A) h_{t-1} + dt_t x_t (x) B_t      h: head x p x n
-        y_t = h_t C_t + D x_t                             (ops/ssd.py)
+        y_t = h_t C_t + D x_t          (ops/ssd.py: Pallas kernels where
+                                       the sizes are whole tiles of a TPU)
         y = RMSNorm_grouped(y * silu(z)) * w    groups of d_inner / g channels
         out = y W_out
     E   s = sigmoid(u W_r), float32, over ALL experts
@@ -155,9 +156,12 @@ class Mamba2Mixer(nn.Module):
                         chunk=self.chunk_size)
             # receipts for a caller that asks (`mutable=["counters"]`): a
             # state that has died or never decays is the first thing to
-            # go wrong in a run
+            # go wrong in a run; and whether the recurrence took the Pallas
+            # kernels (1) or the XLA form (0)
             self.sow("counters", "ssm_chunks",
                      b * -(-t // self.chunk_size))
+            self.sow("counters", "ssm_kernel", int(ssd.takes_kernels(
+                (b, t, h, p), (b, t, g, n), self.chunk_size)))
             self.sow("counters", "ssm_decay_min", ssd.smallest_decay(dt, a))
         with jax.named_scope("ssm_gate_out"):
             gated = y.reshape(b, t, inner) * nn.silu(z.astype(jnp.float32))

@@ -19,11 +19,18 @@ B and C come in groups, a group serving `heads / groups` consecutive heads.
 The decays, their running sums and the carried state are float32; the four
 products take operands in x's dtype and accumulate in float32.
 
-Plain XLA products over the chunks, differentiated by JAX: the masked
-`chunk x chunk` decay product of every head (32 KB a token in float32 over
-64 heads) is made once a pass, as one fusion's output in x's dtype, and the
-backward pass makes it again under the block's recomputation. Why this form
-and not a kernel: PERF.md, section 6, PR 32.
+Two implementations behind `ssd`, chosen by what the call can observe:
+
+- the Pallas kernels of ops/ssd_pallas.py, forward and hand-written
+  backward, where the chunk, a group's heads and the state are whole tiles
+  of the chip (`ssd_pallas.applies`) and the backend is a TPU (or the Pallas
+  interpreter a test switched on): a chunk's masked decay product is made
+  in VMEM and never reaches HBM (PERF.md, section 6, PR 33);
+- `ssd_xla` everywhere else (the CPU, the tiny preset's chunks of 8 and
+  heads of 8): plain XLA products over the chunks, differentiated by JAX;
+  the masked `chunk x chunk` decay product of every head is one fusion's
+  output in x's dtype. It is also the function the kernels are tested
+  against.
 """
 
 from __future__ import annotations
@@ -32,17 +39,34 @@ import jax
 import jax.numpy as jnp
 
 
+def takes_kernels(x_shape, group_shape, chunk: int) -> bool:
+    """Whether `ssd` runs the Pallas kernels for arguments of these shapes
+    (`x`'s and `B`'s) here: shapes and backend decide, nothing else."""
+    from distributed_vgg_f_tpu.ops import ssd_pallas
+    return (jax.default_backend() == "tpu" or ssd_pallas.INTERPRET) \
+        and ssd_pallas.applies(x_shape, group_shape, chunk)
+
+
 def ssd(x, dt, A, B, C, D, chunk: int = 128):
     """`x` (b, t, h, p) in the compute dtype; `dt` (b, t, h) float32, after
     its softplus; `A` (h,) float32, negative; `B`, `C` (b, t, g, n) with
     h a multiple of g; `D` (h,) float32. Returns `y` (b, t, h, p) float32.
     `t` is a whole number of chunks (or shorter than one)."""
+    t, h, g = x.shape[1], x.shape[2], B.shape[2]
+    if t % min(chunk, t) or h % g:
+        raise ValueError(f"{t} positions in chunks of {chunk}, {h} heads in "
+                         f"{g} groups: neither may leave a rest")
+    if takes_kernels(x.shape, B.shape, chunk):
+        from distributed_vgg_f_tpu.ops import ssd_pallas
+        return ssd_pallas.scan(x, dt, A, B, C, D, chunk=chunk)
+    return ssd_xla(x, dt, A, B, C, D, chunk)
+
+
+def ssd_xla(x, dt, A, B, C, D, chunk: int = 128):
+    """`ssd` as plain XLA products, differentiated by JAX."""
     b, t, h, p = x.shape
     g, n = B.shape[2:]
     q = min(chunk, t)
-    if t % q or h % g:
-        raise ValueError(f"{t} positions in chunks of {chunk}, {h} heads in "
-                         f"{g} groups: neither may leave a rest")
     c, r, dtype = t // q, h // g, x.dtype
     f32 = jnp.float32
 

@@ -36,7 +36,7 @@ NAMESPACE_HELP = {
            "load extremes, dropped assignments, passes over the routed "
            "buffers and the rows they hold)",
     "ssm": "language-model state-space layers (chunks scanned a step, "
-           "smallest decay of any layer)",
+           "smallest decay of any layer, layers on the Pallas kernels)",
     "eval": "trainer evaluation passes",
     "distributed": "cross-process coordination barriers",
     "telemetry": "the telemetry registry itself (poller faults)",

@@ -1107,14 +1107,20 @@ class Trainer:
                                     if k.startswith(metric)))
                             # the state-space layers' receipts
                             # (models/nemotron_h.py): chunks scanned in the
-                            # step, and the smallest decay of any layer
+                            # step, the smallest decay of any layer, and
+                            # the layers whose recurrence ran as the
+                            # Pallas kernels (ops/ssd_pallas.py)
                             decays = [v for k, v in last_metrics.items()
                                       if k.startswith("ssm_decay_min/")]
                             if decays:
                                 reg.set_gauge("ssm/decay_min", min(decays))
-                                reg.set_gauge("ssm/chunks", sum(
+                                over_layers = lambda metric: sum(
                                     v for k, v in last_metrics.items()
-                                    if k.startswith("ssm_chunks/")))
+                                    if k.startswith(metric))
+                                reg.set_gauge("ssm/chunks",
+                                              over_layers("ssm_chunks/"))
+                                reg.set_gauge("ssm/kernel_layers",
+                                              over_layers("ssm_kernel/"))
                         entry = {"step": step + 1, **last_metrics,
                                  **meter.snapshot(),
                                  # host_wait_fraction: share of wall time this

@@ -192,29 +192,54 @@ def test_grouped_query_core_reads_two_key_heads_not_thirty_two(chip):
     assert not repeated, repeated
 
 
-def test_chunked_scan_compiles_at_the_cell_s_widths(chip):
-    """`ops/ssd.py` at `nemotron3_nano_30b_ep8`'s widths (2 x 8,192
-    positions, 64 heads of 64 in 8 groups, state 128, chunks of 128, bf16)
-    with its gradients: plain XLA products, no kernel of the repo's own,
-    and the temporaries at its fullest under 3 GiB (the masked decay
-    product is 134 M elements a layer: 0.25 GiB in bf16, 0.5 in float32)."""
+def _scan_with_gradients(chip, monkeypatch, *, b, t, h, p, g, n, chunk,
+                         dtype):
+    """`ssd.ssd` with all six gradients, compiled for the described chip as
+    a TPU's trace would lower it (`ssd` asks the backend, which is the
+    CPU's here)."""
     from distributed_vgg_f_tpu.ops import ssd
-    b, t, h, p, g, n = 2, 8192, 64, 64, 8, 128
-    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
-                                                    sharding=chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    arg = lambda shape, kind: jax.ShapeDtypeStruct(shape, kind, sharding=chip)
 
     def loss(x, dt, a, b_in, c_out, d):
-        return jnp.sum(ssd.ssd(x, dt, a, b_in, c_out, d, chunk=128))
+        return jnp.sum(ssd.ssd(x, dt, a, b_in, c_out, d, chunk=chunk))
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
-        arg((b, t, h, p), jnp.bfloat16), arg((b, t, h), jnp.float32),
-        arg((h,), jnp.float32), arg((b, t, g, n), jnp.bfloat16),
-        arg((b, t, g, n), jnp.bfloat16), arg((h,), jnp.float32)).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        arg((b, t, h, p), dtype), arg((b, t, h), jnp.float32),
+        arg((h,), jnp.float32), arg((b, t, g, n), dtype),
+        arg((b, t, g, n), dtype), arg((h,), jnp.float32)).compile()
+
+
+def test_chunked_scan_compiles_at_the_cell_s_widths(chip, monkeypatch):
+    """`ops/ssd.py` at `nemotron3_nano_30b_ep8`'s widths (2 x 8,192
+    positions, 64 heads of 64 in 8 groups, state 128, chunks of 128, bf16)
+    with its gradients: the two Pallas kernels of ops/ssd_pallas.py, no
+    array of chunk x chunk a head (`[2,64,8,8,128,128]`, 134 M elements)
+    in any dtype or order, and the temporaries at its fullest under the
+    XLA form's 0.98 GiB (0.66 as compiled: y's cotangent and the states
+    entering each chunk, 0.25 GiB each in float32, and dx)."""
+    compiled = _scan_with_gradients(
+        chip, monkeypatch, b=2, t=8192, h=64, p=64, g=8, n=128, chunk=128,
+        dtype=jnp.bfloat16)
+    text = compiled.as_text()
+    assert len(re.findall(r"= .*custom-call.*tpu_custom_call", text)) == 2
+    products = sorted({kind for kind in re.findall(r"\w+\[[\d,]+\]", text)
+                       if _elements(kind) >= 2 * 64 * 64 * 128 * 128})
+    assert not products, products
     memory = compiled.memory_analysis()
     temporaries = (memory.peak_memory_in_bytes - memory.argument_size_in_bytes
                    - memory.output_size_in_bytes) / 2 ** 30
-    assert temporaries < 3.0, f"{temporaries:.2f} GiB of temporaries"
+    assert temporaries < 0.75, f"{temporaries:.2f} GiB of temporaries"
+
+
+def test_chunked_scan_at_the_tiny_preset_s_widths_stays_xla(chip,
+                                                            monkeypatch):
+    """`nemotron3_nano_tiny` (chunks of 8, 4 heads of 8 in 2 groups, state
+    16, float32) on a TPU: no tile of the chip's, so no kernel."""
+    compiled = _scan_with_gradients(
+        chip, monkeypatch, b=2, t=32, h=4, p=8, g=2, n=16, chunk=8,
+        dtype=jnp.float32)
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 def test_expert_share_moves_only_the_rows_its_buffers_hold(chip):

@@ -23,7 +23,7 @@ from distributed_vgg_f_tpu.config import ModelConfig, get_config
 from distributed_vgg_f_tpu.models import nemotron_h
 from distributed_vgg_f_tpu.models.mistral4 import ExpertShare
 from distributed_vgg_f_tpu.models.registry import build_model
-from distributed_vgg_f_tpu.ops import ssd
+from distributed_vgg_f_tpu.ops import ssd, ssd_pallas
 from distributed_vgg_f_tpu.parallel.mesh import MeshSpec, build_mesh
 from distributed_vgg_f_tpu.train.trainer import Trainer
 from distributed_vgg_f_tpu.utils.logging import MetricLogger
@@ -129,6 +129,145 @@ def test_smallest_decay_is_the_scan_s_own():
     assert 0 < want < 1
 
 
+# ---- the Pallas kernels (ops/ssd_pallas.py), interpreted -------------------
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """`ssd.ssd` takes the kernels off a TPU, in the Pallas interpreter."""
+    monkeypatch.setattr(ssd_pallas, "INTERPRET", True)
+
+
+def _tile_inputs(chunks, groups, dtype, seed=0):
+    """Two sequences at the smallest sizes the kernels take: chunks of 128,
+    two heads of 64 a group, state 128."""
+    inputs = _scan_inputs(seed, seq=128 * chunks, heads=2 * groups, dim=64,
+                          groups=groups, state=128)
+    return {k: v.astype(dtype) if k in "xBC" else v
+            for k, v in inputs.items()}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("groups", [1, 2])
+@pytest.mark.parametrize("chunks", [1, 3])
+def test_kernels_equal_the_xla_form_and_the_literal_recurrence(
+        interpreted, chunks, groups, dtype):
+    """Values and every input's gradient (x, dt, A, B, C, D). In float32
+    the kernels, the XLA form and the recurrence differ by the order of
+    their sums; in bf16 the kernels round what the XLA form rounds (its own
+    backward rounds the cotangents of its products once more, which is the
+    0.5 % between the two gradients) and the float32 recurrence on the
+    same rounded inputs stands a bf16 product's rounding away."""
+    inputs = _tile_inputs(chunks, groups, jnp.dtype(dtype))
+    assert ssd.takes_kernels(inputs["x"].shape, inputs["B"].shape, 128)
+    weigh = jax.random.normal(jax.random.key(9), inputs["x"].shape)
+    as_f32 = lambda kw: {k: v.astype(jnp.float32) for k, v in kw.items()}
+    forms = {"kernels": lambda kw: ssd.ssd(**kw, chunk=128),
+             "xla": lambda kw: ssd.ssd_xla(**kw, chunk=128),
+             "literal": lambda kw: _literal(**as_f32(kw))}
+    assert "pallas_call" in str(jax.make_jaxpr(forms["kernels"])(inputs))
+    assert "pallas_call" not in str(jax.make_jaxpr(forms["xla"])(inputs))
+    value, grad = {}, {}
+    for name, form in forms.items():
+        value[name], grad[name] = jax.jit(jax.value_and_grad(
+            lambda kw, form=form: jnp.sum(form(kw) * weigh)))(inputs)
+    y = jax.jit(forms["kernels"])(inputs)
+    assert y.dtype == jnp.float32 and y.shape == inputs["x"].shape
+    limits = {"xla": TOLERANCE, "literal": TOLERANCE} \
+        if dtype == "float32" else {"xla": 1e-2, "literal": 2e-2}
+    for other, limit in limits.items():
+        assert _rel(y, jax.jit(forms[other])(inputs)) < limit, other
+        for name in inputs:
+            assert grad["kernels"][name].dtype == inputs[name].dtype
+            assert _rel(grad["kernels"][name].astype(jnp.float32),
+                        grad[other][name].astype(jnp.float32)) < limit, \
+                (other, name)
+
+
+@pytest.mark.parametrize("shape", [
+    dict(seq=512, heads=2, dim=64, groups=1, state=128, chunk=256),
+    dict(seq=256, heads=4, dim=32, groups=1, state=256, chunk=128),
+    dict(seq=256, heads=2, dim=128, groups=2, state=128, chunk=128)],
+    ids=["chunks_of_256", "heads_of_32_state_256", "heads_of_128"])
+def test_kernels_at_the_other_sizes_they_admit(interpreted, shape):
+    """A chunk of two lane tiles, four heads to a lane tile, one head to a
+    lane tile: float32 values and gradients against the XLA form."""
+    chunk = shape.pop("chunk")
+    inputs = _scan_inputs(**shape)
+    assert ssd.takes_kernels(inputs["x"].shape, inputs["B"].shape, chunk)
+    weigh = jax.random.normal(jax.random.key(9), inputs["x"].shape)
+    got, want = (jax.jit(jax.value_and_grad(
+        lambda kw, form=form: jnp.sum(form(**kw, chunk=chunk) * weigh)))(
+            inputs) for form in (ssd.ssd, ssd.ssd_xla))
+    assert abs(float(got[0]) - float(want[0])) \
+        < TOLERANCE * abs(float(want[0])) + 1e-3
+    for name in inputs:
+        assert _rel(got[1][name], want[1][name]) < TOLERANCE, name
+
+
+def test_a_forgetful_scan_fails_the_same_tolerance_against_the_kernels(
+        interpreted):
+    """`chunk_reset`, the fault the reference plants, is a hundred
+    tolerances away from what the kernels carry from chunk to chunk."""
+    inputs = _tile_inputs(3, 1, jnp.float32)
+    got = ssd.ssd(**inputs, chunk=128)
+    assert _rel(got, jax.jit(_literal)(**inputs)) < TOLERANCE
+    forgetful = _forgetful(**inputs, chunk=128)
+    assert _rel(forgetful, got) > 100 * TOLERANCE
+    assert _rel(forgetful, jax.jit(_literal, static_argnames="reset_every")(
+        **inputs, reset_every=128)) < TOLERANCE
+
+
+@pytest.mark.parametrize("form", ["kernels", "xla"])
+def test_a_rest_of_a_chunk_still_raises(form, monkeypatch):
+    monkeypatch.setattr(ssd_pallas, "INTERPRET", form == "kernels")
+    inputs = _scan_inputs(seq=200, heads=2, dim=64, groups=1, state=128)
+    with pytest.raises(ValueError, match="neither may leave a rest"):
+        ssd.ssd(**inputs, chunk=128)
+    with pytest.raises(ValueError, match="neither may leave a rest"):
+        ssd.ssd(**_scan_inputs(heads=3, groups=2), chunk=8)
+
+
+@pytest.mark.parametrize("shape, taken", [
+    # the benchmark's cell, and the smallest the kernels take
+    (dict(t=8192, h=64, p=64, g=8, n=128, chunk=128), True),
+    (dict(t=128, h=2, p=64, g=1, n=128, chunk=128), True),
+    # the tiny preset; a state, a chunk, a group's lanes that are no tile
+    (dict(t=32, h=4, p=8, g=2, n=16, chunk=8), False),
+    (dict(t=256, h=2, p=64, g=1, n=64, chunk=128), False),
+    (dict(t=256, h=2, p=64, g=1, n=128, chunk=64), False),
+    (dict(t=256, h=1, p=64, g=1, n=128, chunk=128), False),
+    # a sequence shorter than its chunk is one chunk of its own length
+    (dict(t=64, h=2, p=64, g=1, n=128, chunk=128), False)])
+def test_shapes_and_backend_choose_the_kernels(shape, taken, monkeypatch):
+    x = (2, shape["t"], shape["h"], shape["p"])
+    group = (2, shape["t"], shape["g"], shape["n"])
+    assert ssd_pallas.applies(x, group, shape["chunk"]) == taken
+    assert not ssd.takes_kernels(x, group, shape["chunk"])   # the CPU
+    monkeypatch.setattr(ssd_pallas, "INTERPRET", True)
+    assert ssd.takes_kernels(x, group, shape["chunk"]) == taken
+
+
+#: a Mamba mixer at the smallest sizes the kernels take
+TILE_MAMBA = {"mamba_num_heads": 2, "mamba_head_dim": 64, "n_groups": 1,
+              "ssm_state_size": 128, "chunk_size": 128}
+
+
+@pytest.mark.parametrize("case", ["tile_sizes", "tiny_preset"])
+def test_mixer_sows_whether_it_took_the_kernels(case, interpreted):
+    """`ssm_kernel` beside `ssm_chunks`: 1 at tile sizes, 0 for the tiny
+    preset, interpreter on in both."""
+    model, arch = _model(**(TILE_MAMBA if case == "tile_sizes" else {}))
+    seq = 256 if case == "tile_sizes" else SEQ
+    mixer = nemotron_h.Mamba2Mixer(**model.mixers["mamba"],
+                                   compute_dtype=jnp.float32)
+    u = jax.random.normal(jax.random.key(4), (2, seq, arch["hidden_size"]))
+    params = mixer.init(jax.random.key(1), u)["params"]
+    _, sown = mixer.apply({"params": params}, u, mutable=["counters"])
+    counters = {k: v[0] for k, v in sown["counters"].items()}
+    assert counters["ssm_kernel"] == (1 if case == "tile_sizes" else 0)
+    assert counters["ssm_chunks"] == 2 * seq // arch["chunk_size"]
+
+
 # ---- each mixer against the plain reference --------------------------------
 
 MIXERS = {"mamba": (0, nemotron_h.Mamba2Mixer),
@@ -136,6 +275,9 @@ MIXERS = {"mamba": (0, nemotron_h.Mamba2Mixer),
           "attention": (3, nemotron_h.GroupedQueryAttention),
           # the Pallas kernel with 2 query heads a key head, interpreted
           "attention_flash": (3, nemotron_h.GroupedQueryAttention),
+          # the recurrence through the Pallas kernels, interpreted, at the
+          # smallest sizes they take (`TILE_MAMBA`, two chunks)
+          "mamba_kernels": (0, nemotron_h.Mamba2Mixer),
           # the routed path's widths padded to whole tiles (of 24 here:
           # hidden 64 -> 72, width 32 -> 48), as the cell's 2688 and 1856 are
           "experts_padded": (1, ExpertShare)}
@@ -152,12 +294,16 @@ def test_mixer_matches_the_reference(case, monkeypatch):
         assert (mistral4._to_whole_tiles(64), mistral4._to_whole_tiles(32),
                 mistral4._to_whole_tiles(24), mistral4._to_whole_tiles(16)) \
             == (8, 16, 0, 0)
+    seq, extra = SEQ, {}
+    if case == "mamba_kernels":
+        monkeypatch.setattr(ssd_pallas, "INTERPRET", True)
+        seq, extra = 256, TILE_MAMBA
     kind = case.split("_")[0]
     index, layer = MIXERS[case]
-    model, arch = _model()
+    model, arch = _model(**extra)
     params, _ = _seeded(model)
     p = params[f"layer_{index}"]["mixer"]
-    u = jax.random.normal(jax.random.key(4), (2, SEQ, arch["hidden_size"]))
+    u = jax.random.normal(jax.random.key(4), (2, seq, arch["hidden_size"]))
     weigh = jax.random.normal(jax.random.key(5), u.shape)
     kwargs = dict(model.mixers[kind], compute_dtype=jnp.float32)
     share = (0, arch["n_routed_experts"])
@@ -311,6 +457,7 @@ def test_fit_with_the_tiny_preset_logs_every_layer_under_its_own_index():
         telemetry.reset()
         telemetry.configure(enabled=True)
     assert gauges["ssm/chunks"] == 2 * 8              # two Mamba layers
+    assert gauges["ssm/kernel_layers"] == 0           # chunks of 8: XLA's
     assert 0 < gauges["ssm/decay_min"] < 1
     assert gauges["moe/assignments_held"] == 2 * 128  # two expert layers
     assert gauges["moe/dropped_assignments"] == 0
@@ -321,6 +468,7 @@ def test_fit_with_the_tiny_preset_logs_every_layer_under_its_own_index():
     assert abs(float(lines[0][1]) - first) < 2e-5 * first
     for name in ("moe_held/layer_1", "moe_dropped/layer_4=0",
                  "moe_passes/layer_4=1", "ssm_chunks/layer_0=8",
+                 "ssm_kernel/layer_0=0", "ssm_kernel/layer_2=0",
                  "ssm_decay_min/layer_2"):
         assert name in log, name
     assert "moe_held/layer_0" not in log and "ssm_chunks/layer_1" not in log

@@ -265,6 +265,49 @@ def test_routed_experts_backward_keeps_the_three_names():
         "scatter-add": both("moe_dispatch", "moe_combine")}, found
 
 
+def test_scan_kernels_keep_the_name_ssm_scan(monkeypatch):
+    """The hybrid model's train step at the smallest sizes the recurrence's
+    Pallas kernels take (ops/ssd_pallas.py), lowered for a TPU. The op is
+    one jitted function, which JAX lowers once for both Mamba layers and
+    apart from its call sites: three kernels in all (the forward, the
+    block's recomputed forward that saves the entering states, the
+    hand-written backward), under no name stack but what the function
+    opens itself. So it opens `ssm_scan`, in the forward and in the
+    backward, and the benchmark's reader gives all three to that row:
+    `ssm_pct` and `ssm_scan_roofline_pct` keep reading it."""
+    import json
+    from chipbench import scope_reduce
+    cfg = apply_overrides(get_config("nemotron3_nano_tiny"), {
+        "model.extra.mamba_num_heads": 2, "model.extra.mamba_head_dim": 64,
+        "model.extra.n_groups": 1, "model.extra.ssm_state_size": 128,
+        "model.extra.chunk_size": 128, "model.extra.seq_len": 256})
+    mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
+                      jax.devices()[:1])
+    trainer = Trainer(cfg, mesh=mesh, logger=MetricLogger(stream=io.StringIO()))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (cfg.data.global_batch_size, 257), jnp.int32)}
+    state, rng = jax.eval_shape(trainer.init_state), trainer.base_rng()
+    # `ssd.ssd` and the attention core ask the backend: a TPU's trace
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = trainer.train_step.__wrapped__.trace(state, batch, rng).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    monkeypatch.undo()
+    with open(os.path.join(os.path.dirname(scope_reduce.__file__),
+                           "hybrid_lm_scopes.json")) as f:
+        names = json.load(f)
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = [named[loc] for loc in re.findall(
+        r"custom_call @tpu_custom_call.*loc\((#loc\d+)\)", text)]
+    scan = sorted(call for call in calls if "gqa_core" not in call)
+    assert scan == ["ssm_scan/pallas_call", "ssm_scan/pallas_call",
+                    "ssm_scan/ssm_scan/pallas_call"], calls
+    assert {scope_reduce.scope_of(call + ":", names)[0]
+            for call in scan} == {"ssm_scan"}
+    # the function is called under the layer's own `ssm_scan` too
+    assert any(stack.endswith("layer_0/mixer/ssm_scan/jit(scan)")
+               for stack in re.findall(r'loc\("([^"]*)"', text))
+
+
 def test_jitted_steps_are_named_for_what_they_are(lowered):
     """The module's name is what a trace's `XLA Modules` line shows and,
     unlike the scopes, part of the persistent compile cache's key."""
