@@ -1302,7 +1302,8 @@ MISTRAL_SMALL4_PUBLISHED = {
 def _language_model(name: str, extra: dict, vocab_rows: int, steps: int,
                     model: str = "mistral4") -> ExperimentConfig:
     """The language-model presets' common frame (`model`: the registry's
-    name, models/mistral4.py or models/nemotron_h.py): packed int32 tokens
+    name, models/mistral4.py, models/nemotron_h.py or models/ling3.py):
+    packed int32 tokens
     from the seeded source, one sequence a step, bf16 compute on float32
     weights, SGD-momentum 0.9 at a constant rate, no weight decay, no
     dropout, no augmentation (the catalog gives no recipe). `model.extra`
@@ -1417,6 +1418,67 @@ def _nemotron3_nano_tiny() -> ExperimentConfig:
         train=_replace(cfg.train, log_every=1))
 
 
+#: `Ling-3.0-flash-VL`'s published language-model config
+#: (https://huggingface.co/inclusionAI/Ling-3.0-flash-VL/blob/main/
+#: config.json): every width as published. `n_routed_experts` is the
+#: source's `num_experts` under the name `mistral4.ExpertShare` and the
+#: benchmark's driver know the router's width by.
+LING3_FLASH_PUBLISHED = {
+    "hidden_size": 2560, "intermediate_size": 6144,
+    "num_attention_heads": 32, "head_dim": 128, "layer_group_size": 6,
+    "short_conv_kernel_size": 4, "kda_lower_bound": -5,
+    "q_lora_rank": None, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "v_head_dim": 128, "rope_theta": 6000000,
+    "n_routed_experts": 512, "num_experts_per_tok": 8, "n_group": 8,
+    "topk_group": 4, "moe_intermediate_size": 768,
+    "moe_shared_expert_intermediate_size": 768,
+    "routed_scaling_factor": 2.5, "rms_norm_eps": 1e-6,
+    "num_hidden_layers": 42, "first_k_dense_replace": 2,
+}
+
+
+def _ling3_flash_ep64() -> ExperimentConfig:
+    """One chip's share of Ling-3.0-flash's language stack under 64-way
+    expert parallelism: 7 of 42 layers, one leading dense layer (of two)
+    and one period of six expert layers, kinds by the published rule
+    (`DKKKKLK`: six Kimi-delta layers, one latent), experts [0, 8) of 512
+    (the router stays 512 wide, 8 groups, top-8 of the best 4), 19648 of
+    157184 vocabulary rows, two sequences of 8192 a step. 0.82 B parameters
+    at 12 bytes each: recomputation per block and the loss in chunks, as
+    the Mistral preset and for its reason."""
+    cfg = _language_model(
+        "ling3_flash_ep64",
+        {**LING3_FLASH_PUBLISHED, "num_hidden_layers": 7,
+         "first_k_dense_replace": 1, "hybrid_override_pattern": "DKKKKLK",
+         "first_expert": 0, "experts_held": 8, "seq_len": 8192},
+        vocab_rows=19648, steps=100, model="ling3")
+    return _replace(cfg, data=_replace(cfg.data, global_batch_size=2),
+                    optim=_replace(cfg.optim, reference_batch_size=2))
+
+
+def _ling3_flash_tiny() -> ExperimentConfig:
+    """The same seven kinds of layer at a size a CPU test holds, every
+    expert held: hidden 64, 4 heads of 16 (latent: 16 + 8 on values of
+    16, rank 16), 16 experts in 4 groups, top-4 of the best 2, vocabulary
+    256, sequences of 128 (two chunks of the delta rule), float32."""
+    cfg = _language_model(
+        "ling3_flash_tiny",
+        {**LING3_FLASH_PUBLISHED, "hidden_size": 64, "intermediate_size": 96,
+         "num_attention_heads": 4, "head_dim": 16, "kv_lora_rank": 16,
+         "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+         "n_routed_experts": 16, "num_experts_per_tok": 4, "n_group": 4,
+         "topk_group": 2, "moe_intermediate_size": 32,
+         "moe_shared_expert_intermediate_size": 32, "num_hidden_layers": 7,
+         "first_k_dense_replace": 1, "hybrid_override_pattern": "DKKKKLK",
+         "seq_len": 128},
+        vocab_rows=256, steps=3, model="ling3")
+    return _replace(
+        cfg, model=_replace(cfg.model, compute_dtype="float32"),
+        data=_replace(cfg.data, global_batch_size=2),
+        optim=_replace(cfg.optim, reference_batch_size=2),
+        train=_replace(cfg.train, log_every=1))
+
+
 PRESETS = {
     "vggf_cifar10_smoke": _vggf_cifar10_smoke,
     "vggf_imagenet_dp": _vggf_imagenet_dp,
@@ -1429,6 +1491,8 @@ PRESETS = {
     "mistral_small4_tiny": _mistral_small4_tiny,
     "nemotron3_nano_30b_ep8": _nemotron3_nano_30b_ep8,
     "nemotron3_nano_tiny": _nemotron3_nano_tiny,
+    "ling3_flash_ep64": _ling3_flash_ep64,
+    "ling3_flash_tiny": _ling3_flash_tiny,
 }
 
 

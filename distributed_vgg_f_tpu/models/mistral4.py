@@ -32,8 +32,9 @@ assignment falls on this share.
 Training only: serving (a latent cache), checkpoint re-topology and ZeRO's
 flat vector for this model are out of scope.
 
-**Shared with `models/nemotron_h.py`**, which builds another stack on the
-same pieces: `RMSNorm`, `_dense`, `Head` and `chunked_next_token_loss`; and
+**Shared with `models/nemotron_h.py` and `models/ling3.py`**, which build
+other stacks on the same pieces (the latter also on `LatentAttention`,
+without a query compression and with a plain rotary table): `RMSNorm`, `_dense`, `Head` and `chunked_next_token_loss`; and
 the whole expert share (`route`, `routed_capacity`, `routed_passes`,
 `_window`, `routed_experts` with its hand-written backward pass,
 `ExpertShare`), which takes the scoring (`softmax` | `sigmoid`, the latter
@@ -73,6 +74,13 @@ def yarn_inv_freq(dim: int, base: float, factor: float, original_len: int,
                    / (high - low), 0.0, 1.0)
     extrap = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
     return (extrap * (1.0 - ramp) + extrap / factor * ramp).astype(np.float32)
+
+
+def plain_inv_freq(dim: int, base: float) -> np.ndarray:
+    """The `dim // 2` inverse frequencies of a rotary table with no
+    scaling."""
+    return (1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+            ).astype(np.float32)
 
 
 def rotate_interleaved(x, positions, inv_freq):
@@ -128,9 +136,13 @@ class LatentAttention(nn.Module):
     """MLA, causal over the whole sequence. The core is the Pallas kernel
     of ops/flash_attention.py where that can run (a TPU, or the Pallas
     interpreter that tests switch on) and explicit scores elsewhere (a CPU
-    run of the tiny preset)."""
+    run of the tiny preset). `q_lora_rank=None`: no query compression, one
+    `q_proj` and no `q_a_norm` (DeepSeek-V2-Lite's form, models/ling3.py).
+    `rope` without `rope_type: yarn` is a plain table by its `rope_theta`:
+    no frequency ramp, no attention or query scale. Query and key heads
+    (nope + rope) and value heads may differ in size."""
     num_heads: int
-    q_lora_rank: int
+    q_lora_rank: int | None
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -146,27 +158,33 @@ class LatentAttention(nn.Module):
         h, dn, dr, dv = (self.num_heads, self.qk_nope_head_dim,
                          self.qk_rope_head_dim, self.v_head_dim)
         rope = dict(self.rope)
+        yarn = rope.get("rope_type") == "yarn"
         inv_freq = jnp.asarray(yarn_inv_freq(
             dr, rope["rope_theta"], rope["factor"],
             rope["original_max_position_embeddings"], rope["beta_fast"],
-            rope["beta_slow"]))
+            rope["beta_slow"]) if yarn
+            else plain_inv_freq(dr, rope["rope_theta"]))
         positions = jnp.arange(t)
-        # the kernel applies d^-0.5 itself: what is left goes on q
-        q_scale = yarn_attention_scale(dn + dr, rope["factor"],
-                                       rope["mscale_all_dim"]) \
-            * math.sqrt(dn + dr)
         with jax.named_scope("mla_q"):
-            c_q = RMSNorm(self.eps, name="q_a_norm")(
-                _dense(self.q_lora_rank, self.compute_dtype, "q_a_proj")(x))
-            q = _dense(h * (dn + dr), self.compute_dtype, "q_b_proj")(c_q)
+            if self.q_lora_rank is None:
+                q = _dense(h * (dn + dr), self.compute_dtype, "q_proj")(x)
+            else:
+                c_q = RMSNorm(self.eps, name="q_a_norm")(_dense(
+                    self.q_lora_rank, self.compute_dtype, "q_a_proj")(x))
+                q = _dense(h * (dn + dr), self.compute_dtype, "q_b_proj")(c_q)
             q = q.reshape(b, t, h, dn + dr)
             q = jnp.concatenate(
                 [q[..., :dn],
                  rotate_interleaved(q[..., dn:], positions, inv_freq)], -1)
-            q = (q.astype(jnp.float32) * (q_scale * llama4_query_scale(
-                positions, rope["original_max_position_embeddings"],
-                rope["llama_4_scaling_beta"]))[None, :, None, None]
-                 ).astype(self.compute_dtype)
+            if yarn:
+                # the kernel applies d^-0.5 itself: what is left goes on q
+                q_scale = yarn_attention_scale(dn + dr, rope["factor"],
+                                               rope["mscale_all_dim"]) \
+                    * math.sqrt(dn + dr)
+                q = (q.astype(jnp.float32) * (q_scale * llama4_query_scale(
+                    positions, rope["original_max_position_embeddings"],
+                    rope["llama_4_scaling_beta"]))[None, :, None, None]
+                     ).astype(self.compute_dtype)
         with jax.named_scope("mla_kv"):
             kv_a = _dense(self.kv_lora_rank + dr, self.compute_dtype,
                           "kv_a_proj")(x)
@@ -182,9 +200,6 @@ class LatentAttention(nn.Module):
             v = kv[..., dn:]
         with jax.named_scope("mla_core"):
             if jax.default_backend() == "tpu" or flash_attention.INTERPRET:
-                if dv != dn + dr:
-                    raise ValueError("the flash core takes one head size "
-                                     f"(qk {dn + dr}, v {dv})")
                 # the kernel's own default is blocks of at most 128: at
                 # 4096 tokens and 32 heads that is 17 k grid steps of one
                 # small product each, 30 ms forward and backward on a v5e
@@ -207,8 +222,20 @@ class LatentAttention(nn.Module):
                 ctx.reshape(b, t, h * dv))
 
 
+def kept_groups(choice, n_group: int, topk_group: int):
+    """DeepSeek-V3's group limit: the experts in `n_group` equal runs, a
+    group's score the sum of its two largest `choice` (tokens, experts),
+    the `topk_group` best groups kept (the lower index wins a tie).
+    Returns (tokens, n_group) bool."""
+    groups = choice.reshape(choice.shape[0], n_group, -1)
+    best_two, _ = jax.lax.top_k(groups, 2)
+    _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    return jnp.any(kept[..., None] == jnp.arange(n_group), axis=-2)
+
+
 def route(scores, top_k: int, first_expert: int, experts_held: int, *,
-          bias=None, scale: float = 1.0):
+          bias=None, scale: float = 1.0, n_group: int = 1,
+          topk_group: int = 1):
     """Top-k of `scores` (tokens, experts) and this share's view of it:
     `(weights, local)` of shape (tokens, top_k), weights normalised over
     all chosen experts, `local` the held experts' index in
@@ -216,12 +243,20 @@ def route(scores, top_k: int, first_expert: int, experts_held: int, *,
     With a selection `bias` (experts,) the choice is by `scores + bias`,
     the weights are the chosen scores themselves over their sum, times
     `scale`, and no gradient reaches the bias (the sigmoid scoring of
-    DeepSeek-V3's router, which models/nemotron_h.py takes)."""
+    DeepSeek-V3's router, which models/nemotron_h.py takes); with
+    `n_group` > 1 the choice is among the experts of the `topk_group`
+    groups that `kept_groups` keeps (models/ling3.py)."""
     if bias is None:
         top_p, top_e = jax.lax.top_k(scores, top_k)
         weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     else:
-        _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+        choice = scores + jax.lax.stop_gradient(bias)
+        if n_group > 1:
+            kept = kept_groups(choice, n_group, topk_group)
+            choice = jnp.where(
+                jnp.repeat(kept, choice.shape[-1] // n_group, axis=-1),
+                choice, -jnp.inf)
+        _, top_e = jax.lax.top_k(choice, top_k)
         top_p = jnp.take_along_axis(scores, top_e, axis=-1)
         weights = scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True)
                                    + 1e-20)
@@ -406,10 +441,10 @@ class ExpertShare(nn.Module):
     """The routed experts this chip holds, and the shared expert.
     `scoring` "softmax" (over all experts) or "sigmoid" (each expert's own,
     chosen with the selection bias `router_bias`, the weights times
-    `routed_scaling_factor`); `expert` one of `EXPERTS`, for the routed
-    experts and the shared one alike; the shared expert is
-    `shared_intermediate_size` wide (default: `moe_intermediate_size` x
-    `n_shared_experts`)."""
+    `routed_scaling_factor`, the choice under `route`'s group limit where
+    `n_group` > 1); `expert` one of `EXPERTS`, for the routed experts and
+    the shared one alike; the shared expert is `shared_intermediate_size`
+    wide (default: `moe_intermediate_size` x `n_shared_experts`)."""
     n_routed_experts: int
     num_experts_per_tok: int
     moe_intermediate_size: int
@@ -421,6 +456,8 @@ class ExpertShare(nn.Module):
     expert: str = "swiglu"
     routed_scaling_factor: float = 1.0
     shared_intermediate_size: int | None = None
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, u):
@@ -443,9 +480,20 @@ class ExpertShare(nn.Module):
             else:
                 bias = self.param("router_bias", nn.initializers.normal(0.01),
                                   (self.n_routed_experts,), jnp.float32)
+                scores = jax.nn.sigmoid(logits)
                 weights, local = route(
-                    jax.nn.sigmoid(logits), k, self.first_expert, held,
-                    bias=bias, scale=self.routed_scaling_factor)
+                    scores, k, self.first_expert, held, bias=bias,
+                    scale=self.routed_scaling_factor, n_group=self.n_group,
+                    topk_group=self.topk_group)
+                if self.n_group > 1:
+                    # the share of the batch's tokens whose kept groups
+                    # include the one this share's first expert is in: what
+                    # the group limit lets reach this chip at all
+                    mine = self.first_expert * self.n_group \
+                        // self.n_routed_experts
+                    self.sow("counters", "group_share", jnp.mean(kept_groups(
+                        scores + bias, self.n_group, self.topk_group
+                    )[:, mine].astype(jnp.float32)))
         with jax.named_scope("moe_dispatch"):
             # every assignment of the batch, sorted by held expert; those
             # of experts that live elsewhere sort behind the last group,

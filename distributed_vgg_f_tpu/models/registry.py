@@ -102,3 +102,11 @@ def _build_nemotron_h(cfg: ModelConfig) -> nn.Module:
     # as `mistral4`: vocabulary rows held, published widths and the share
     from distributed_vgg_f_tpu.models import nemotron_h
     return nemotron_h.build(cfg.num_classes, _dtype(cfg), cfg.extra)
+
+
+@register("ling3")
+def _build_ling3(cfg: ModelConfig) -> nn.Module:
+    # as `mistral4`: vocabulary rows held, published widths, the share and
+    # the cut in depth
+    from distributed_vgg_f_tpu.models import ling3
+    return ling3.build(cfg.num_classes, _dtype(cfg), cfg.extra)

@@ -459,6 +459,7 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
 
     def _fwd_call(q3, k3, v3):
         bh, t, d = q3.shape
+        dv = v3.shape[-1]       # the values' (and the output's) own head size
         nq, nk = t // block_q, t // block_k
         scale = 1.0 / math.sqrt(d)
         if jagged:
@@ -476,13 +477,13 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
                                        lambda b, s, qi, ki: (b, qi[s], 0)),
                           pl.BlockSpec((1, block_k, d), lambda b, s, qi, ki:
                                        (kv_row(b), ki[s], 0)),
-                          pl.BlockSpec((1, block_k, d), lambda b, s, qi, ki:
+                          pl.BlockSpec((1, block_k, dv), lambda b, s, qi, ki:
                                        (kv_row(b), ki[s], 0))],
-                out_specs=[pl.BlockSpec((1, block_q, d),
+                out_specs=[pl.BlockSpec((1, block_q, dv),
                                         lambda b, s, qi, ki: (b, qi[s], 0)),
                            pl.BlockSpec((1, block_q, 1),
                                         lambda b, s, qi, ki: (b, qi[s], 0))],
-                scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                scratch_shapes=[pltpu.VMEM((block_q, dv), jnp.float32),
                                 pltpu.VMEM((block_q, 128), jnp.float32),
                                 pltpu.VMEM((block_q, 128), jnp.float32)],
             )
@@ -490,25 +491,28 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
                 functools.partial(_fwd_kernel_jagged, scale=scale,
                                   block_q=block_q, block_k=block_k),
                 grid_spec=grid_spec,
-                out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+                out_shape=[jax.ShapeDtypeStruct((bh, t, dv), q3.dtype),
                            jax.ShapeDtypeStruct((bh, t, 1), jnp.float32)],
                 interpret=interpret,
             )(qi_arr, ki_arr, q3, k3, v3)
             return out, lse
         grid = (bh, nq, nk)
         q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-        kv_spec = pl.BlockSpec((1, block_k, d),
-                               lambda b, i, j: (kv_row(b), j, 0))
+        k_spec = pl.BlockSpec((1, block_k, d),
+                              lambda b, i, j: (kv_row(b), j, 0))
+        v_spec = pl.BlockSpec((1, block_k, dv),
+                              lambda b, i, j: (kv_row(b), j, 0))
         out, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
                               block_k=block_k, causal=causal, kv_len=kv_len),
             grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=[q_spec,
+            in_specs=[q_spec, k_spec, v_spec],
+            out_specs=[pl.BlockSpec((1, block_q, dv),
+                                    lambda b, i, j: (b, i, 0)),
                        pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))],
-            out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+            out_shape=[jax.ShapeDtypeStruct((bh, t, dv), q3.dtype),
                        jax.ShapeDtypeStruct((bh, t, 1), jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((block_q, dv), jnp.float32),
                             pltpu.VMEM((block_q, 128), jnp.float32),
                             pltpu.VMEM((block_q, 128), jnp.float32)],
             interpret=interpret,
@@ -531,6 +535,7 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
         q3, k3, v3, out3, lse, b, h = res
         do3 = _bh_layout(g)
         bh, t, d = q3.shape
+        dv = v3.shape[-1]
         nq, nk = t // block_q, t // block_k
         scale = 1.0 / math.sqrt(d)
         # delta_i = Σ_d dO_i · O_i, the softmax-backward row constant;
@@ -541,7 +546,11 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
         if jagged:
             qs = pl.BlockSpec((1, block_q, d),
                               lambda b_, s, a, c: (b_, a[s], 0))
+            dos = pl.BlockSpec((1, block_q, dv),
+                               lambda b_, s, a, c: (b_, a[s], 0))
             ks = pl.BlockSpec((1, block_k, d),
+                              lambda b_, s, a, c: (kv_row(b_), c[s], 0))
+            vs = pl.BlockSpec((1, block_k, dv),
                               lambda b_, s, a, c: (kv_row(b_), c[s], 0))
             rs = pl.BlockSpec((1, block_q, 1),
                               lambda b_, s, a, c: (b_, a[s], 0))
@@ -553,7 +562,7 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=2,
                     grid=(bh, len(qi_np)),
-                    in_specs=[qs, ks, ks, qs, rs, rs],
+                    in_specs=[qs, ks, vs, dos, rs, rs],
                     out_specs=qs,
                     scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
                 out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
@@ -567,11 +576,17 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
             ki_arr, qi_arr = np.triu_indices(nq)
             qs_t = pl.BlockSpec((1, block_q, d),
                                 lambda b_, s, c, a: (b_, a[s], 0))
+            dos_t = pl.BlockSpec((1, block_q, dv),
+                                 lambda b_, s, c, a: (b_, a[s], 0))
             ks_t = pl.BlockSpec((1, block_k, d),
                                 lambda b_, s, c, a: (kv_row(b_), c[s], 0))
+            vs_t = pl.BlockSpec((1, block_k, dv),
+                                lambda b_, s, c, a: (kv_row(b_), c[s], 0))
             # dK/dV leave the kernel one partial a query head
-            dkv_t = pl.BlockSpec((1, block_k, d),
-                                 lambda b_, s, c, a: (b_, c[s], 0))
+            dk_t = pl.BlockSpec((1, block_k, d),
+                                lambda b_, s, c, a: (b_, c[s], 0))
+            dv_t = pl.BlockSpec((1, block_k, dv),
+                                lambda b_, s, c, a: (b_, c[s], 0))
             rs_t = pl.BlockSpec((1, block_q, 1),
                                 lambda b_, s, c, a: (b_, a[s], 0))
             dk3, dv3 = pl.pallas_call(
@@ -580,12 +595,12 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=2,
                     grid=(bh, len(ki_arr)),
-                    in_specs=[qs_t, ks_t, ks_t, qs_t, rs_t, rs_t],
-                    out_specs=[dkv_t, dkv_t],
+                    in_specs=[qs_t, ks_t, vs_t, dos_t, rs_t, rs_t],
+                    out_specs=[dk_t, dv_t],
                     scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                                    pltpu.VMEM((block_k, d), jnp.float32)]),
+                                    pltpu.VMEM((block_k, dv), jnp.float32)]),
                 out_shape=[jax.ShapeDtypeStruct(q3.shape, k3.dtype),
-                           jax.ShapeDtypeStruct(q3.shape, v3.dtype)],
+                           jax.ShapeDtypeStruct((bh, t, dv), v3.dtype)],
                 interpret=interpret,
             )(jnp.asarray(ki_arr.astype(np.int32)),
               jnp.asarray(qi_arr.astype(np.int32)), q3, k3, v3, do3, lse,
@@ -595,14 +610,17 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
                     _bthd_layout(_sum_over_group(dv3, group), b, h // group))
 
         q_spec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
-        kv_spec = pl.BlockSpec((1, block_k, d),
-                               lambda b_, i, j: (kv_row(b_), j, 0))
+        do_spec = pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0))
+        k_spec = pl.BlockSpec((1, block_k, d),
+                              lambda b_, i, j: (kv_row(b_), j, 0))
+        v_spec = pl.BlockSpec((1, block_k, dv),
+                              lambda b_, i, j: (kv_row(b_), j, 0))
         row_spec = pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0))
         dq3 = pl.pallas_call(
             functools.partial(_dq_kernel, scale=scale, block_q=block_q,
                               block_k=block_k, causal=causal, kv_len=kv_len),
             grid=(bh, nq, nk),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -611,22 +629,28 @@ def _make_op(causal: bool, block_q: int, block_k: int, interpret: bool,
 
         # transposed grid: KV block outer, Q blocks accumulate innermost
         q_spec_t = pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0))
-        kv_spec_t = pl.BlockSpec((1, block_k, d),
-                                 lambda b_, j, i: (kv_row(b_), j, 0))
-        dkv_spec_t = pl.BlockSpec((1, block_k, d),
-                                  lambda b_, j, i: (b_, j, 0))
+        do_spec_t = pl.BlockSpec((1, block_q, dv),
+                                 lambda b_, j, i: (b_, i, 0))
+        k_spec_t = pl.BlockSpec((1, block_k, d),
+                                lambda b_, j, i: (kv_row(b_), j, 0))
+        v_spec_t = pl.BlockSpec((1, block_k, dv),
+                                lambda b_, j, i: (kv_row(b_), j, 0))
+        dk_spec_t = pl.BlockSpec((1, block_k, d),
+                                 lambda b_, j, i: (b_, j, 0))
+        dv_spec_t = pl.BlockSpec((1, block_k, dv),
+                                 lambda b_, j, i: (b_, j, 0))
         row_spec_t = pl.BlockSpec((1, block_q, 1), lambda b_, j, i: (b_, i, 0))
         dk3, dv3 = pl.pallas_call(
             functools.partial(_dkv_kernel, scale=scale, block_q=block_q,
                               block_k=block_k, causal=causal, kv_len=kv_len),
             grid=(bh, nk, nq),
-            in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
+            in_specs=[q_spec_t, k_spec_t, v_spec_t, do_spec_t, row_spec_t,
                       row_spec_t],
-            out_specs=[dkv_spec_t, dkv_spec_t],
+            out_specs=[dk_spec_t, dv_spec_t],
             out_shape=[jax.ShapeDtypeStruct(q3.shape, k3.dtype),
-                       jax.ShapeDtypeStruct(q3.shape, v3.dtype)],
+                       jax.ShapeDtypeStruct((bh, t, dv), v3.dtype)],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+                            pltpu.VMEM((block_k, dv), jnp.float32)],
             interpret=interpret,
         )(q3, k3, v3, do3, lse, delta)
         return (_bthd_layout(dq3, b, h),
@@ -901,6 +925,12 @@ def flash_self_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          interpret: bool | None = None) -> jnp.ndarray:
     """Exact self-attention, O(T·D) HBM footprint. (B, T, H, D) in and out.
 
+    Two head sizes: `v` may have another last dimension than `q` and `k`
+    (latent attention's 192-wide queries and keys on 128-wide values); the
+    output has `v`'s, the scores are scaled by `q`'s `D^-0.5`. Each block
+    takes its array's whole last dimension, so neither has to be a multiple
+    of the 128 lanes.
+
     Grouped queries: `k` and `v` may hold fewer heads than `q`, (B, T, H_kv,
     D) with H a multiple of H_kv; query head j then reads key head
     j // (H / H_kv) through the kernels' block index maps, and no key or
@@ -942,7 +972,7 @@ def flash_self_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if causal_skip == "dma" and not causal:
         raise ValueError("causal_skip='dma' only applies to causal "
                          "attention — drop it or set causal=True")
-    if k.shape != v.shape or q.shape[:2] != k.shape[:2] \
+    if k.shape[:3] != v.shape[:3] or q.shape[:2] != k.shape[:2] \
             or q.shape[3] != k.shape[3] or q.shape[2] % k.shape[2]:
         raise ValueError(f"q/k/v shapes differ: {q.shape} {k.shape} {v.shape}")
     t, group = q.shape[1], q.shape[2] // k.shape[2]

@@ -44,3 +44,13 @@ LM_LAYERS = ("mla_q", "mla_kv", "mla_core", "mla_out", "moe_router",
 #: file, `chipbench/hybrid_lm_scopes.json`.
 HYBRID_LM_LAYERS = ("ssm_in", "ssm_conv", "ssm_scan", "ssm_gate_out",
                     "gqa_qkv", "gqa_core", "gqa_out")
+
+#: The layers of models/ling3.py that the lists above lack (`mla_*`,
+#: `moe_*`, `embed_tokens` and `lm_head` are shared): Kimi Delta Attention
+#: (`kda_qkv` its three projections, `kda_conv` the short convolutions and
+#: the norms of q and k, `kda_gates` the decay, beta and output gates,
+#: `kda_core` the recurrence of ops/kda.py, `kda_out` the head norm, the
+#: gate and the output projection) and the dense feed-forward. Its cell's
+#: traces are reduced by a fourth file, `chipbench/ling_lm_scopes.json`.
+LING_LM_LAYERS = ("kda_qkv", "kda_conv", "kda_gates", "kda_core", "kda_out",
+                  "mlp_dense")

@@ -34,9 +34,12 @@ NAMESPACE_HELP = {
     "step": "jitted train-step dispatch wrapper",
     "moe": "language-model expert routing (assignments held, expert "
            "load extremes, dropped assignments, passes over the routed "
-           "buffers and the rows they hold)",
+           "buffers and the rows they hold, the share of tokens the "
+           "router's group limit lets reach this share)",
     "ssm": "language-model state-space layers (chunks scanned a step, "
            "smallest decay of any layer, layers on the Pallas kernels)",
+    "kda": "language-model delta-rule linear-attention layers (chunks a "
+           "step, smallest decay of any layer)",
     "eval": "trainer evaluation passes",
     "distributed": "cross-process coordination barriers",
     "telemetry": "the telemetry registry itself (poller faults)",

@@ -58,21 +58,24 @@ def _expert_load_metrics(counts, counters, expert_layers) -> dict:
     took in this replica's batch, then the dropped ones, a row for each
     layer of `expert_layers` (the model says which of its layers have
     experts: all of models/mistral4.py's, four of nine in
-    models/nemotron_h.py's cell); `counters` is what the layers sowed, a
+    models/nemotron_h.py's cell, six of seven in models/ling3.py's); `counters` is what the layers sowed, a
     dict for each `layer_N`. Per expert layer, under the layer's own
     index: assignments held, the largest and smallest held expert's load,
     dropped assignments, the passes the routed path made over its buffers
-    and the rows those hold; and the whole table as `moe_load`. What a
-    layer without experts sowed keeps the name it was sown under
-    (`ssm_chunks/layer_0`)."""
+    and the rows those hold (and what else the expert share sowed, as
+    `moe_group_share`); and the whole table as `moe_load`. What a layer
+    without experts sowed, and what the attention (`attn`) of a layer with
+    experts did, keeps the name it was sown under (`ssm_chunks/layer_0`,
+    `kda_chunks/layer_1`)."""
     counts = counts.astype(jnp.float32)
     load, dropped = counts[:, :-1], counts[:, -1]
     metrics = {"moe_load": load}
 
     def sown(layer: str, prefix: str) -> None:
-        for module in counters.get(layer, {}).values():
-            for name, (value,) in module.items():
-                metrics[f"{prefix}{name}/{layer}"] = jnp.asarray(
+        for module, values in counters.get(layer, {}).items():
+            own = "" if module == "attn" else prefix
+            for name, (value,) in values.items():
+                metrics[f"{own}{name}/{layer}"] = jnp.asarray(
                     value, jnp.float32)
 
     for row, i in enumerate(expert_layers):

@@ -1121,6 +1121,23 @@ class Trainer:
                                               over_layers("ssm_chunks/"))
                                 reg.set_gauge("ssm/kernel_layers",
                                               over_layers("ssm_kernel/"))
+                            # the delta-rule layers' receipts and the group
+                            # limit's (models/ling3.py): chunks a step, the
+                            # smallest decay of any layer (exp(-5): a gate
+                            # at its bound), and the mean share of tokens
+                            # whose kept groups include this share's
+                            decays = [v for k, v in last_metrics.items()
+                                      if k.startswith("kda_decay_min/")]
+                            if decays:
+                                reg.set_gauge("kda/decay_min", min(decays))
+                                reg.set_gauge("kda/chunks", sum(
+                                    v for k, v in last_metrics.items()
+                                    if k.startswith("kda_chunks/")))
+                            shares = [v for k, v in last_metrics.items()
+                                      if k.startswith("moe_group_share/")]
+                            if shares:
+                                reg.set_gauge("moe/group_share",
+                                              sum(shares) / len(shares))
                         entry = {"step": step + 1, **last_metrics,
                                  **meter.snapshot(),
                                  # host_wait_fraction: share of wall time this

@@ -283,3 +283,59 @@ def test_expert_share_moves_only_the_rows_its_buffers_hold(chip):
     assert temporaries < 0.690, (
         f"{temporaries:.3f} GiB of temporaries; with 16,384-row buffers "
         f"(PR 30's tree) this function needed 0.690 GiB, with PR 31's 0.578")
+
+
+def test_delta_rule_core_compiles_at_the_cell_s_widths(chip):
+    """`ops/kda.py` at `ling3_flash_ep64`'s widths (2 x 8,192 positions, 32
+    heads of 128 on both sides, chunks of 64, bf16) with its five
+    gradients: plain XLA products (a kernel is a later PR's), no array of
+    tokens x chunk x channels x heads (`16384 x 64 x 128 x 32`, 4.3 G
+    elements: the pairwise decays of a chunk) or of a sixteenth of it (the
+    sub-blocks' own) in any dtype or order, and the temporaries at its
+    fullest under 2.0 GiB (1.89 as compiled: the states entering each
+    chunk in float32 0.5 GiB, the chunks' maps of the state and read-outs
+    1.4, their cotangents)."""
+    from distributed_vgg_f_tpu.ops import kda
+    b, t, h, d = 2, 8192, 32, 128
+    arg = lambda shape, kind: jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda.kda(q, k, v, g, beta))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((b, t, h, d), jnp.bfloat16), arg((b, t, h, d), jnp.bfloat16),
+        arg((b, t, h, d), jnp.bfloat16), arg((b, t, h, d), jnp.float32),
+        arg((b, t, h), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    pairwise = sorted({kind for kind in re.findall(r"\w+\[[\d,]+\]", text)
+                       if _elements(kind) >= b * t * 16 * d * h})
+    assert not pairwise, pairwise
+    memory = compiled.memory_analysis()
+    temporaries = (memory.peak_memory_in_bytes - memory.argument_size_in_bytes
+                   - memory.output_size_in_bytes) / 2 ** 30
+    assert temporaries < 2.0, f"{temporaries:.2f} GiB of temporaries"
+
+
+def test_latent_core_at_two_head_sizes_compiles_for_v5e(chip):
+    """The latent layer's attention core of `ling3_flash_ep64` (2
+    sequences of 8,192 tokens, 32 heads, queries and keys of 192 on values
+    of 128, blocks of 1,024, bf16) with its three gradients: three
+    kernels, each block its array's whole last dimension (192 is one and a
+    half lane tiles), and no copy of q or k padded to 256."""
+    q, v = (2, 8192, 32, 192), (2, 8192, 32, 128)
+
+    def fn(q, k, v):
+        return fa.flash_self_attention(q, k, v, causal=True, block_q=1024,
+                                       block_k=1024)
+
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=chip)
+            for s in (q, q, v)]
+    compiled = jax.jit(_sum_grad(fn, 3)).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"= .*custom-call.*tpu_custom_call", text)) == 3
+    assert not re.findall(r"bf16\[\d+,8192,256\]", text)
+    gradients = re.findall(r"ROOT .*\(bf16\[2,8192,32,192\]\S*, "
+                           r"bf16\[2,8192,32,192\]\S*, "
+                           r"bf16\[2,8192,32,128\]", text)
+    assert gradients, "dq, dk at 192 and dv at 128"

@@ -407,3 +407,62 @@ def test_grouped_queries_refuse_heads_that_do_not_divide():
     with pytest.raises(ValueError, match="shapes differ"):
         flash_self_attention(q, kv, jnp.zeros((1, 64, 2, 32)), causal=True,
                              interpret=True)
+
+
+# ---- two head sizes: queries and keys of one, values of another ------------
+
+@pytest.mark.parametrize("skip", ["mxu", "dma"])
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("sizes", [(192, 128), (48, 32), (32, 48)])
+def test_two_head_sizes_match_explicit_scores(sizes, group, skip):
+    """Latent attention's shape (models/ling3.py: 192-wide queries and keys
+    on 128-wide values, one and a half lane tiles against one) and two
+    sizes off the lanes, 8 query heads on 8 / group key heads, causal, on
+    the rectangular grids and the jagged ones: the output, in the values'
+    size, and the three gradients against the naive oracle. The scores are
+    scaled by the queries' size."""
+    d, dv = sizes
+    heads, t = 8, 128
+    kq, kk, kv, kc = jax.random.split(jax.random.key(13), 4)
+    q = jax.random.normal(kq, (2, t, heads, d))
+    k = jax.random.normal(kk, (2, t, heads // group, d))
+    v = jax.random.normal(kv, (2, t, heads // group, dv))
+    cot = jax.random.normal(kc, (2, t, heads, dv))
+
+    def flash_loss(q, k, v):
+        out = flash_self_attention(q, k, v, causal=True, block_q=64,
+                                   block_k=64, causal_skip=skip,
+                                   interpret=True)
+        return jnp.vdot(out, cot), out
+
+    def naive_loss(q, k, v):
+        out = naive_attention(q, jnp.repeat(k, group, axis=2),
+                              jnp.repeat(v, group, axis=2), causal=True)
+        return jnp.vdot(out, cot), out
+
+    (_, out), grads = jax.value_and_grad(
+        flash_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, ref), ref_grads = jax.value_and_grad(
+        naive_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert out.shape == (2, t, heads, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for g, r, name in zip(grads, ref_grads, "qkv"):
+        assert g.shape == r.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
+
+
+def test_two_head_sizes_pad_and_mask_like_one():
+    """The internal pad-to-block path (a prime-ish length) with another
+    value size, non-causal."""
+    kq, kk, kv = jax.random.split(jax.random.key(17), 3)
+    q = jax.random.normal(kq, (1, 197, 2, 24))
+    k = jax.random.normal(kk, (1, 197, 2, 24))
+    v = jax.random.normal(kv, (1, 197, 2, 16))
+    out = flash_self_attention(q, k, v, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(naive_attention(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="shapes differ"):
+        flash_self_attention(q, k[..., :16], v, interpret=True)

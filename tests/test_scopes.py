@@ -76,7 +76,8 @@ def lowered():
             "vgg16": _lowered_forward("vgg16"),
             "vit_s16": _lowered_forward("vit_s16", depth=1),
             "mistral4": _lowered_token_step(),
-            "nemotron_h": _lowered_token_step("nemotron3_nano_tiny")}
+            "nemotron_h": _lowered_token_step("nemotron3_nano_tiny"),
+            "ling3": _lowered_token_step("ling3_flash_tiny")}
 
 
 def _stacks(text: str) -> set:
@@ -98,12 +99,17 @@ HOME = {**{name: "vggf" for name in scopes.PHASES},
         "pool_init": "resnet50", "gap": "resnet50",
         "embed_tokens": "vit_s16",
         **{name: "mistral4" for name in scopes.LM_LAYERS},
-        **{name: "nemotron_h" for name in scopes.HYBRID_LM_LAYERS}}
+        **{name: "nemotron_h" for name in scopes.HYBRID_LM_LAYERS},
+        **{name: "ling3" for name in scopes.LING_LM_LAYERS}}
 
 
 def test_every_declared_name_has_a_home():
     assert set(HOME) == set(scopes.PHASES) | set(scopes.LAYERS) \
-        | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS)
+        | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS) \
+        | set(scopes.LING_LM_LAYERS)
+    assert not (set(scopes.PHASES) | set(scopes.LAYERS)
+                | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS)) \
+        & set(scopes.LING_LM_LAYERS)
     assert not set(scopes.PHASES) & set(scopes.LAYERS)
     assert not (set(scopes.PHASES) | set(scopes.LAYERS)) \
         & set(scopes.LM_LAYERS)
@@ -112,7 +118,8 @@ def test_every_declared_name_has_a_home():
 
 
 @pytest.mark.parametrize("name", scopes.PHASES + scopes.LAYERS
-                         + scopes.LM_LAYERS + scopes.HYBRID_LM_LAYERS)
+                         + scopes.LM_LAYERS + scopes.HYBRID_LM_LAYERS
+                         + scopes.LING_LM_LAYERS)
 def test_declared_name_reaches_the_lowered_program(lowered, name):
     assert _holds(_stacks(lowered[HOME[name]]), name)
 
@@ -154,6 +161,46 @@ def test_the_hybrid_model_s_names_file_equals_the_declared_lists(lowered):
     assert scope_reduce.scope_of(
         f"jit(train_step)/jvp({loss})/NemotronHLM.hidden/checkpoint/layer_0/"
         "norm/mul:", names) == ("checkpoint/layer_0/norm", False)
+
+
+def test_the_ling_model_s_names_file_equals_the_declared_lists(lowered):
+    """`chipbench/ling_lm_scopes.json`, the benchmark's own copy: its
+    layers are Ling-3.0-flash's own names and the ones it shares (latent
+    attention's, the expert share's, the embedding, the head), each of
+    which reaches that model's lowered step, forward and backward; its
+    phases are the first names file's."""
+    import json
+    from chipbench import scope_reduce
+    here = os.path.dirname(scope_reduce.__file__)
+    with open(os.path.join(here, "ling_lm_scopes.json")) as f:
+        names = json.load(f)
+    assert set(names["layers"]) == set(scopes.LING_LM_LAYERS) \
+        | set(scopes.LM_LAYERS) | {"embed_tokens"}
+    assert names["phases"] == scope_reduce.declared()["phases"]
+    assert set(names["kda"]) | {"mlp_dense"} == set(scopes.LING_LM_LAYERS)
+    assert set(names["mla"]) | set(names["moe"]) | {"lm_head"} \
+        == set(scopes.LM_LAYERS)
+    for group in ("kda_core", "mla_core", "moe_experts"):
+        assert names[group] == [group]
+    stacks = _stacks(lowered["ling3"])
+    for name in names["layers"]:
+        assert _holds(stacks, name), name
+    for name in ("kda_core", "kda_conv", "kda_gates", "kda_out", "mla_core",
+                 "mlp_dense", "moe_experts"):
+        assert any(s.endswith(name) and "transpose(" not in s
+                   for s in stacks), name
+        assert any(name in s and "transpose(" in s for s in stacks), name
+    # a stack of that model reduces to its layer by the file's own rules:
+    # the recurrence's scan and its groups made again sit under `kda_core`
+    loss = "LingLM.next_token_loss"
+    assert scope_reduce.scope_of(
+        f"jit(train_step)/transpose(jvp({loss}))/LingLM.hidden/"
+        f"jvp({loss})/LingLM.hidden/checkpoint/rematted_computation/"
+        "layer_0/attn/kda_core/while/body/checkpoint/dot_general:",
+        names) == ("kda_core", True)
+    assert scope_reduce.scope_of(
+        f"jit(train_step)/jvp({loss})/LingLM.hidden/checkpoint/layer_0/"
+        "input_norm/mul:", names) == ("checkpoint/layer_0/input_norm", False)
 
 
 @pytest.mark.parametrize("model", ["resnet50", "vgg16", "vit_s16"])
@@ -332,4 +379,5 @@ def test_call_sites_and_declared_lists_agree():
     assert "pool{b}" in found          # models/vgg16.py, one for each block
     found = (found - {"pool{b}"}) | {f"pool{b}" for b in range(1, 6)}
     assert found == set(scopes.PHASES) | set(scopes.LAYERS) \
-        | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS)
+        | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS) \
+        | set(scopes.LING_LM_LAYERS)
