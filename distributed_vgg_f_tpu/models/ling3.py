@@ -181,8 +181,11 @@ class KimiDeltaAttention(nn.Module):
         with jax.named_scope("kda_core"):
             o = kda.kda(q, k, v, g, beta, chunk=self.chunk_size)
             # receipts for a caller that asks (`mutable=["counters"]`): a
-            # gate stuck at its bound shows as exp(lower_bound)
+            # gate stuck at its bound shows as exp(lower_bound); and whether
+            # the recurrence took the Pallas kernels (1) or the XLA form (0)
             self.sow("counters", "kda_chunks", b * -(-t // self.chunk_size))
+            self.sow("counters", "kda_kernel", int(kda.takes_kernels(
+                k.shape, v.shape, self.chunk_size)))
             self.sow("counters", "kda_decay_min", kda.smallest_decay(g))
         with jax.named_scope("kda_out"):
             # one norm over each head's channels, one scale for all heads

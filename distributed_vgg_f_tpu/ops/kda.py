@@ -23,12 +23,13 @@ and QK the read-out's masked scores:
     S'   = Diag(exp(G_last)) S - (K_out^T W) S + K_out^T U0
     o    = (Q_in - QK W) S + QK U0
 
-so everything but S itself (A, T, W, U0, the scores, the decayed copies of q
-and k, and the four matrices above) is made for many chunks at once
-(`_within`, a group of chunks at a time, each group made again in the
-backward pass so that one group's intermediates are alive); the scan over
-the chunks (`_across`) is one small product a chunk, and the read-out is
-made for all chunks at once from the states the scan kept.
+so in the XLA form (`kda_xla`) everything but S itself (A, T, W, U0, the
+scores, the decayed copies of q and k, and the four matrices above) is made
+for many chunks at once (`_within`, a group of chunks at a time, each group
+made again in the backward pass so that one group's intermediates are
+alive); the scan over the chunks (`_across`) is one small product a chunk,
+and the read-out is made for all chunks at once from the states the scan
+kept.
 
 **The exponents.** A chunk of 64 at the gate's bound of -5 a position spans
 G = -320, and exp(-G_j) alone overflows float32 after 17 positions. So no
@@ -52,8 +53,24 @@ blocks of one position up to the chunk), in float32 at `highest`
 precision: no power of A is ever formed, so keys that repeat do not cancel
 catastrophically.
 
+Two implementations behind `kda`, chosen by what the call can observe:
+
+- the Pallas kernels of ops/kda_pallas.py, forward and hand-written
+  backward, where the chunk is 64, a head is one lane tile on both sides,
+  the heads come in pairs (`kda_pallas.applies`) and the backend is a TPU
+  (or the Pallas interpreter a test switched on): the direct form above
+  (U = T (V - (K * exp(G)) S), with S in VMEM along a sequential chunk
+  axis), the same sub-blocks, reference points and clamp, the solve by
+  forward substitution on the vector unit in float32; a chunk's scores,
+  their inverse and the decayed copies of q and k never reach HBM (PERF.md,
+  section 6, PR 35);
+- `kda_xla` everywhere else (the CPU, the tiny preset's heads of 16): the
+  affine form below as plain XLA products over groups of chunks,
+  differentiated by JAX. It is also the function the kernels are tested
+  against.
+
 `kda_recurrent` is the literal recurrence, one position at a time: what the
-tests hold `kda` against.
+tests hold both against.
 """
 
 from __future__ import annotations
@@ -71,6 +88,7 @@ SUB = 16
 #: 75 a gate at its bound reaches, under float32's 88 with room for a sum
 #: over the channels
 _CAP = SUB * -LOWER_BOUND
+#: the XLA form's only (the kernels walk a grid step's chunks one by one):
 #: chunks whose S-free parts are made (and made again) together: at the
 #: benchmark's widths on a v5e a layer's forward and backward take 119 ms
 #: with 32, 96 with 128 and 67 with 8 (PERF.md, PR 34)
@@ -192,17 +210,34 @@ def _across(parts, batch: int):
     return o.reshape(batch, chunks, *o.shape[1:])
 
 
+def takes_kernels(k_shape, v_shape, chunk: int) -> bool:
+    """Whether `kda` runs the Pallas kernels for arguments of these shapes
+    (`k`'s and `v`'s) here: shapes and backend decide, nothing else."""
+    from distributed_vgg_f_tpu.ops import kda_pallas
+    return (jax.default_backend() == "tpu" or kda_pallas.INTERPRET) \
+        and kda_pallas.applies(k_shape, v_shape, chunk)
+
+
 def kda(q, k, v, g, beta, chunk: int = 64):
     """`q`, `k` (b, t, h, dk) in the compute dtype (normalised, q scaled,
     by the caller), `v` (b, t, h, dv), `g` (b, t, h, dk) float32 in
     [`LOWER_BOUND`, 0], `beta` (b, t, h) float32. Returns o (b, t, h, dv)
     float32. `t` is a whole number of chunks (or shorter than one)."""
+    t = k.shape[1]
+    if t % min(chunk, t):
+        raise ValueError(f"{t} positions in chunks of {chunk}: a rest of a "
+                         "chunk is left")
+    if takes_kernels(k.shape, v.shape, chunk):
+        from distributed_vgg_f_tpu.ops import kda_pallas
+        return kda_pallas.chunked(q, k, v, g, beta)
+    return kda_xla(q, k, v, g, beta, chunk)
+
+
+def kda_xla(q, k, v, g, beta, chunk: int = 64):
+    """`kda` as plain XLA products, differentiated by JAX."""
     b, t, h, dk = k.shape
     dv = v.shape[-1]
     c = min(chunk, t)
-    if t % c:
-        raise ValueError(f"{t} positions in chunks of {chunk}: a rest of a "
-                         "chunk is left")
     n = b * (t // c)
     group = math.gcd(n, GROUP_CHUNKS)
     split = lambda x: x.reshape(n // group, group, c, *x.shape[2:])

@@ -39,7 +39,8 @@ NAMESPACE_HELP = {
     "ssm": "language-model state-space layers (chunks scanned a step, "
            "smallest decay of any layer, layers on the Pallas kernels)",
     "kda": "language-model delta-rule linear-attention layers (chunks a "
-           "step, smallest decay of any layer)",
+           "step, smallest decay of any layer, layers on the Pallas "
+           "kernels)",
     "eval": "trainer evaluation passes",
     "distributed": "cross-process coordination barriers",
     "telemetry": "the telemetry registry itself (poller faults)",

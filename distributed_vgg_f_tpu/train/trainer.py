@@ -1124,15 +1124,21 @@ class Trainer:
                             # the delta-rule layers' receipts and the group
                             # limit's (models/ling3.py): chunks a step, the
                             # smallest decay of any layer (exp(-5): a gate
-                            # at its bound), and the mean share of tokens
-                            # whose kept groups include this share's
+                            # at its bound), the layers whose recurrence
+                            # ran as the Pallas kernels
+                            # (ops/kda_pallas.py), and the mean share of
+                            # tokens whose kept groups include this share's
                             decays = [v for k, v in last_metrics.items()
                                       if k.startswith("kda_decay_min/")]
                             if decays:
                                 reg.set_gauge("kda/decay_min", min(decays))
-                                reg.set_gauge("kda/chunks", sum(
+                                over_layers = lambda metric: sum(
                                     v for k, v in last_metrics.items()
-                                    if k.startswith("kda_chunks/")))
+                                    if k.startswith(metric))
+                                reg.set_gauge("kda/chunks",
+                                              over_layers("kda_chunks/"))
+                                reg.set_gauge("kda/kernel_layers",
+                                              over_layers("kda_kernel/"))
                             shares = [v for k, v in last_metrics.items()
                                       if k.startswith("moe_group_share/")]
                             if shares:

@@ -285,17 +285,21 @@ def test_expert_share_moves_only_the_rows_its_buffers_hold(chip):
         f"(PR 30's tree) this function needed 0.690 GiB, with PR 31's 0.578")
 
 
-def test_delta_rule_core_compiles_at_the_cell_s_widths(chip):
+def test_delta_rule_core_compiles_at_the_cell_s_widths(chip, monkeypatch):
     """`ops/kda.py` at `ling3_flash_ep64`'s widths (2 x 8,192 positions, 32
     heads of 128 on both sides, chunks of 64, bf16) with its five
-    gradients: plain XLA products (a kernel is a later PR's), no array of
-    tokens x chunk x channels x heads (`16384 x 64 x 128 x 32`, 4.3 G
-    elements: the pairwise decays of a chunk) or of a sixteenth of it (the
-    sub-blocks' own) in any dtype or order, and the temporaries at its
-    fullest under 2.0 GiB (1.89 as compiled: the states entering each
-    chunk in float32 0.5 GiB, the chunks' maps of the state and read-outs
-    1.4, their cotangents)."""
+    gradients, as a TPU's trace lowers it: the two Pallas kernels of
+    ops/kda_pallas.py; no array with a chunk's 64 x 64 (the scores, their
+    inverse: `[2,128,32,64,64]`, 33.5 M elements a kind) in any dtype; no
+    heads-major copy of q, k, v or g (no array in which the 32 heads stand
+    before a chunk's 64 or a sequence's 8,192 positions, and no layout of
+    `[2,8192,32,128]` but the row-major one); the states entering each
+    chunk once, float32 `[2,128,32,128,128]`; and the temporaries at its
+    fullest under 1.5 GiB (1.38 as compiled: those states 0.5 GiB, the
+    five arguments as (b, t, 4096) 0.67, which in the model are what the
+    mixer has, and o's cotangent; the XLA form needed 1.89)."""
     from distributed_vgg_f_tpu.ops import kda
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     b, t, h, d = 2, 8192, 32, 128
     arg = lambda shape, kind: jax.ShapeDtypeStruct(shape, kind, sharding=chip)
 
@@ -307,14 +311,23 @@ def test_delta_rule_core_compiles_at_the_cell_s_widths(chip):
         arg((b, t, h, d), jnp.bfloat16), arg((b, t, h, d), jnp.float32),
         arg((b, t, h), jnp.float32)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    pairwise = sorted({kind for kind in re.findall(r"\w+\[[\d,]+\]", text)
-                       if _elements(kind) >= b * t * 16 * d * h})
-    assert not pairwise, pairwise
+    assert len(re.findall(r"= .*custom-call.*tpu_custom_call", text)) == 2
+    kinds = set(re.findall(r"\w+\[[\d,]+\]", text))
+    square = sorted(kind for kind in kinds if re.search(r"[\[,]64,64[,\]]",
+                                                       kind))
+    assert not square, square
+    heads_major = sorted(kind for kind in kinds
+                         if re.search(r"[\[,]32,(64|8192),", kind))
+    assert not heads_major, heads_major
+    turned = sorted(set(re.findall(
+        r"\w+\[2,8192,32,128\]\{(?!3,2,1,0)[\d,]+", text)))
+    assert not turned, turned
+    states = {kind for kind in kinds if _elements(kind) >= b * t * h * d * 2}
+    assert states == {"f32[2,128,32,128,128]"}, states
     memory = compiled.memory_analysis()
     temporaries = (memory.peak_memory_in_bytes - memory.argument_size_in_bytes
                    - memory.output_size_in_bytes) / 2 ** 30
-    assert temporaries < 2.0, f"{temporaries:.2f} GiB of temporaries"
+    assert temporaries < 1.5, f"{temporaries:.2f} GiB of temporaries"
 
 
 def test_latent_core_at_two_head_sizes_compiles_for_v5e(chip):

@@ -355,6 +355,47 @@ def test_scan_kernels_keep_the_name_ssm_scan(monkeypatch):
                for stack in re.findall(r'loc\("([^"]*)"', text))
 
 
+def test_delta_rule_kernels_keep_the_name_kda_core(monkeypatch):
+    """Ling's train step at the smallest sizes the delta rule's Pallas
+    kernels take (ops/kda_pallas.py: heads of 128 in pairs, two chunks of 64),
+    lowered for a TPU. As `ssd_pallas.scan`, the op is one jitted function
+    that JAX lowers once for the six KDA layers: three kernels in all (the
+    forward, the block's recomputed forward that saves the entering
+    states, the hand-written backward), each under the `kda_core` the
+    function opens itself, so the benchmark's reader gives all three to
+    that row: `kda_pct` and `kda_core_roofline_pct` keep reading it."""
+    import json
+    from chipbench import scope_reduce
+    cfg = apply_overrides(get_config("ling3_flash_tiny"), {
+        "model.extra.num_attention_heads": 4, "model.extra.head_dim": 128})
+    seq = cfg.model.extra["seq_len"]
+    mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
+                      jax.devices()[:1])
+    trainer = Trainer(cfg, mesh=mesh, logger=MetricLogger(stream=io.StringIO()))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (cfg.data.global_batch_size, seq + 1), jnp.int32)}
+    state, rng = jax.eval_shape(trainer.init_state), trainer.base_rng()
+    # `kda.kda` and the attention core ask the backend: a TPU's trace
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = trainer.train_step.__wrapped__.trace(state, batch, rng).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    monkeypatch.undo()
+    with open(os.path.join(os.path.dirname(scope_reduce.__file__),
+                           "ling_lm_scopes.json")) as f:
+        names = json.load(f)
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = [named[loc] for loc in re.findall(
+        r"custom_call @tpu_custom_call.*loc\((#loc\d+)\)", text)]
+    core = sorted(call for call in calls if "mla_core" not in call)
+    assert core == ["kda_core/kda_core/pallas_call", "kda_core/pallas_call",
+                    "kda_core/pallas_call"], calls
+    assert {scope_reduce.scope_of(call + ":", names)[0]
+            for call in core} == {"kda_core"}
+    # the function is called under the layer's own `kda_core` too
+    assert any(stack.endswith("layer_0/attn/kda_core/jit(chunked)")
+               for stack in re.findall(r'loc\("([^"]*)"', text))
+
+
 def test_jitted_steps_are_named_for_what_they_are(lowered):
     """The module's name is what a trace's `XLA Modules` line shows and,
     unlike the scopes, part of the persistent compile cache's key."""
