@@ -12,7 +12,6 @@ import ctypes
 import logging
 import os
 import threading
-import time
 from typing import Iterator, Mapping, Optional
 
 import numpy as np
@@ -143,15 +142,13 @@ class NativeBatchIterator:
             # one copy total
             images = np.empty(self._shape, np.float32)
             labels = np.empty((self.batch_size,), np.int32)
-        t0 = time.monotonic_ns()
-        self._lib.dvgg_loader_next(
-            self._handle,
-            images.ctypes.data_as(_F32P),
-            labels.ctypes.data_as(_I32P))
         # per-BATCH, not per-image: the time blocked on the native
         # double-buffer is the loader's contribution to an infeed stall
-        telemetry.record("native_loader_next", "infeed_source", t0,
-                         time.monotonic_ns() - t0)
+        with telemetry.span("native_loader_next", "infeed_source"):
+            self._lib.dvgg_loader_next(
+                self._handle,
+                images.ctypes.data_as(_F32P),
+                labels.ctypes.data_as(_I32P))
         telemetry.inc("native_loader/batches")
         return {"image": images, "label": labels}
 

@@ -47,6 +47,11 @@ class _WaitTimeout(Exception):
     retries-exhausted DataStallError)."""
 
 
+#: what a worker's draw returns from a source that has ended (the draw is a
+#: span like every other: the worker waited for it)
+_EXHAUSTED = object()
+
+
 class DevicePrefetchIterator:
     """Wraps a host-batch iterator; yields mesh-sharded device batches.
 
@@ -124,13 +129,10 @@ class DevicePrefetchIterator:
                 # the worker's own source wait is "infeed_source": it shows
                 # WHERE the pipeline starves (host loader vs H2D) without
                 # double-counting against the consumer-side "infeed" spans
-                t0 = time.monotonic_ns()
-                try:
-                    host_batch = next(source)
-                except StopIteration:
+                with rec.span("source_next", "infeed_source"):
+                    host_batch = next(source, _EXHAUSTED)
+                if host_batch is _EXHAUSTED:
                     break
-                rec.record("source_next", "infeed_source", t0,
-                           time.monotonic_ns() - t0)
                 reg.inc("prefetch/source_batches")
                 if self._closed.is_set():
                     return
@@ -140,11 +142,9 @@ class DevicePrefetchIterator:
                 # columns corroborate against)
                 nbytes = sum(int(np.asarray(v).nbytes)
                              for v in host_batch.values())
-                t0 = time.monotonic_ns()
-                device_batch = shard_host_batch(host_batch, self._mesh,
-                                                self._data_axis)
-                rec.record("device_put", "infeed_source", t0,
-                           time.monotonic_ns() - t0)
+                with rec.span("device_put", "infeed_source"):
+                    device_batch = shard_host_batch(host_batch, self._mesh,
+                                                    self._data_axis)
                 reg.inc("prefetch/device_put_bytes", nbytes)
                 # count the bytes BEFORE the queue put: the consumer may
                 # dequeue (and decrement) the instant the put lands, and a
@@ -212,46 +212,47 @@ class DevicePrefetchIterator:
     def __next__(self):
         if self._closed.is_set():
             raise StopIteration
-        t_wait = time.monotonic_ns()
-        if self._batch_timeout <= 0:
-            item = self._get(None)
-        else:
-            timeout, waited = self._batch_timeout, 0.0
-            for attempt in range(self._timeout_retries + 1):
-                try:
-                    item = self._get(timeout)
-                    break
-                except _WaitTimeout:
-                    telemetry.inc("prefetch/timeouts")
-                    waited += timeout
-                    timeout *= 2  # exponential backoff between retries
+        # "infeed" category = time the CONSUMER was blocked here — the
+        # direct input to the stall attributor's infeed_fraction (a wait
+        # that ends in the stream's end or an error is a span too)
+        with telemetry.span("prefetch_wait", "infeed") as wait:
+            if self._batch_timeout <= 0:
+                item = self._get(None)
             else:
-                telemetry.inc("resilience/data_stall_errors")
-                from distributed_vgg_f_tpu.telemetry import flight
-                flight.note_crash(
-                    "data_stall",
-                    f"watchdog timeout: no batch within {waited:.1f}s "
-                    f"across {self._timeout_retries + 1} attempts "
-                    f"({self._batches_delivered} batches delivered)")
-                raise DataStallError(
-                    f"input pipeline stalled: no batch within {waited:.1f}s "
-                    f"across {self._timeout_retries + 1} watchdog attempts "
-                    f"(train.data_timeout_s={self._batch_timeout}, "
-                    f"exponential backoff; {self._batches_delivered} batches "
-                    f"delivered before the stall). The host loader is hung "
-                    f"or severely underprovisioned — check storage/decode "
-                    f"workers, or raise train.data_timeout_s if this "
-                    f"pipeline is legitimately this slow.") from None
+                timeout, waited = self._batch_timeout, 0.0
+                for attempt in range(self._timeout_retries + 1):
+                    try:
+                        item = self._get(timeout)
+                        break
+                    except _WaitTimeout:
+                        telemetry.inc("prefetch/timeouts")
+                        waited += timeout
+                        timeout *= 2  # exponential backoff between retries
+                else:
+                    telemetry.inc("resilience/data_stall_errors")
+                    from distributed_vgg_f_tpu.telemetry import flight
+                    flight.note_crash(
+                        "data_stall",
+                        f"watchdog timeout: no batch within {waited:.1f}s "
+                        f"across {self._timeout_retries + 1} attempts "
+                        f"({self._batches_delivered} batches delivered)")
+                    raise DataStallError(
+                        f"input pipeline stalled: no batch within "
+                        f"{waited:.1f}s across {self._timeout_retries + 1} "
+                        f"watchdog attempts "
+                        f"(train.data_timeout_s={self._batch_timeout}, "
+                        f"exponential backoff; {self._batches_delivered} "
+                        f"batches delivered before the stall). The host "
+                        f"loader is hung or severely underprovisioned — "
+                        f"check storage/decode workers, or raise "
+                        f"train.data_timeout_s if this pipeline is "
+                        f"legitimately this slow.") from None
         kind, payload = item[0], item[1]
         if kind == "batch":
             self._batches_delivered += 1
-            # "infeed" category = time the CONSUMER was blocked here — the
-            # direct input to the stall attributor's infeed_fraction
-            dt = time.monotonic_ns() - t_wait
-            telemetry.record("prefetch_wait", "infeed", t_wait, dt)
             reg = telemetry.get_registry()
             reg.inc("prefetch/batches")
-            reg.inc("prefetch/wait_ns", dt)
+            reg.inc("prefetch/wait_ns", wait.dur_ns)
             reg.set_gauge("prefetch/queue_depth", self._queue.qsize())
             # clamped like the producer's rollback: a concurrent close()
             # (teardown, watchdog, __del__) may already have zeroed the
@@ -372,13 +373,10 @@ class HostPrefetchIterator:
         try:
             source = iter(self._source)
             while not self._closed.is_set():
-                t0 = time.monotonic_ns()
-                try:
-                    batch = next(source)
-                except StopIteration:
+                with rec.span("host_prefetch_next", "infeed_source"):
+                    batch = next(source, _EXHAUSTED)
+                if batch is _EXHAUSTED:
                     break
-                rec.record("host_prefetch_next", "infeed_source", t0,
-                           time.monotonic_ns() - t0)
                 reg.inc("prefetch/host_batches")
                 if not self._put(("batch", batch)):
                     return

@@ -54,3 +54,25 @@ HYBRID_LM_LAYERS = ("ssm_in", "ssm_conv", "ssm_scan", "ssm_gate_out",
 #: traces are reduced by a fourth file, `chipbench/ling_lm_scopes.json`.
 LING_LM_LAYERS = ("kda_qkv", "kda_conv", "kda_gates", "kda_core", "kda_out",
                   "mlp_dense")
+
+# ---------------------------------------------------------------------------
+# Host spans of set-up: names on the telemetry ring (`telemetry.span`), not
+# on the device. `chipbench/host_spans.json` is the benchmark's copy, which
+# its `setup_*` readers cut the ring by (`chipbench/layer_metrics/
+# _startup.py`); `tests/test_scopes.py` holds the call sites to these lists
+# and `tests/chipbench/test_startup_metrics.py` the copy.
+# ---------------------------------------------------------------------------
+
+#: Category `startup` (train/trainer.py): the trainer module's import chain,
+#: all of `Trainer.__init__`, its children, and `Trainer.init_state`.
+STARTUP_SPANS = ("import_trainer", "trainer_init", "distributed_init",
+                 "build_model", "build_optimizer", "plan_exchange",
+                 "build_steps", "init_state")
+
+#: The children among them: each lies inside `trainer_init`, on its thread.
+TRAINER_INIT_CHILDREN = ("distributed_init", "build_model",
+                         "build_optimizer", "plan_exchange", "build_steps")
+
+#: Category `compile` (telemetry/compile_events.py): a span is named
+#: `<stage>:<fun_name>` for one of these stages of JAX's own events.
+COMPILE_SPANS = ("trace", "lower", "backend", "cache_read")

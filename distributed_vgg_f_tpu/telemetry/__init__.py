@@ -37,7 +37,6 @@ tests/test_telemetry.py pins this in a subprocess.
 
 from __future__ import annotations
 
-import time
 from typing import Callable, Iterator, Optional
 
 from distributed_vgg_f_tpu.telemetry import schema  # noqa: F401 (re-export)
@@ -52,6 +51,7 @@ from distributed_vgg_f_tpu.telemetry.spans import (
     SpanRecorder,
     get_process_label,
     get_recorder,
+    process_start_ns,
     record,
     set_process_label,
     span,
@@ -67,7 +67,8 @@ __all__ = [
     "SpanRecorder", "TelemetryRegistry", "StallAttributor", "VERDICTS",
     "classify", "configure", "enabled", "get_process_label",
     "get_recorder", "get_registry", "inc", "instrument_iterator",
-    "occupancy_from_spans", "record", "register_poller", "reset", "schema",
+    "occupancy_from_spans", "process_start_ns", "record",
+    "register_poller", "reset", "schema",
     "set_gauge", "set_process_label", "span",
 ]
 
@@ -109,39 +110,39 @@ def instrument_iterator(source: Iterator, name: str = "next_batch",
                         category: str = "infeed",
                         counter: str = "prefetch/batches") -> Iterator:
     """Wrap a batch iterator with the per-batch telemetry the trainer's
-    FULL feed path performs, op-for-op: the prefetch worker's two spans +
-    source counter + queue-depth gauge, the consumer's wait span + batch/
-    wait counters + queue-depth gauge, and the trainer loop's own infeed
-    span + step-dispatch span/counter — 5 span records, 4 counter
-    increments, 2 gauge sets per batch (data/prefetch.py + trainer loop +
-    step wrapper). This is the instrumented side of the bench's
-    telemetry-on-vs-off overhead receipt
+    FULL feed path performs, op-for-op and through the same `span(...)`
+    calls: the prefetch worker's two spans + source counter + queue-depth
+    gauge, the consumer's wait span + batch/wait counters + queue-depth
+    gauge, and the trainer loop's own infeed span + step-dispatch
+    span/counter — 5 spans, 4 counter increments, 2 gauge sets per batch
+    (data/prefetch.py + trainer loop + step wrapper). This is the
+    instrumented side of the bench's telemetry-on-vs-off overhead receipt
     (benchmarks/host_pipeline_bench.py): the receipt must charge the 'on'
     column AT LEAST what training pays, never a lighter stand-in."""
     rec = get_recorder()
     reg = get_registry()
     it = iter(source)
     base = counter.rsplit("/", 1)[0]
+    end = object()
     while True:
-        t0 = time.monotonic_ns()
-        try:
-            batch = next(it)
-        except StopIteration:
-            return
-        dt = time.monotonic_ns() - t0
-        # worker side (prefetch.py _worker): source draw + device put
-        rec.record("source_next", "infeed_source", t0, dt)
-        rec.record("device_put", "infeed_source", t0 + dt, 0)
-        reg.inc(f"{base}/source_batches")
-        reg.set_gauge(f"{base}/queue_depth", 1)
-        # consumer side (prefetch.py __next__)
-        rec.record("prefetch_wait", category, t0, dt)
-        reg.inc(counter)
-        reg.inc(f"{base}/wait_ns", dt)
-        reg.set_gauge(f"{base}/queue_depth", 0)
-        # trainer loop's own infeed span + the jitted-step dispatch
-        # wrapper (train/step.py)
-        rec.record(name, category, t0, dt)
-        rec.record("train_step_dispatch", "dispatch", t0 + dt, 0)
+        # trainer loop's own infeed span, around the consumer's wait
+        # (prefetch.py __next__), around the worker's source draw + device
+        # put (prefetch.py _worker; nothing is put here)
+        with rec.span(name, category):
+            with rec.span("prefetch_wait", category) as wait:
+                with rec.span("source_next", "infeed_source"):
+                    batch = next(it, end)
+                if batch is end:
+                    return
+                with rec.span("device_put", "infeed_source"):
+                    pass
+                reg.inc(f"{base}/source_batches")
+                reg.set_gauge(f"{base}/queue_depth", 1)
+            reg.inc(counter)
+            reg.inc(f"{base}/wait_ns", wait.dur_ns)
+            reg.set_gauge(f"{base}/queue_depth", 0)
+        # the jitted-step dispatch wrapper (train/step.py)
+        with rec.span("train_step_dispatch", "dispatch"):
+            pass
         reg.inc("step/dispatched")
         yield batch

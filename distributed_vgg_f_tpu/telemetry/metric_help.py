@@ -41,6 +41,10 @@ NAMESPACE_HELP = {
     "kda": "language-model delta-rule linear-attention layers (chunks a "
            "step, smallest decay of any layer, layers on the Pallas "
            "kernels)",
+    "compile": "JAX's own compile events (traces, lowerings, backend "
+               "compiles, persistent-cache reads, hits and misses)",
+    "startup": "where set-up starts (the process's start on the span "
+               "ring's clock)",
     "eval": "trainer evaluation passes",
     "distributed": "cross-process coordination barriers",
     "telemetry": "the telemetry registry itself (poller faults)",

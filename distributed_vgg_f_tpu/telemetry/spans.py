@@ -248,6 +248,22 @@ def get_recorder() -> SpanRecorder:
     return _default
 
 
+def process_start_ns() -> Optional[int]:
+    """When this process started, on the ring's clock (`monotonic_ns`), to
+    the kernel's clock tick (10 ms). Linux: field 22 of `/proc/self/stat`,
+    in ticks since boot, against `CLOCK_BOOTTIME` now. None where either
+    is missing."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command's name (field 2) may hold spaces: count from its ")"
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        since_boot_ns = ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+        age_ns = time.clock_gettime_ns(time.CLOCK_BOOTTIME) - since_boot_ns
+    except (OSError, AttributeError, ValueError, IndexError):
+        return None
+    return time.monotonic_ns() - age_ns
+
+
 def span(name: str, category: str = "host") -> _Span:
     """`with spans.span("next_batch", "infeed"):` on the default recorder."""
     return _default.span(name, category)

@@ -399,19 +399,21 @@ def build_train_step(model, mesh: Mesh, weight_decay: float,
     # of the device). The wrapper keeps `.lower` (bench.py AOT-compiles the
     # step) and is a plain passthrough when telemetry is disabled.
     from distributed_vgg_f_tpu import telemetry
+    static_gauges_set = False
 
     @functools.wraps(jitted)
     def dispatch(state, batch, rng):
+        nonlocal static_gauges_set
         rec = telemetry.get_recorder()
         if not rec.enabled:
             return jitted(state, batch, rng)
         with rec.span("train_step_dispatch", "dispatch"):
             out = jitted(state, batch, rng)
         telemetry.inc("step/dispatched")
-        # comm/* receipts (ISSUE 11): per-step exchange counters + the
-        # static exchange-shape gauges, single-sourced from the geometry
-        # the trace actually used (comm_meta fills on first trace, so the
-        # first dispatch already sees it)
+        # comm/* receipts (ISSUE 11): per-step exchange counters,
+        # single-sourced from the geometry the trace actually used
+        # (comm_meta fills on first trace, so the first dispatch already
+        # sees it)
         if comm_meta:
             telemetry.inc("comm/exchanges")
             telemetry.inc("comm/wire_bytes", comm_meta["wire_bytes"])
@@ -422,11 +424,16 @@ def build_train_step(model, mesh: Mesh, weight_decay: float,
                 telemetry.inc("comm/gathers", comm_meta["gathers"])
                 telemetry.inc("comm/gather_wire_bytes",
                               comm_meta["gather_bytes"])
+        # what the trace fixed for good (the exchange's shape, the LRN
+        # sites by kind): gauges, set by the first dispatch that recorded
+        if not static_gauges_set:
+            static_gauges_set = True
             reg = telemetry.get_registry()
-            reg.set_gauge("comm/buckets_per_step", comm_meta["buckets"])
-            reg.set_gauge("comm/bucket_mb", comm_meta["bucket_mb"])
-        for kind, count in lrn_sites.items():
-            telemetry.get_registry().set_gauge(f"lrn/{kind}_sites", count)
+            if comm_meta:
+                reg.set_gauge("comm/buckets_per_step", comm_meta["buckets"])
+                reg.set_gauge("comm/bucket_mb", comm_meta["bucket_mb"])
+            for kind, count in lrn_sites.items():
+                reg.set_gauge(f"lrn/{kind}_sites", count)
         return out
 
     dispatch.lower = jitted.lower

@@ -8,8 +8,13 @@ drives eval/checkpoint cadence (SURVEY.md §3.1 TPU mapping).
 
 from __future__ import annotations
 
-import os
 import time
+
+# the start of the `startup:import_trainer` span: the imports below are
+# what it times
+_IMPORT_T0_NS = time.monotonic_ns()
+
+import os
 from typing import Iterator, Mapping, Optional
 
 import jax
@@ -44,6 +49,17 @@ from distributed_vgg_f_tpu.train.step import build_eval_step, build_train_step
 from distributed_vgg_f_tpu.utils.logging import MetricLogger
 from distributed_vgg_f_tpu.utils.meter import ThroughputMeter
 
+# Set-up as the program sees it (PERF.md section 3): what this module's
+# imports took (jax, flax, optax, orbax, data, models, checkpoint; less
+# whatever the caller had imported already), recorded after the fact
+# because the recorder is among them, and where the process started on the
+# ring's clock (absent off Linux).
+telemetry.record("import_trainer", "startup", _IMPORT_T0_NS,
+                 time.monotonic_ns() - _IMPORT_T0_NS)
+_PROCESS_START_NS = telemetry.process_start_ns()
+if _PROCESS_START_NS is not None:
+    telemetry.set_gauge("startup/process_start_ns", _PROCESS_START_NS)
+
 
 # Monotone counter naming each alignment barrier: every process creates
 # Trainers and calls fit/evaluate in the same program order, so the n-th
@@ -77,16 +93,23 @@ def _align_cold_start() -> None:
 class Trainer:
     def __init__(self, cfg: ExperimentConfig, mesh=None,
                  logger: Optional[MetricLogger] = None):
-        initialize_distributed()
-        self.cfg = cfg
         # Telemetry spine (telemetry/): configure the process-wide recorder
         # and registry from config BEFORE anything records — the wired call
         # sites (prefetch, checkpoint manager, guards) all write to the
-        # defaults this flips.
+        # defaults this flips, and the `startup` spans below are the first.
         telemetry.configure(enabled=cfg.telemetry.enabled,
                             span_capacity=cfg.telemetry.span_capacity,
                             flight_windows=cfg.telemetry.flight_windows,
                             annotate=jax.profiler.TraceAnnotation)
+        with telemetry.span("trainer_init", "startup"):
+            self._init(cfg, mesh, logger)
+
+    def _init(self, cfg: ExperimentConfig, mesh,
+              logger: Optional[MetricLogger]) -> None:
+        """All of construction, under the `startup:trainer_init` span; the
+        `startup` spans inside it are its children (scopes.STARTUP_SPANS),
+        and what none of them covers is its self time."""
+        self.cfg = cfg
         if cfg.data.space_to_depth and not supports_space_to_depth(
                 cfg.model.name, cfg.data.image_size, cfg.data.name):
             # the packed layout is the VGG-F stem's input contract
@@ -99,18 +122,23 @@ class Trainer:
                 f"(got model={cfg.model.name!r}, "
                 f"image_size={cfg.data.image_size}, "
                 f"dataset={cfg.data.name!r})")
-        self.mesh = mesh if mesh is not None else build_mesh(
-            MeshSpec((cfg.mesh.data_axis,), (cfg.mesh.num_data,)))
+        with telemetry.span("distributed_init", "startup"):
+            initialize_distributed()
+            self.mesh = mesh if mesh is not None else build_mesh(
+                MeshSpec((cfg.mesh.data_axis,), (cfg.mesh.num_data,)))
         self.data_axis = cfg.mesh.data_axis
-        self.model = build_model(cfg.model)
+        with telemetry.span("build_model", "startup"):
+            self.model = build_model(cfg.model)
         # what a batch of this model is (models/ingest.py): "image" or
         # "tokens". It picks the sample input, the data source's arguments
         # and the step's prologue, loss and metrics
         from distributed_vgg_f_tpu.models.ingest import ingest_descriptor
         self.batch_kind = ingest_descriptor(cfg.model.name).kind
-        self.tx, self.schedule = build_optimizer(cfg)
+        with telemetry.span("build_optimizer", "startup"):
+            self.tx, self.schedule = build_optimizer(cfg)
         self._replicated = NamedSharding(self.mesh, P())
-        self._plan_exchange()
+        with telemetry.span("plan_exchange", "startup"):
+            self._plan_exchange()
         # Device-finish prologue (data/device_ingest.py, data.wire='u8'):
         # normalize/cast/space-to-depth for uint8-wire batches, fused into
         # the jitted steps. Installed UNCONDITIONALLY — it dispatches on
@@ -143,7 +171,8 @@ class Trainer:
         if self.batch_kind == "tokens":
             # no pixels: no finish and no augmentation to install
             self.device_finish = self.device_augment = None
-        self._build_steps()
+        with telemetry.span("build_steps", "startup"):
+            self._build_steps()
         self.logger = logger or MetricLogger()
         # Live observability endpoint (telemetry/exporter.py): one
         # process-wide HTTP server (/metrics /healthz /stallz /trace),
@@ -340,15 +369,17 @@ class Trainer:
     def init_state(self, rng: jax.Array | None = None) -> TrainState:
         """Initialize params on-device: replicated over the mesh, except
         what the exchange shards over the data axis."""
-        rng = rng if rng is not None else jax.random.key(self.cfg.train.seed)
-        sample = self._sample_input()
-
         def init_fn(rng):
             return TrainState.create(self.model, self.tx, rng, sample,
                                      ema=self.cfg.train.ema_decay > 0.0,
                                      exchange=self.exchange)
 
-        return jax.jit(init_fn, out_shardings=self._state_sharding())(rng)
+        with telemetry.span("init_state", "startup"):
+            rng = rng if rng is not None \
+                else jax.random.key(self.cfg.train.seed)
+            sample = self._sample_input()
+            return jax.jit(init_fn,
+                           out_shardings=self._state_sharding())(rng)
 
     def _make_best_manager(self) -> CheckpointManager:
         """The single-slot best-eval manager under <checkpoint_dir>/best.
@@ -473,10 +504,14 @@ class Trainer:
         # multi-process (device_put to non-addressable devices does not).
         # The dropout key uses the configured PRNG impl ("rbg" by default —
         # much cheaper random bits on TPU than threefry; see TrainConfig).
-        seed = self.cfg.train.seed + 1
+        # The seed is an argument (its low 32 bits, which is what
+        # `jax.random.key` keeps of a Python int), not a constant of the
+        # program: one program for every seed, so a new seed is no compile
+        # and no new entry in the persistent cache.
+        seed = np.uint32((self.cfg.train.seed + 1) & 0xFFFFFFFF)
         impl = self.cfg.train.dropout_rng_impl
-        return jax.jit(lambda: jax.random.key(seed, impl=impl),
-                       out_shardings=self._replicated)()
+        return jax.jit(lambda s: jax.random.key(s, impl=impl),
+                       out_shardings=self._replicated)(seed)
 
     # ------------------------------------------------------------------ data
     def make_dataset(self, split: str = "train", data_cfg=None) -> Iterator:
@@ -1698,35 +1733,33 @@ class Trainer:
         totals = {"top1": 0, "top5": 0, "count": 0}
         _align_cold_start()
         t0 = time.monotonic()
-        t0_ns = time.monotonic_ns()
 
         def accumulate(batch):
             counts = jax.device_get(self.eval_step(state, self.shard(batch)))
             for k in totals:
                 totals[k] += int(counts[k])
 
-        if num_batches is None and getattr(dataset, "is_finite", False):
-            it = iter(dataset)
-            exhausted = False
-            while True:
-                batch = None
-                if not exhausted:
-                    batch = next(it, None)
-                    exhausted = batch is None
-                if not self._any_host_has_data(not exhausted):
-                    break
-                accumulate(batch if batch is not None
-                           else dataset.padding_batch())
-        else:
-            if num_batches is None:
-                num_batches = max(1, cfg.data.num_eval_examples
-                                  // cfg.data.global_batch_size)
-            it = iter(dataset)
-            for _ in range(num_batches):
-                accumulate(next(it))
+        with telemetry.span("eval_pass", "eval"):
+            if num_batches is None and getattr(dataset, "is_finite", False):
+                it = iter(dataset)
+                exhausted = False
+                while True:
+                    batch = None
+                    if not exhausted:
+                        batch = next(it, None)
+                        exhausted = batch is None
+                    if not self._any_host_has_data(not exhausted):
+                        break
+                    accumulate(batch if batch is not None
+                               else dataset.padding_batch())
+            else:
+                if num_batches is None:
+                    num_batches = max(1, cfg.data.num_eval_examples
+                                      // cfg.data.global_batch_size)
+                it = iter(dataset)
+                for _ in range(num_batches):
+                    accumulate(next(it))
         n = max(1, totals["count"])
-        telemetry.record("eval_pass", "eval", t0_ns,
-                         time.monotonic_ns() - t0_ns)
         telemetry.inc("eval/passes")
         result = {"eval_top1": totals["top1"] / n, "eval_top5": totals["top5"] / n,
                   "eval_examples": totals["count"],

@@ -9,6 +9,11 @@ no timestamp.
 - `JAX_COMPILATION_CACHE_DIR` set: JAX already keeps its cache there (it
   reads the variable at import) and this function sets nothing.
 - unset: one fixed, git-ignored directory at the root of the checkout.
+
+On both paths it also hands `jax.monitoring` to the telemetry package's
+compile listeners (`telemetry/compile_events.py`), once a process: what JAX
+traces, lowers, compiles and reads from this cache becomes `compile` spans
+and `compile/*` counters.
 """
 
 from __future__ import annotations
@@ -27,10 +32,13 @@ def enable_compile_cache(subdir: str = "") -> str:
     the in-checkout default (the tests key XLA:CPU entries, which are
     machine code, by the host's CPU features); it is ignored when the
     environment variable places the cache."""
+    import jax
+
+    from distributed_vgg_f_tpu.telemetry import compile_events
+    compile_events.install(jax.monitoring)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
     path = os.path.join(DEFAULT_CACHE_DIR, subdir) if subdir \
         else DEFAULT_CACHE_DIR
     jax.config.update("jax_compilation_cache_dir", path)

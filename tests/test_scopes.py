@@ -422,3 +422,23 @@ def test_call_sites_and_declared_lists_agree():
     assert found == set(scopes.PHASES) | set(scopes.LAYERS) \
         | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS) \
         | set(scopes.LING_LM_LAYERS)
+
+
+def test_host_span_call_sites_and_declared_lists_agree():
+    """Set-up's host spans (PR 36), held as the device names are: every
+    `startup` span of the lists has a `span(...)` or `record(...)` call
+    site and no call site opens another, and the stages the compile
+    listener names its spans by are `COMPILE_SPANS`."""
+    startup, stages = set(), set()
+    for folder, _, files in os.walk(PACKAGE):
+        for file in files:
+            if file.endswith(".py"):
+                with open(os.path.join(folder, file)) as f:
+                    text = f.read()
+                startup |= set(re.findall(
+                    r'(?:span|record)\("([a-z_]+)", "startup"', text))
+                stages |= set(re.findall(
+                    r'record\(f"([a-z_]+):\{fun_name\}", "compile"', text))
+    assert startup == set(scopes.STARTUP_SPANS)
+    assert stages == set(scopes.COMPILE_SPANS)
+    assert set(scopes.TRAINER_INIT_CHILDREN) < set(scopes.STARTUP_SPANS)

@@ -18,7 +18,7 @@ import jax
 import numpy as np
 import pytest
 
-from distributed_vgg_f_tpu import telemetry
+from distributed_vgg_f_tpu import scopes, telemetry
 from distributed_vgg_f_tpu.config import (
     DataConfig,
     ExperimentConfig,
@@ -515,6 +515,364 @@ def test_counter_table_matches_runtime(devices8):
         f"entries): {stale}")
 
 
+# ------------------------------------------------- JAX's compile events
+# (telemetry/compile_events.py; conftest's `enable_compile_cache` installed
+# the listeners, which are global to the process and outlive every test)
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND = "/jax/core/compile/backend_compile_duration"
+CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+CACHE_HIT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS = "/jax/compilation_cache/cache_misses"
+
+
+def _compile_spans(name=None):
+    return [s for s in telemetry.get_recorder().snapshot()
+            if s[1] == "compile" and (name is None or s[0] == name)]
+
+
+def _compile_counters():
+    return {k: v for k, v in telemetry.get_registry().snapshot().items()
+            if k.startswith("compile/")}
+
+
+def _raise_stage(event, seconds, fun_name):
+    """One of JAX's stage events, through JAX's own dispatcher, ending
+    now. Returns the ring-clock interval it has to land in."""
+    t0 = time.monotonic_ns()
+    end = time.time()
+    jax.monitoring.record_event_time_span(event, end - seconds, end,
+                                          fun_name=fun_name)
+    return t0 - int(seconds * 1e9), time.monotonic_ns()
+
+
+@pytest.mark.parametrize("event,fun_name,span,counters", [
+    (TRACE, "probe", "trace:probe", {"compile/trace_events": 1}),
+    (LOWER, "jit(probe)", "lower:jit(probe)",
+     {"compile/programs": 1, "compile/lower_ns": 5_000_000}),
+    (BACKEND, "jit(probe)", "backend:jit(probe)",
+     {"compile/backend_ns": 5_000_000}),
+])
+def test_compile_stage_event_becomes_a_span_and_counters(event, fun_name,
+                                                         span, counters):
+    lo, hi = _raise_stage(event, 0.005, fun_name)
+    (got,) = _compile_spans()
+    assert got[0] == span and got[4] == threading.get_ident()
+    # on the ring's clock, to the float's rounding of `time.time()`
+    assert abs(got[3] - 5_000_000) < 10_000
+    assert lo - 1_000_000 <= got[2] and got[2] + got[3] <= hi + 1_000_000
+    have = _compile_counters()
+    assert set(have) == set(counters)
+    for name, want in counters.items():
+        assert abs(have[name] - want) < 10_000, (name, have)
+
+
+@pytest.mark.parametrize("event,counter,span", [
+    (CACHE_HIT, "compile/cache_hits", "cache_read:jit(probe)"),
+    (CACHE_MISS, "compile/cache_misses", "backend:jit(probe)"),
+])
+def test_cache_event_is_counted_and_names_the_stage_s_span(event, counter,
+                                                           span):
+    """The hit fires inside the backend stage, before the stage's span is
+    reported: that span is the cache's read, and the next program's (the
+    cache is asked again first) is a compile again."""
+    jax.monitoring.record_event(CACHE_REQUEST)
+    jax.monitoring.record_event(event)
+    _raise_stage(BACKEND, 0.002, "jit(probe)")
+    jax.monitoring.record_event(CACHE_REQUEST)
+    _raise_stage(BACKEND, 0.002, "jit(next)")
+    assert [s[0] for s in _compile_spans()] == [span, "backend:jit(next)"]
+    have = _compile_counters()
+    assert have[counter] == 1
+    read = event == CACHE_HIT
+    assert ("compile/cache_read_ns" in have) == read
+    assert abs(have["compile/backend_ns"]
+               - (2_000_000 if read else 4_000_000)) < 10_000
+
+
+def test_a_short_trace_event_is_counted_and_leaves_no_span():
+    _raise_stage(TRACE, 0.0005, "sin")
+    _raise_stage(TRACE, 0.0015, "outer")
+    assert [s[0] for s in _compile_spans()] == ["trace:outer"]
+    assert _compile_counters() == {"compile/trace_events": 2}
+
+
+def test_compile_listeners_install_once_and_outlive_a_reset():
+    from distributed_vgg_f_tpu.telemetry import compile_events
+    from distributed_vgg_f_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    assert compile_events.install(jax.monitoring) is False
+    enable_compile_cache()          # every entry point calls it: still once
+    telemetry.reset()
+    _raise_stage(LOWER, 0.002, "jit(probe)")
+    assert len(_compile_spans()) == 1
+    assert _compile_counters()["compile/programs"] == 1
+
+
+def test_compile_listeners_are_silent_when_telemetry_is_off():
+    telemetry.configure(enabled=False)
+    jax.monitoring.record_event(CACHE_HIT)
+    for event in (TRACE, LOWER, BACKEND):
+        _raise_stage(event, 0.002, "probe")
+    jax.jit(lambda x: x * 3 + 1)(np.float32(2)).block_until_ready()
+    telemetry.configure(enabled=True)
+    assert _compile_spans() == [] and _compile_counters() == {}
+
+
+@pytest.fixture
+def own_compile_cache(tmp_path):
+    """JAX's persistent cache in a directory of this test's own, holding
+    whatever compiles, and the suite's cache back afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    for k, v in zip(keys, (str(tmp_path / "cache"), 0.0, -1)):
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_a_real_compile_then_the_same_program_from_the_cache(
+        own_compile_cache):
+    """What JAX itself raises: a jitted function compiled here leaves one
+    lowering and one backend span under its name; after
+    `jax.clear_caches()` the same call lowers again and reads the
+    executable from the persistent cache."""
+    def startup_probe(x):
+        return jax.numpy.tanh(x) * 3 + 1
+
+    x = np.arange(4, dtype=np.float32)
+    jax.jit(startup_probe)(x).block_until_ready()
+    lower, backend, read = ("lower:jit(startup_probe)",
+                            "backend:jit(startup_probe)",
+                            "cache_read:jit(startup_probe)")
+    assert len(_compile_spans(lower)) == 1
+    assert len(_compile_spans(backend)) == 1 and not _compile_spans(read)
+    first = _compile_counters()
+    assert first["compile/programs"] >= 1
+    assert first["compile/cache_misses"] >= 1
+    assert first.get("compile/cache_hits", 0) == 0
+    spans = {s[0]: s for s in _compile_spans()}
+    assert spans[lower][2] + spans[lower][3] <= spans[backend][2] + 1_000
+
+    jax.clear_caches()
+    telemetry.reset()
+    jax.jit(startup_probe)(x).block_until_ready()
+    assert len(_compile_spans(lower)) == 1
+    assert len(_compile_spans(read)) == 1 and not _compile_spans(backend)
+    second = _compile_counters()
+    assert second["compile/cache_hits"] >= 1
+    assert second.get("compile/cache_misses", 0) == 0
+    assert second["compile/cache_read_ns"] > 0
+    assert second.get("compile/backend_ns", 0) == 0
+
+
+# --------------------------------------- set-up on the ring: `startup` spans
+_STARTUP_CHILD = """
+import io, json, sys
+import jax
+from distributed_vgg_f_tpu import telemetry
+from distributed_vgg_f_tpu.config import get_config, apply_overrides
+from distributed_vgg_f_tpu.train.trainer import Trainer
+from distributed_vgg_f_tpu.utils.compile_cache import enable_compile_cache
+from distributed_vgg_f_tpu.utils.logging import MetricLogger
+enable_compile_cache(sys.argv[1])
+cfg = apply_overrides(get_config("vggf_synthetic"), {
+    "data.image_size": 32, "model.num_classes": 10,
+    "data.global_batch_size": 8, "mesh.num_data": 1})
+trainer = Trainer(cfg, logger=MetricLogger(stream=io.StringIO()))
+jax.block_until_ready(trainer.init_state())
+print(json.dumps({
+    "spans": telemetry.get_recorder().snapshot(),
+    "start_ns": telemetry.get_registry().gauge("startup/process_start_ns"),
+    "counters": telemetry.get_registry().snapshot()}))
+"""
+
+
+@pytest.fixture(scope="module")
+def startup_ring():
+    """A process of its own builds a `Trainer` at a tiny size and its
+    state: the ring it leaves, from the module's import on (in this
+    process the module was imported long ago and the ring reset since)."""
+    from _child_bootstrap import cpu_cache_subdir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""}
+    out = subprocess.run(
+        [sys.executable, "-c", _STARTUP_CHILD, cpu_cache_subdir()],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.splitlines()[-1])
+
+
+def _startup(ring, name):
+    return [s for s in ring["spans"] if s[1] == "startup" and s[0] == name]
+
+
+@pytest.mark.parametrize("name", scopes.STARTUP_SPANS)
+def test_trainer_leaves_every_startup_span_once(startup_ring, name):
+    (span,) = _startup(startup_ring, name)
+    assert span[3] >= 0
+
+
+def test_startup_spans_nest_as_declared(startup_ring):
+    assert {s[0] for s in startup_ring["spans"] if s[1] == "startup"} \
+        == set(scopes.STARTUP_SPANS)
+    (imports,) = _startup(startup_ring, "import_trainer")
+    (init,) = _startup(startup_ring, "trainer_init")
+    (state,) = _startup(startup_ring, "init_state")
+    # the process started before the import, the import ended before the
+    # trainer was built, the state came after it
+    assert startup_ring["start_ns"] <= imports[2]
+    assert imports[2] + imports[3] <= init[2]
+    assert init[2] + init[3] <= state[2]
+    ends = []
+    for name in scopes.TRAINER_INIT_CHILDREN:
+        (child,) = _startup(startup_ring, name)
+        assert child[4] == init[4]                       # its thread
+        assert init[2] <= child[2]
+        assert child[2] + child[3] <= init[2] + init[3]
+        ends.append((child[2], child[2] + child[3]))
+    # the children follow each other in the declared order
+    assert ends == sorted(ends)
+    assert all(a[1] <= b[0] for a, b in zip(ends, ends[1:]))
+    # the state's init is a program: lowered inside `init_state`
+    lowered = [s for s in startup_ring["spans"]
+               if s[0] == "lower:jit(init_fn)"]
+    assert len(lowered) == 1 and state[2] <= lowered[0][2] \
+        and lowered[0][2] + lowered[0][3] <= state[2] + state[3]
+    assert startup_ring["counters"]["compile/programs"] >= 1
+
+
+# -------------------- the feed path's spans on a profiler's clock (PR 36)
+class _Annotations:
+    """Stand-in for `jax.profiler.TraceAnnotation`: what was opened and
+    closed, in order."""
+
+    def __init__(self):
+        self.opened, self.closed = [], []
+        self._lock = threading.Lock()
+
+    def __call__(self, name):
+        hook = self
+
+        class _One:
+            def __enter__(self):
+                with hook._lock:
+                    hook.opened.append(name)
+
+            def __exit__(self, *exc):
+                with hook._lock:
+                    hook.closed.append(name)
+        return _One()
+
+
+def _host_batches(n, rows=8):
+    return [{"image": np.full((rows, 4, 4, 3), i, np.float32),
+             "label": np.full((rows,), i, np.int32)} for i in range(n)]
+
+
+def _drain_device_prefetch(devices8, install):
+    from distributed_vgg_f_tpu.data.prefetch import DevicePrefetchIterator
+    from distributed_vgg_f_tpu.parallel.mesh import MeshSpec, build_mesh
+    mesh = build_mesh(MeshSpec(("data",), (8,)), devices8)
+    install()
+    it = DevicePrefetchIterator(iter(_host_batches(3)), mesh)
+    assert len(list(it)) == 3
+    it.close()
+
+
+def _drain_host_prefetch(devices8, install):
+    from distributed_vgg_f_tpu.data.prefetch import HostPrefetchIterator
+    install()
+    it = HostPrefetchIterator(iter(_host_batches(3)))
+    assert len(list(it)) == 3
+    it.close()
+
+
+def _one_eval_pass(devices8, install):
+    from distributed_vgg_f_tpu.train.trainer import Trainer
+    tr = Trainer(_cfg(), logger=MetricLogger(stream=io.StringIO()))
+    batches = _host_batches(2, rows=16)
+    for b in batches:
+        b["image"] = np.zeros((16, 32, 32, 3), np.float32)
+        b["label"] = b["label"] % 10
+    state = tr.init_state()
+    install()                      # the trainer had installed the profiler's
+    tr.evaluate(state, iter(batches), num_batches=2, step=0)
+
+
+def _native_loader_batches(devices8, install):
+    from distributed_vgg_f_tpu.data.native_loader import (
+        NativeBatchIterator, load_native)
+    if load_native() is None:
+        pytest.skip("native toolchain unavailable")
+    rng = np.random.default_rng(0)
+    it = NativeBatchIterator(
+        rng.integers(0, 256, (64, 8, 8, 3)).astype(np.uint8),
+        rng.integers(0, 10, (64,)).astype(np.int32), 16, train=False,
+        seed=0, mean=(0.0, 0.0, 0.0), std=(1.0, 1.0, 1.0))
+    install()
+    for _ in range(3):
+        next(it)
+    it.close()
+
+
+@pytest.mark.parametrize("drive,want", [
+    # three batches; the draw and the wait that find the source at its end
+    # are spans too (the worker, and the consumer, waited for them)
+    (_drain_device_prefetch, {"dvggf:infeed_source:source_next": 4,
+                              "dvggf:infeed_source:device_put": 3,
+                              "dvggf:infeed:prefetch_wait": 4}),
+    (_drain_host_prefetch, {"dvggf:infeed_source:host_prefetch_next": 4}),
+    (_one_eval_pass, {"dvggf:eval:eval_pass": 1}),
+    (_native_loader_batches, {"dvggf:infeed_source:native_loader_next": 3}),
+], ids=["device_prefetch", "host_prefetch", "eval_pass", "native_loader"])
+def test_feed_path_spans_open_on_the_profilers_clock(devices8, drive, want):
+    """Each is a `with span(...)` around the work, so a `jax.profiler`
+    capture shows it as `dvggf:<category>:<name>` (a span recorded after
+    the fact would reach the ring only): opened and closed once a batch,
+    and the ring holds the same spans."""
+    hook = _Annotations()
+    try:
+        drive(devices8, lambda: telemetry.configure(annotate=hook))
+    finally:
+        telemetry.get_recorder().annotate = None
+    for name, count in want.items():
+        assert hook.opened.count(name) == count, (name, hook.opened)
+        assert hook.closed.count(name) == count, (name, hook.closed)
+        category, span = name.split(":")[1:]
+        assert sum(1 for s in telemetry.get_recorder().snapshot()
+                   if (s[1], s[0]) == (category, span)) == count
+
+
+def test_instrument_iterator_pays_what_the_feed_path_pays():
+    """The host bench's overhead receipt charges `instrument_iterator`:
+    the same five `span(...)` calls a batch as prefetch worker, consumer,
+    trainer loop and step wrapper make, annotations included."""
+    hook = _Annotations()
+    telemetry.configure(annotate=hook)
+    try:
+        assert list(telemetry.instrument_iterator(iter(range(3)))) \
+            == [0, 1, 2]
+    finally:
+        telemetry.get_recorder().annotate = None
+    for name in ("dvggf:infeed_source:source_next",
+                 "dvggf:infeed_source:device_put",
+                 "dvggf:infeed:prefetch_wait", "dvggf:infeed:next_batch",
+                 "dvggf:dispatch:train_step_dispatch"):
+        assert hook.opened.count(name) >= 3 and \
+            hook.opened.count(name) == hook.closed.count(name), name
+    counters = telemetry.get_registry().snapshot()
+    assert counters["prefetch/batches"] == 3
+    assert counters["prefetch/source_batches"] == 3
+    assert counters["step/dispatched"] == 3 and counters["prefetch/wait_ns"] > 0
+
+
 # --------------------------------------------------------- import isolation
 def test_import_pulls_no_heavy_deps():
     """ISSUE 4 satellite (extended in ISSUE 8 to the live-observability
@@ -528,6 +886,7 @@ def test_import_pulls_no_heavy_deps():
         "import distributed_vgg_f_tpu.telemetry.exporter\n"
         "import distributed_vgg_f_tpu.telemetry.flight\n"
         "import distributed_vgg_f_tpu.telemetry.regress\n"
+        "import distributed_vgg_f_tpu.telemetry.compile_events\n"
         "heavy = [m for m in ('tensorflow', 'jax', 'numpy')\n"
         "         if m in sys.modules]\n"
         "assert not heavy, f'telemetry imported {heavy}'\n"

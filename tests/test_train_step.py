@@ -197,6 +197,23 @@ def test_dropout_differs_across_replicas(devices8):
     assert len({m.tobytes() for m in masks}) > 1
 
 
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 31 - 2, 2 ** 31 + 5,
+                                  2 ** 40 + 7, -4])
+def test_base_rng_is_the_seeds_key_whatever_the_seed(devices8, seed):
+    """The dropout key's program takes the seed as an argument (one program
+    for every seed: a constant made each new seed a compile and a cache
+    entry), and the key is still `jax.random.key(seed + 1)`, for a seed of
+    any size."""
+    cfg = _tiny_cfg()
+    cfg = dataclasses.replace(cfg, train=dataclasses.replace(cfg.train,
+                                                             seed=seed))
+    tr = Trainer(cfg, logger=_quiet())
+    want = jax.random.key(seed + 1, impl=cfg.train.dropout_rng_impl)
+    np.testing.assert_array_equal(jax.random.key_data(tr.base_rng()),
+                                  jax.random.key_data(want))
+    assert tr.base_rng().sharding.is_fully_replicated
+
+
 def test_eval_step_counts(devices8):
     cfg = _tiny_cfg(batch=16, dropout=0.0)
     tr = Trainer(cfg, logger=_quiet())
