@@ -57,7 +57,7 @@ from distributed_vgg_f_tpu.models.mistral4 import (
     chunked_next_token_loss)
 from distributed_vgg_f_tpu.models.nemotron_h import (
     _a_log, _log_uniform_dt_bias)
-from distributed_vgg_f_tpu.ops import kda
+from distributed_vgg_f_tpu.ops import kda, short_conv
 
 #: a letter of the pattern -> (attention, feed-forward)
 KINDS = {"D": ("kda", "dense"), "K": ("kda", "experts"),
@@ -95,26 +95,10 @@ class ConvTaps(nn.Module):
 
 
 # The elementwise stretches of the layer are made again in the backward
-# pass from what enters them (`jax.checkpoint`): kept, their float32
-# intermediates of tokens x 4096 (a quarter of a GiB each in the cell, a
-# dozen of them) would stand beside the recurrence's own.
-
-@functools.partial(jax.checkpoint, static_argnums=(2, 3))
-def _conv_silu_heads(x, kernel, heads: int, scale: float | None):
-    """silu of the causal depthwise convolution of x (b, t, channels) with
-    zeros before the sequence's start, as (b, t, heads, channels / heads)
-    in x's dtype; with a `scale`, each head L2-normalised (eps 1e-6) and
-    multiplied by it."""
-    b, t, channels = x.shape
-    width = kernel.shape[0]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
-    y = nn.silu(sum(padded[:, j:j + t] * kernel[j] for j in range(width)))
-    y = y.reshape(b, t, heads, channels // heads)
-    if scale is not None:
-        y = y * (scale * jax.lax.rsqrt(
-            jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6))
-    return y.astype(x.dtype)
-
+# pass from what enters them (`jax.checkpoint`; the short convolutions' are
+# ops/short_conv.py's): kept, their float32 intermediates of tokens x 4096 (a
+# quarter of a GiB each in the cell, a dozen of them) would stand beside the
+# recurrence's own.
 
 @functools.partial(jax.checkpoint, static_argnums=(3,))
 def _safe_gate(f, a_log, dt_bias, lower_bound: float):
@@ -163,11 +147,15 @@ class KimiDeltaAttention(nn.Module):
             q, k, v = (_dense(inner, dtype, f"{name}_proj")(u)
                        for name in "qkv")
         with jax.named_scope("kda_conv"):
-            q, k, v = (_conv_silu_heads(
-                x, ConvTaps(self.conv_kernel, inner, name=f"{name}_conv")(),
-                h, scale)
-                for name, x, scale in (("q", q, dk ** -0.5), ("k", k, 1.0),
-                                       ("v", v, None)))
+            taps = {name: ConvTaps(self.conv_kernel, inner,
+                                   name=f"{name}_conv")() for name in "qkv"}
+            # whether the convolutions took the Pallas kernels (1) or the
+            # XLA form (0), for a caller that asks (`mutable=["counters"]`)
+            self.sow("counters", "kda_conv_kernel", int(
+                short_conv.takes_kernels(q.shape, taps["q"].shape, h)))
+            q, k, v = (short_conv.conv_silu_heads(x, taps[name], h, scale)
+                       for name, x, scale in (("q", q, dk ** -0.5),
+                                              ("k", k, 1.0), ("v", v, None)))
         with jax.named_scope("kda_gates"):
             a_log = self.param("A_log", _a_log, (h,), jnp.float32)
             dt_bias = self.param("dt_bias", _log_uniform_dt_bias(*DT_INIT),

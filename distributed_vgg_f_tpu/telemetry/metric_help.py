@@ -39,8 +39,8 @@ NAMESPACE_HELP = {
     "ssm": "language-model state-space layers (chunks scanned a step, "
            "smallest decay of any layer, layers on the Pallas kernels)",
     "kda": "language-model delta-rule linear-attention layers (chunks a "
-           "step, smallest decay of any layer, layers on the Pallas "
-           "kernels)",
+           "step, smallest decay of any layer, layers whose recurrence "
+           "and whose short convolutions are on the Pallas kernels)",
     "compile": "JAX's own compile events (traces, lowerings, backend "
                "compiles, persistent-cache reads, hits and misses)",
     "startup": "where set-up starts (the process's start on the span "

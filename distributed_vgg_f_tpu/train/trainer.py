@@ -1160,9 +1160,10 @@ class Trainer:
                             # limit's (models/ling3.py): chunks a step, the
                             # smallest decay of any layer (exp(-5): a gate
                             # at its bound), the layers whose recurrence
-                            # ran as the Pallas kernels
-                            # (ops/kda_pallas.py), and the mean share of
-                            # tokens whose kept groups include this share's
+                            # and whose short convolutions ran as the
+                            # Pallas kernels (ops/kda_pallas.py,
+                            # ops/short_conv_pallas.py), and the mean share
+                            # of tokens whose kept groups include this share's
                             decays = [v for k, v in last_metrics.items()
                                       if k.startswith("kda_decay_min/")]
                             if decays:
@@ -1174,6 +1175,9 @@ class Trainer:
                                               over_layers("kda_chunks/"))
                                 reg.set_gauge("kda/kernel_layers",
                                               over_layers("kda_kernel/"))
+                                reg.set_gauge(
+                                    "kda/conv_kernel_layers",
+                                    over_layers("kda_conv_kernel/"))
                             shares = [v for k, v in last_metrics.items()
                                       if k.startswith("moe_group_share/")]
                             if shares:

@@ -330,6 +330,61 @@ def test_delta_rule_core_compiles_at_the_cell_s_widths(chip, monkeypatch):
     assert temporaries < 1.5, f"{temporaries:.2f} GiB of temporaries"
 
 
+def _short_conv_with_gradients(chip, monkeypatch, *, b, t, heads, width,
+                               dtype, scale):
+    """`short_conv.conv_silu_heads` with its output and both gradients,
+    compiled for the described chip as a TPU's trace would lower it; y and
+    its cotangent cross the program's edge as (b, t, channels), as the
+    recurrence's kernels take and give them (a parameter of (b, t, heads,
+    width) has another tiling and would be copied)."""
+    from distributed_vgg_f_tpu.ops import short_conv
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    arg = lambda shape, kind: jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+
+    def both(x, taps, dy):
+        y, back = jax.vjp(lambda x, taps: short_conv.conv_silu_heads(
+            x, taps, heads, scale), x, taps)
+        return y.reshape(dy.shape), back(dy.reshape(y.shape))
+
+    return jax.jit(both).lower(
+        arg((b, t, heads * width), dtype), arg((4, heads * width),
+                                               jnp.float32),
+        arg((b, t, heads * width), dtype)).compile()
+
+
+@pytest.mark.parametrize("scale", [1.0, None], ids=["head_norm", "no_norm"])
+def test_short_conv_compiles_at_the_cell_s_widths(chip, monkeypatch, scale):
+    """ops/short_conv.py at `ling3_flash_ep64`'s widths (2 x 8,192
+    positions, 32 heads of 128, four taps, bf16) forward and backward, as a
+    TPU's trace lowers it: the two Pallas kernels of
+    ops/short_conv_pallas.py under the default VMEM budget (they ask for no
+    more), no float32 array of tokens x channels in any shape outside them
+    (x's, p's and s's float32 copies live in the kernels' registers), and
+    the temporaries no more than dtaps' partial sums."""
+    compiled = _short_conv_with_gradients(
+        chip, monkeypatch, b=2, t=8192, heads=32, width=128,
+        dtype=jnp.bfloat16, scale=scale)
+    text = compiled.as_text()
+    assert len(re.findall(r"= .*custom-call.*tpu_custom_call", text)) == 2
+    assert "vmem_limit_bytes" not in text
+    wide = sorted({kind for kind in re.findall(r"f32\[[\d,]+\]", text)
+                   if _elements(kind) >= 2 * 8192 * 4096})
+    assert not wide, wide
+    memory = compiled.memory_analysis()
+    temporaries = (memory.peak_memory_in_bytes - memory.argument_size_in_bytes
+                   - memory.output_size_in_bytes) / 2 ** 20
+    assert temporaries < 1, f"{temporaries:.2f} MiB of temporaries"
+
+
+def test_short_conv_at_the_tiny_preset_s_widths_stays_xla(chip, monkeypatch):
+    """`ling3_flash_tiny` (4 heads of 16, float32) on a TPU: a head is no
+    lane tile, so no kernel."""
+    compiled = _short_conv_with_gradients(
+        chip, monkeypatch, b=2, t=64, heads=4, width=16, dtype=jnp.float32,
+        scale=1.0)
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
 def test_latent_core_at_two_head_sizes_compiles_for_v5e(chip):
     """The latent layer's attention core of `ling3_flash_ep64` (2
     sequences of 8,192 tokens, 32 heads, queries and keys of 192 on values

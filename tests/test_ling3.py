@@ -412,6 +412,7 @@ def test_block_sows_its_counters():
     counters = sown["counters"]
     assert int(counters["layer_0"]["attn"]["kda_chunks"][0]) == 2 * 2
     assert int(counters["layer_0"]["attn"]["kda_kernel"][0]) == 0
+    assert int(counters["layer_0"]["attn"]["kda_conv_kernel"][0]) == 0
     assert 0 < float(counters["layer_4"]["attn"]["kda_decay_min"][0]) < 1
     assert "attn" not in counters["layer_5"]            # the latent layer
     assert 0 < float(counters["layer_5"]["moe"]["group_share"][0]) <= 1
@@ -420,10 +421,12 @@ def test_block_sows_its_counters():
 
 @pytest.mark.parametrize("case", ["tile_sizes", "tiny_preset"])
 def test_layer_sows_whether_it_took_the_kernels(case, monkeypatch):
-    """`kda_kernel` beside `kda_chunks`: 1 at tile sizes (two heads of
-    128), 0 for the tiny preset's heads of 16, interpreter on in both."""
-    from distributed_vgg_f_tpu.ops import kda_pallas
+    """`kda_kernel` and `kda_conv_kernel` beside `kda_chunks`: 1 at tile
+    sizes (two heads of 128), 0 for the tiny preset's heads of 16,
+    interpreters on in both."""
+    from distributed_vgg_f_tpu.ops import kda_pallas, short_conv_pallas
     monkeypatch.setattr(kda_pallas, "INTERPRET", True)
+    monkeypatch.setattr(short_conv_pallas, "INTERPRET", True)
     tiles = {"num_attention_heads": 2, "head_dim": kda_pallas.LANES}
     model, arch = _model(**(tiles if case == "tile_sizes" else {}))
     layer = ling3.KimiDeltaAttention(**model.layers["kda"],
@@ -433,6 +436,7 @@ def test_layer_sows_whether_it_took_the_kernels(case, monkeypatch):
     _, sown = layer.apply({"params": params}, u, mutable=["counters"])
     counters = {k: v[0] for k, v in sown["counters"].items()}
     assert counters["kda_kernel"] == (1 if case == "tile_sizes" else 0)
+    assert counters["kda_conv_kernel"] == counters["kda_kernel"]
     assert counters["kda_chunks"] == 2 * SEQ // 64
 
 
@@ -463,6 +467,7 @@ def test_fit_with_the_tiny_preset_logs_every_layer_under_its_own_index():
         telemetry.configure(enabled=True)
     assert gauges["kda/chunks"] == 6 * 4              # six delta-rule layers
     assert gauges["kda/kernel_layers"] == 0           # heads of 16: XLA's
+    assert gauges["kda/conv_kernel_layers"] == 0
     assert 0 < gauges["kda/decay_min"] < 1
     assert 0 < gauges["moe/group_share"] <= 1
     assert gauges["moe/assignments_held"] == 6 * 1024  # six expert layers
@@ -477,10 +482,12 @@ def test_fit_with_the_tiny_preset_logs_every_layer_under_its_own_index():
                  "moe_passes/layer_5=1", "moe_group_share/layer_5",
                  "kda_chunks/layer_0=4", "kda_chunks/layer_6=4",
                  "kda_kernel/layer_0=0", "kda_kernel/layer_6=0",
+                 "kda_conv_kernel/layer_0=0", "kda_conv_kernel/layer_6=0",
                  "kda_decay_min/layer_2"):
         assert name in log, name
     assert "moe_held/layer_0" not in log and "kda_chunks/layer_5" not in log
     assert "kda_kernel/layer_5" not in log
+    assert "kda_conv_kernel/layer_5" not in log
     assert "moe_kda" not in log
 
 

@@ -355,15 +355,12 @@ def test_scan_kernels_keep_the_name_ssm_scan(monkeypatch):
                for stack in re.findall(r'loc\("([^"]*)"', text))
 
 
-def test_delta_rule_kernels_keep_the_name_kda_core(monkeypatch):
-    """Ling's train step at the smallest sizes the delta rule's Pallas
-    kernels take (ops/kda_pallas.py: heads of 128 in pairs, two chunks of 64),
-    lowered for a TPU. As `ssd_pallas.scan`, the op is one jitted function
-    that JAX lowers once for the six KDA layers: three kernels in all (the
-    forward, the block's recomputed forward that saves the entering
-    states, the hand-written backward), each under the `kda_core` the
-    function opens itself, so the benchmark's reader gives all three to
-    that row: `kda_pct` and `kda_core_roofline_pct` keep reading it."""
+@pytest.fixture(scope="module")
+def tile_sized_ling_step():
+    """Ling's train step at the smallest sizes its Pallas kernels take
+    (heads of 128 in pairs, two chunks of 64), lowered for a TPU: the text
+    with debug info, the name stacks of its kernel calls but the latent
+    layer's, and the benchmark's names for that model."""
     import json
     from chipbench import scope_reduce
     cfg = apply_overrides(get_config("ling3_flash_tiny"), {
@@ -375,18 +372,32 @@ def test_delta_rule_kernels_keep_the_name_kda_core(monkeypatch):
     batch = {"tokens": jax.ShapeDtypeStruct(
         (cfg.data.global_batch_size, seq + 1), jnp.int32)}
     state, rng = jax.eval_shape(trainer.init_state), trainer.base_rng()
-    # `kda.kda` and the attention core ask the backend: a TPU's trace
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = trainer.train_step.__wrapped__.trace(state, batch, rng).lower(
-        lowering_platforms=("tpu",)).as_text(debug_info=True)
-    monkeypatch.undo()
+    # `kda.kda`, `short_conv.conv_silu_heads` and the attention core ask
+    # the backend: a TPU's trace
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        text = trainer.train_step.__wrapped__.trace(state, batch, rng).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
     with open(os.path.join(os.path.dirname(scope_reduce.__file__),
                            "ling_lm_scopes.json")) as f:
         names = json.load(f)
     named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
     calls = [named[loc] for loc in re.findall(
         r"custom_call @tpu_custom_call.*loc\((#loc\d+)\)", text)]
-    core = sorted(call for call in calls if "mla_core" not in call)
+    return text, sorted(call for call in calls if "mla_core" not in call), \
+        names
+
+
+def test_delta_rule_kernels_keep_the_name_kda_core(tile_sized_ling_step):
+    """As `ssd_pallas.scan`, the op of ops/kda_pallas.py is one jitted
+    function that JAX lowers once for the six KDA layers: three kernels in
+    all (the forward, the block's recomputed forward that saves the
+    entering states, the hand-written backward), each under the `kda_core`
+    the function opens itself, so the benchmark's reader gives all three to
+    that row: `kda_pct` and `kda_core_roofline_pct` keep reading it."""
+    from chipbench import scope_reduce
+    text, calls, names = tile_sized_ling_step
+    core = [call for call in calls if "kda_core" in call]
     assert core == ["kda_core/kda_core/pallas_call", "kda_core/pallas_call",
                     "kda_core/pallas_call"], calls
     assert {scope_reduce.scope_of(call + ":", names)[0]
@@ -394,6 +405,32 @@ def test_delta_rule_kernels_keep_the_name_kda_core(monkeypatch):
     # the function is called under the layer's own `kda_core` too
     assert any(stack.endswith("layer_0/attn/kda_core/jit(chunked)")
                for stack in re.findall(r'loc\("([^"]*)"', text))
+
+
+def test_short_conv_kernels_keep_the_name_kda_conv(tile_sized_ling_step):
+    """ops/short_conv_pallas.py likewise: one jitted function, traced with
+    and without the head norm (q's and k's scales are operands), lowered
+    once a variant for the six layers' 18 call sites: six kernels in all
+    (a variant's forward, its forward as the differentiated rule calls it
+    in the block's recomputation, its hand-written backward), each under
+    the `kda_conv` the function opens itself, forward and backward, so the
+    benchmark's reader gives all six to that row; no other kernel joins the
+    step."""
+    from chipbench import scope_reduce
+    text, calls, names = tile_sized_ling_step
+    conv = [call for call in calls if "kda_core" not in call]
+    assert conv == ["kda_conv/kda_conv/pallas_call"] * 2 \
+        + ["kda_conv/pallas_call"] * 4, calls
+    assert {scope_reduce.scope_of(call + ":", names)[0]
+            for call in conv} == {"kda_conv"}
+    stacks = re.findall(r'loc\("([^"]*)"', text)
+    # the function is called under the layer's own `kda_conv`, in the pass,
+    # in the block's recomputation and in the backward pass
+    at = "layer_0/attn/kda_conv/jit(convolved)"
+    assert any(s.endswith(at) and "transpose(" not in s for s in stacks)
+    assert any(s.endswith("rematted_computation/" + at) for s in stacks)
+    assert any(s.endswith("checkpoint/" + at) and "transpose(" in s
+               for s in stacks)
 
 
 def test_jitted_steps_are_named_for_what_they_are(lowered):
