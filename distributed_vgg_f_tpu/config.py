@@ -1302,7 +1302,8 @@ MISTRAL_SMALL4_PUBLISHED = {
 def _language_model(name: str, extra: dict, vocab_rows: int, steps: int,
                     model: str = "mistral4") -> ExperimentConfig:
     """The language-model presets' common frame (`model`: the registry's
-    name, models/mistral4.py, models/nemotron_h.py or models/ling3.py):
+    name, models/mistral4.py, models/nemotron_h.py, models/ling3.py or
+    models/solar_open2.py):
     packed int32 tokens
     from the seeded source, one sequence a step, bf16 compute on float32
     weights, SGD-momentum 0.9 at a constant rate, no weight decay, no
@@ -1479,6 +1480,64 @@ def _ling3_flash_tiny() -> ExperimentConfig:
         train=_replace(cfg.train, log_every=1))
 
 
+#: `Solar-Open2-250B`'s published config (https://huggingface.co/upstage/
+#: Solar-Open2-250B/blob/main/config.json, `model_type: solar_open2`):
+#: every width as published; `gqa_layers` the rule the kinds follow.
+SOLAR_OPEN2_PUBLISHED = {
+    "hidden_size": 4096, "num_attention_heads": 64, "head_dim": 128,
+    "num_key_value_heads": 8, "intermediate_size": 10240,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 128,
+                           "num_heads": 64, "num_kv_heads": None},
+    "moe_intermediate_size": 1280, "rms_norm_eps": 1e-05,
+    "num_hidden_layers": 48, "first_k_dense_replace": 0, "use_rope": False,
+    "gqa_interval": 3, "gqa_layers": list(range(0, 48, 4)),
+    "use_gqa_gate": True, "kda_use_full_proj": False,
+    "kda_allow_neg_eigval": True, "n_routed_experts": 320,
+    "n_shared_experts": 1, "norm_topk_prob": True,
+    "routed_scaling_factor": 1, "num_experts_per_tok": 8,
+}
+
+
+def _solar_open2_250b_ep40() -> ExperimentConfig:
+    """One chip's share of Solar-Open2-250B under 40-way expert
+    parallelism: 4 of 48 layers, one period of the published pattern
+    (`GKKK`: gated grouped-query attention, then three Kimi-delta layers,
+    experts in each), experts [0, 8) of 320 (the router stays 320 wide,
+    top-8), 24576 of 196608 vocabulary rows, one sequence of 4096 a step.
+    1.30 B parameters: float32 weights and momentum and bf16 gradients
+    (10 bytes each) take 12.1 GiB of the chip's 15.75 before the first
+    activation; recomputation per block and the loss in chunks, as the
+    Mistral preset and for its reason."""
+    return _language_model(
+        "solar_open2_250b_ep40",
+        {**SOLAR_OPEN2_PUBLISHED, "num_hidden_layers": 4,
+         "hybrid_override_pattern": "GKKK", "first_expert": 0,
+         "experts_held": 8, "seq_len": 4096},
+        vocab_rows=24576, steps=100, model="solar_open2")
+
+
+def _solar_open2_tiny() -> ExperimentConfig:
+    """The same four layers at a size a CPU test holds, every expert held:
+    hidden 64, 4 delta-rule heads of 16, 4 query heads on 2 key/value heads
+    of 16, 16 experts top-4 of width 32, vocabulary 256, sequences of 128
+    (two chunks of the delta rule), float32."""
+    cfg = _language_model(
+        "solar_open2_tiny",
+        {**SOLAR_OPEN2_PUBLISHED, "hidden_size": 64,
+         "num_attention_heads": 4, "head_dim": 16, "num_key_value_heads": 2,
+         "linear_attn_config": {**SOLAR_OPEN2_PUBLISHED["linear_attn_config"],
+                                "head_dim": 16, "num_heads": 4},
+         "moe_intermediate_size": 32, "n_routed_experts": 16,
+         "num_experts_per_tok": 4, "num_hidden_layers": 4,
+         "hybrid_override_pattern": "GKKK", "seq_len": 128},
+        vocab_rows=256, steps=3, model="solar_open2")
+    return _replace(
+        cfg, model=_replace(cfg.model, compute_dtype="float32"),
+        data=_replace(cfg.data, global_batch_size=2),
+        optim=_replace(cfg.optim, reference_batch_size=2),
+        train=_replace(cfg.train, log_every=1))
+
+
 PRESETS = {
     "vggf_cifar10_smoke": _vggf_cifar10_smoke,
     "vggf_imagenet_dp": _vggf_imagenet_dp,
@@ -1493,6 +1552,8 @@ PRESETS = {
     "nemotron3_nano_tiny": _nemotron3_nano_tiny,
     "ling3_flash_ep64": _ling3_flash_ep64,
     "ling3_flash_tiny": _ling3_flash_tiny,
+    "solar_open2_250b_ep40": _solar_open2_250b_ep40,
+    "solar_open2_tiny": _solar_open2_tiny,
 }
 
 

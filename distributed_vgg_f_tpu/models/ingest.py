@@ -98,14 +98,16 @@ INGEST_DESCRIPTORS: Dict[str, IngestDescriptor] = {
     "vggf_student": IngestDescriptor("vggf_student", space_to_depth=True,
                                      serving_only=True),
     # the decoder-only language models (models/mistral4.py,
-    # models/nemotron_h.py, models/ling3.py): packed int32 tokens, no pixel
-    # wire;
+    # models/nemotron_h.py, models/ling3.py, models/solar_open2.py): packed
+    # int32 tokens, no pixel wire;
     # `zoo_model_names` (the image grids, the serving router) leaves them
     # out by their kind
     "mistral4": IngestDescriptor("mistral4", kind="tokens", wire="tokens"),
     "nemotron_h": IngestDescriptor("nemotron_h", kind="tokens",
                                    wire="tokens"),
     "ling3": IngestDescriptor("ling3", kind="tokens", wire="tokens"),
+    "solar_open2": IngestDescriptor("solar_open2", kind="tokens",
+                                    wire="tokens"),
 }
 
 

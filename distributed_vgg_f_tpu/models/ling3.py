@@ -17,6 +17,9 @@ attention and whose feed-forward both vary by depth.
         S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
         o_t = S_t^T q_t                                 (ops/kda.py)
         y = [RMSNorm_head(o) * sigmoid(u W_g)_h] W_o    one gate a head
+        (`KimiDeltaAttention` also takes Kimi Linear's own form, which
+        models/solar_open2.py runs: an unbounded softplus gate, low-rank
+        gates, one output gate a channel, beta in (0, 2))
     latent  q = u W_q (H x [nope | rope]);  [c | k_r] = u W_kva;
             c <- RMSNorm(c);  [k_nope | v] = c W_kvb;  plain rotary table on
             q's rope part and k_r (shared by the heads); causal softmax of
@@ -111,12 +114,26 @@ def _safe_gate(f, a_log, dt_bias, lower_bound: float):
             b, t, heads, channels // heads))
 
 
+@jax.checkpoint
+def _kimi_gate(f, a_log, dt_bias):
+    """g = -exp(A_log_h) softplus(f + dt_bias), float32, (b, t, heads,
+    channels / heads): Kimi Linear's own gate, any value <= 0."""
+    b, t, channels = f.shape
+    heads = a_log.shape[0]
+    return -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+        (f.astype(jnp.float32) + dt_bias).reshape(
+            b, t, heads, channels // heads))
+
+
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
 def _norm_gate(o, scale, gate, eps: float, dtype):
     """RMSNorm over each head's channels of o (b, t, heads, dv) float32,
-    times the learned scale and the head's gate (b, t, heads)."""
+    times the learned scale and the gate: the head's (b, t, heads) or the
+    channel's (b, t, heads, dv)."""
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
-    return (o * scale * gate[..., None]).astype(dtype)
+    if gate.ndim < o.ndim:
+        return (o * scale * gate[..., None]).astype(dtype)
+    return (o * scale * gate).astype(dtype)
 
 
 class Scale(nn.Module):
@@ -130,13 +147,33 @@ class Scale(nn.Module):
 
 
 class KimiDeltaAttention(nn.Module):
+    """The delta-rule layer of the module docstring, in the two forms the
+    published configurations spell: Ling's (`lower_bound` the safe gate's
+    bound; full `f_proj`; one output gate a head) and Kimi Linear's as
+    Solar-Open2 takes it (`lower_bound` None: g = -exp(A_log_h)
+    softplus(u F_a F_b + dt_bias), unbounded below; `gate_rank`: the decay
+    and output gates through a rank of that many channels, `f_a_proj` then
+    `f_b_proj`, `g_a_proj` then `g_b_proj`; `channel_gate`: one output gate
+    a channel; `beta_scale` 2: beta in (0, 2), negative eigenvalues of the
+    transition)."""
     num_heads: int
     head_dim: int
     conv_kernel: int
-    lower_bound: float
+    lower_bound: float | None
     compute_dtype: Any
     eps: float = 1e-6
     chunk_size: int = 64
+    gate_rank: int | None = None
+    channel_gate: bool = False
+    beta_scale: float = 1.0
+
+    def _projection(self, u, features: int, name: str):
+        """u -> features, whole or through `gate_rank`."""
+        dtype = self.compute_dtype
+        if self.gate_rank is None:
+            return _dense(features, dtype, f"{name}_proj")(u)
+        return _dense(features, dtype, f"{name}_b_proj")(
+            _dense(self.gate_rank, dtype, f"{name}_a_proj")(u))
 
     @nn.compact
     def __call__(self, u):
@@ -160,14 +197,21 @@ class KimiDeltaAttention(nn.Module):
             a_log = self.param("A_log", _a_log, (h,), jnp.float32)
             dt_bias = self.param("dt_bias", _log_uniform_dt_bias(*DT_INIT),
                                  (inner,), jnp.float32)
-            g = _safe_gate(_dense(inner, dtype, "f_proj")(u), a_log, dt_bias,
-                           float(self.lower_bound))
+            f = self._projection(u, inner, "f")
+            bounded = self.lower_bound is not None
+            g = _safe_gate(f, a_log, dt_bias, float(self.lower_bound)) \
+                if bounded else _kimi_gate(f, a_log, dt_bias)
             beta = jax.nn.sigmoid(
                 _dense(h, dtype, "b_proj")(u).astype(jnp.float32))
-            gate = jax.nn.sigmoid(
-                _dense(h, dtype, "g_proj")(u).astype(jnp.float32))
+            if self.beta_scale != 1.0:
+                beta = self.beta_scale * beta
+            gate = jax.nn.sigmoid(self._projection(
+                u, inner if self.channel_gate else h, "g").astype(jnp.float32))
+            if self.channel_gate:
+                gate = gate.reshape(b, t, h, dk)
         with jax.named_scope("kda_core"):
-            o = kda.kda(q, k, v, g, beta, chunk=self.chunk_size)
+            o = kda.kda(q, k, v, g, beta, chunk=self.chunk_size,
+                        bounded=bounded)
             # receipts for a caller that asks (`mutable=["counters"]`): a
             # gate stuck at its bound shows as exp(lower_bound); and whether
             # the recurrence took the Pallas kernels (1) or the XLA form (0)
@@ -175,6 +219,14 @@ class KimiDeltaAttention(nn.Module):
             self.sow("counters", "kda_kernel", int(kda.takes_kernels(
                 k.shape, v.shape, self.chunk_size)))
             self.sow("counters", "kda_decay_min", kda.smallest_decay(g))
+            # and where the form in use reaches past Ling's: the share of
+            # gate entries the bounded form would have clamped, and the
+            # largest beta (over 1: a negative eigenvalue)
+            if not bounded:
+                self.sow("counters", "kda_gate_below_bound",
+                         kda.share_below_bound(g))
+            if self.beta_scale != 1.0:
+                self.sow("counters", "kda_beta_max", jnp.max(beta))
         with jax.named_scope("kda_out"):
             # one norm over each head's channels, one scale for all heads
             scale = Scale(dk, name="o_norm")()
@@ -225,7 +277,9 @@ class LingBlock(nn.Module):
 
 class LingLM(nn.Module):
     """Token ids (B, T) -> float32 logits (B, T, vocabulary held);
-    `next_token_loss` is what the train step calls."""
+    `next_token_loss` is what the train step calls. `kinds` (the letters)
+    and `block` (a layer) are the class's: models/solar_open2.py gives its
+    own."""
     vocab_size: int
     hidden_size: int
     pattern: str                   # one letter of `KINDS` a layer
@@ -234,18 +288,21 @@ class LingLM(nn.Module):
     eps: float = 1e-6
     loss_chunk_rows: int = 1024
 
+    kinds = KINDS
+    block = LingBlock
+
     @property
     def expert_layers(self) -> tuple:
         """The layers the rows of `hidden`'s counts stand for."""
         return tuple(i for i, letter in enumerate(self.pattern)
-                     if KINDS[letter][1] == "experts")
+                     if self.kinds[letter][1] == "experts")
 
     def setup(self):
         self.embed = nn.Embed(self.vocab_size, self.hidden_size,
                               dtype=self.compute_dtype,
                               param_dtype=jnp.float32, name="embed")
         # recomputation per block, as `Mistral4LM`
-        self.blocks = [nn.remat(LingBlock)(
+        self.blocks = [nn.remat(self.block)(
             letter, self.layers, self.compute_dtype, self.eps,
             name=f"layer_{i}") for i, letter in enumerate(self.pattern)]
         self.norm = RMSNorm(self.eps, name="norm")

@@ -174,11 +174,15 @@ class GroupedQueryAttention(nn.Module):
     `num_kv_heads` key/value heads, no position embedding. The core is the
     Pallas kernel of ops/flash_attention.py where that can run (a TPU, or
     the Pallas interpreter that tests switch on) and explicit scores
-    elsewhere, as `mistral4.LatentAttention`."""
+    elsewhere, as `mistral4.LatentAttention`. With `output_gate` (a
+    config's `use_gqa_gate`) the heads' output is gated element by element
+    before the output projection, ctx * sigmoid(u W_gate) (Qiu et al.,
+    arXiv:2505.06708)."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
     compute_dtype: Any
+    output_gate: bool = False
 
     @nn.compact
     def __call__(self, u):
@@ -207,6 +211,12 @@ class GroupedQueryAttention(nn.Module):
                                    -jnp.inf)
                 probs = jax.nn.softmax(scores, axis=-1)
                 ctx = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(dtype), v)
+        if self.output_gate:
+            with jax.named_scope("gqa_gate"):
+                gate = jax.nn.sigmoid(_dense(h * d, dtype, "gate_proj")(
+                    u).astype(jnp.float32))
+                ctx = (ctx.reshape(b, t, h * d).astype(jnp.float32)
+                       * gate).astype(dtype)
         with jax.named_scope("gqa_out"):
             return _dense(d_model, dtype, "o_proj")(ctx.reshape(b, t, h * d))
 

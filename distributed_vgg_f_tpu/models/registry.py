@@ -110,3 +110,10 @@ def _build_ling3(cfg: ModelConfig) -> nn.Module:
     # the cut in depth
     from distributed_vgg_f_tpu.models import ling3
     return ling3.build(cfg.num_classes, _dtype(cfg), cfg.extra)
+
+
+@register("solar_open2")
+def _build_solar_open2(cfg: ModelConfig) -> nn.Module:
+    # as `ling3`
+    from distributed_vgg_f_tpu.models import solar_open2
+    return solar_open2.build(cfg.num_classes, _dtype(cfg), cfg.extra)

@@ -31,21 +31,37 @@ alive); the scan over the chunks (`_across`) is one small product a chunk,
 and the read-out is made for all chunks at once from the states the scan
 kept.
 
-**The exponents.** A chunk of 64 at the gate's bound of -5 a position spans
-G = -320, and exp(-G_j) alone overflows float32 after 17 positions. So no
-exponent is formed that can be large: `exp(G_i)` and `exp(G_last - G_j)`
-are differences that are <= 0; the pairwise `exp(G_i - G_j)` of A and of
-the read-out's scores is split at the first position r of the row's
-sub-block of `SUB` = 16 positions, `exp(G_i - G_r) exp(G_r - G_j)`: the
-first factor's exponent is <= 0, the second's is <= 0 for every j before the
-sub-block and at most `(SUB - 1) x 5 = 75 < 88` inside it. That is what the
-published lower bound of the gate is for, and `g >= LOWER_BOUND` is this
-function's contract. The second factor is made for every column j of the
-chunk, the ones behind the row's sub-block too, where the mask takes the
-product: there its exponent is clamped at `_CAP` (a g under the bound is
-clamped with them: finite, and no longer the recurrence). Decays, their
-sums, the solve and the state are float32; the products take operands in
-q's dtype and accumulate in float32, as ops/ssd.py `ssd_xla` rounds.
+**The exponents.** A chunk of 64 at a gate of -5 a position spans G = -320,
+and exp(-G_j) alone overflows float32 after 17 positions. So no exponent is
+formed that can be large: `exp(G_i)` and `exp(G_last - G_j)` are
+differences that are <= 0; the pairwise `exp(G_i - G_j)` of A and of the
+read-out's scores is split at the first position r of the row's sub-block
+of `SUB` = 16 positions, `exp(G_i - G_r) exp(G_r - G_j)`: the first
+factor's exponent is <= 0, and so is the second's for every j before the
+sub-block. Inside the row's own sub-block (j in [r, i]) the two gates a
+caller can state part ways (`kda`'s `bounded`, a static choice):
+
+- **bounded** (Ling's `kda_safe_gate`, g >= `LOWER_BOUND`): the same split,
+  whose second exponent is at most `(SUB - 1) x 5 = 75 < 88` there. Its
+  factor is made for every column j of the chunk, behind the row's
+  sub-block too, where the mask takes the product: there its exponent is
+  clamped at `_CAP`. A g under the bound is clamped with them: finite, and
+  no longer the recurrence, which is why this form is only for a caller
+  whose gate cannot go under it;
+- **unbounded** (Kimi Linear's `-exp(A_log) softplus(..)`, any g <= 0): the
+  split only for columns before the sub-block (the second factor's exponent
+  is then clamped at 0, which changes no column the mask keeps), and on the
+  diagonal 16 x 16 sub-blocks each pair's `exp(G_i - G_j)` formed directly,
+  an exponent <= 0 whatever the gate, as a product of float32 elements
+  summed over the channels. A gate of -1e4 gives exp = 0, not a NaN. Its
+  price is the diagonal's exponentials (a chunk's 4 x 16 x 16 x 128 of them
+  a head: PERF.md, section 6).
+
+Decays, their sums, the solve and the state are float32; the products take
+operands in q's dtype and accumulate in float32, as ops/ssd.py `ssd_xla`
+rounds. A beta in (0, 2) (`kda_allow_neg_eigval`: negative eigenvalues of
+the transition) changes nothing here: the solve is exact algebra at any
+beta.
 
 The solve is forward substitution in blocks that double (the inverse of a
 2 x 2 block-triangular matrix from its diagonal blocks' inverses, from
@@ -60,7 +76,8 @@ Two implementations behind `kda`, chosen by what the call can observe:
   the heads come in pairs (`kda_pallas.applies`) and the backend is a TPU
   (or the Pallas interpreter a test switched on): the direct form above
   (U = T (V - (K * exp(G)) S), with S in VMEM along a sequential chunk
-  axis), the same sub-blocks, reference points and clamp, the solve by
+  axis), the same sub-blocks, reference points and either form of the
+  diagonal sub-blocks, the solve by
   forward substitution on the vector unit in float32; a chunk's scores,
   their inverse and the decayed copies of q and k never reach HBM (PERF.md,
   section 6, PR 35);
@@ -80,7 +97,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-#: the smallest g a position may have (the published `kda_lower_bound`)
+#: the smallest g a position may have under the bounded form (the published
+#: `kda_lower_bound`)
 LOWER_BOUND = -5.0
 #: positions that share one reference point of the pairwise decays
 SUB = 16
@@ -127,10 +145,11 @@ def _unit_lower_inverse(a):
     return inverse[..., 0, :n, :n]
 
 
-def _within(q, k, v, g, beta):
+def _within(q, k, v, g, beta, bounded: bool = True):
     """What a chunk gives without the state S that enters it. `q`, `k`
     (n, C, h, dk), `v` (n, C, h, dv) in the compute dtype, `g` (n, C, h,
-    dk) and `beta` (n, C, h) float32, n chunks of C positions. Returns the
+    dk) and `beta` (n, C, h) float32, n chunks of C positions; `bounded`
+    the form of the diagonal sub-blocks (module docstring). Returns the
     chunk's map of the state, S' = keep * S - turn S + add, and its
     read-out, o = read S + own: `turn` (n, h, dk, dk) and `read` (n, h, C,
     dk) in the compute dtype, `add` (n, h, dk, dv), `own` (n, h, C, dv) and
@@ -148,15 +167,29 @@ def _within(q, k, v, g, beta):
     k_rows = (k32.reshape(by_block.shape) * from_first).astype(dtype)
     q_rows = (q32.reshape(by_block.shape) * from_first).astype(dtype)
     # columns: every position decayed back to each sub-block's first one:
-    # <= 0 before that sub-block, at most (SUB - 1) x 5 inside it; behind
-    # it the mask below takes what the clamp leaves
-    cols = (k32[:, None] * jnp.exp(jnp.minimum(first - G[:, None], _CAP))
+    # <= 0 before that sub-block, at most (SUB - 1) x 5 inside it under the
+    # bounded gate; behind it the mask below takes what the clamp leaves
+    cols = (k32[:, None] * jnp.exp(jnp.minimum(
+        first - G[:, None], _CAP if bounded else 0.0))
             ).astype(dtype)                                  # (n, B, C, h, dk)
     kk = jnp.einsum("nbihd,nbjhd->nhbij", k_rows, cols,
                     preferred_element_type=f32).reshape(n, h, c, c)
     qk = jnp.einsum("nbihd,nbjhd->nhbij", q_rows, cols,
                     preferred_element_type=f32).reshape(n, h, c, c)
     at = jnp.arange(c)
+    if not bounded:
+        # the diagonal sub-blocks pair by pair, exp(G_i - G_j) <= 1 for
+        # j <= i, in place of the split's (what lies above the diagonal
+        # the masks below take)
+        pair = jnp.exp(jnp.minimum(
+            by_block[:, :, :, None] - by_block[:, :, None], 0.0))
+        k_b, q_b = (x.reshape(by_block.shape) for x in (k32, q32))
+        diag = [jnp.einsum("nbihd,nbjhd,nbijhd->nhbij", x, k_b, pair,
+                           precision=_HIGHEST) for x in (k_b, q_b)]
+        own_block = (at[:, None] // sub) == (at[None, :] // sub)
+        kk, qk = (jnp.where(own_block, jnp.einsum(
+            "nhbij,bc->nhbicj", d, jnp.eye(blocks, dtype=f32)).reshape(
+                n, h, c, c), split) for d, split in zip(diag, (kk, qk)))
     b_rows = beta.astype(f32).transpose(0, 2, 1)             # (n, h, C)
     A = jnp.where(at[:, None] > at[None, :], kk, 0.0) * b_rows[..., None]
     T = (_unit_lower_inverse(A) * b_rows[..., None, :]).astype(dtype)
@@ -218,22 +251,27 @@ def takes_kernels(k_shape, v_shape, chunk: int) -> bool:
         and kda_pallas.applies(k_shape, v_shape, chunk)
 
 
-def kda(q, k, v, g, beta, chunk: int = 64):
+def kda(q, k, v, g, beta, chunk: int = 64, *, bounded: bool = True):
     """`q`, `k` (b, t, h, dk) in the compute dtype (normalised, q scaled,
-    by the caller), `v` (b, t, h, dv), `g` (b, t, h, dk) float32 in
-    [`LOWER_BOUND`, 0], `beta` (b, t, h) float32. Returns o (b, t, h, dv)
-    float32. `t` is a whole number of chunks (or shorter than one)."""
+    by the caller), `v` (b, t, h, dv), `g` (b, t, h, dk) float32, `beta`
+    (b, t, h) float32 (in (0, 1), or in (0, 2) where the transition may
+    have negative eigenvalues). `bounded` is the caller's statement about
+    its gate, read once when the program is traced: True, g in
+    [`LOWER_BOUND`, 0] (the factored sub-blocks); False, any g <= 0 (the
+    diagonal sub-blocks pair by pair: module docstring). Returns o (b, t,
+    h, dv) float32. `t` is a whole number of chunks (or shorter than
+    one)."""
     t = k.shape[1]
     if t % min(chunk, t):
         raise ValueError(f"{t} positions in chunks of {chunk}: a rest of a "
                          "chunk is left")
     if takes_kernels(k.shape, v.shape, chunk):
         from distributed_vgg_f_tpu.ops import kda_pallas
-        return kda_pallas.chunked(q, k, v, g, beta)
-    return kda_xla(q, k, v, g, beta, chunk)
+        return kda_pallas.chunked(q, k, v, g, beta, bounded=bounded)
+    return kda_xla(q, k, v, g, beta, chunk, bounded=bounded)
 
 
-def kda_xla(q, k, v, g, beta, chunk: int = 64):
+def kda_xla(q, k, v, g, beta, chunk: int = 64, *, bounded: bool = True):
     """`kda` as plain XLA products, differentiated by JAX."""
     b, t, h, dk = k.shape
     dv = v.shape[-1]
@@ -241,9 +279,10 @@ def kda_xla(q, k, v, g, beta, chunk: int = 64):
     n = b * (t // c)
     group = math.gcd(n, GROUP_CHUNKS)
     split = lambda x: x.reshape(n // group, group, c, *x.shape[2:])
+    within = jax.checkpoint(lambda *args: _within(*args, bounded=bounded))
     # the S-free parts a group of chunks at a time, each group made again
     # in the backward pass: one group's intermediates are alive
-    parts = jax.lax.map(lambda args: jax.checkpoint(_within)(*args),
+    parts = jax.lax.map(lambda args: within(*args),
                         tuple(split(x) for x in (q, k, v, g, beta)))
     parts = [p.reshape(n, *p.shape[2:]) for p in parts]
     o = _across(parts, b)                                # (b, chunks, h, C, dv)
@@ -277,6 +316,14 @@ def kda_recurrent(q, k, v, g, beta, *, reset_every: int | None = None):
 
 
 def smallest_decay(g):
-    """The smallest `exp(g)` of a batch: exp(LOWER_BOUND) where a gate sits
-    at its bound, 1 where nothing ever decays."""
+    """The smallest `exp(g)` of a batch: exp(LOWER_BOUND) where a bounded
+    gate sits at its bound, 1 where nothing ever decays. Under an unbounded
+    gate it has no floor: 0 where some channel forgets its state at once
+    (g under -103, where float32's exp underflows)."""
     return jnp.exp(jnp.min(g.astype(jnp.float32)))
+
+
+def share_below_bound(g):
+    """The share of a batch's gate entries under `LOWER_BOUND`: those the
+    bounded form would have clamped (0 under a bounded gate)."""
+    return jnp.mean((g < LOWER_BOUND).astype(jnp.float32))

@@ -34,7 +34,13 @@ entering state, `lo` the rounding to q's dtype before a product):
     KK, QK   in sub-blocks of `SUB` = 16 rows against the sub-block's first
              position r: rows lo(k_i e^{G_i - G_r}), lo(q_i e^{G_i - G_r})
              against columns lo(k_j e^{min(G_r - G_j, CAP)}): no exponent is
-             formed that can be large (ops/kda.py, "The exponents")
+             formed that can be large (ops/kda.py, "The exponents"). Under
+             an unbounded gate CAP is 0 and these products keep only the
+             columns before the row's sub-block; the diagonal sub-blocks are
+             made pair by pair, offset by offset: at offset d, row i
+             against column i - d, sum_c k_ic k_{i-d,c} e^{G_ic - G_{i-d,c}}
+             (and q's), float32 on the vector unit, the earlier rows
+             brought down by a sublane roll (`_Chunk.band`)
     M^-1     = (I + Diag(beta) strictly-lower(KK))^-1, float32: forward
              substitution on the vector unit, a column a step, two heads to
              a register (`_inverses`); no product, no power
@@ -59,8 +65,10 @@ cotangent -M^-T dM^-1 M^-T is -d(beta R) U^T: one product of what is there.
 The reference point of a sub-block cancels in exp(G_i - G_r) exp(G_r - G_j),
 so dG is what the decayed copies give: q dq + k (dk of rows and of K e^G,
 less dk of columns and of K e^{G_last - G}), plus the chunk's last row's
-share; dg is its reversed running sum. Cotangents are rounded like the
-values they meet in a product.
+share; dg is its reversed running sum. The pairwise diagonal sub-blocks
+add to the same three: as rows what each row's pairs give, as columns what
+each column's give, rolled back up (`_Chunk.diagonal_grads`). Cotangents
+are rounded like the values they meet in a product.
 
 The running sums are products with a triangle of ones, g split three ways
 into bf16 parts that add up to the float32 value (exact: the ones are).
@@ -88,10 +96,15 @@ CHUNK = 64
 #: a register in the solve): the more, the more products of different heads
 #: stand side by side (PERF.md section 6, PR 35)
 HEADS = 16
+#: and under an unbounded gate, whose pairwise diagonal sub-blocks a head
+#: adds to the unrolled body: with 16 the backward asked the compiler for
+#: 74 MiB of VMEM, over the 64 the kernels may have
+UNBOUNDED_HEADS = 8
 
 
-def _heads_a_step(heads: int) -> int:
-    return max(n for n in range(2, HEADS + 1, 2) if heads % n == 0)
+def _heads_a_step(heads: int, bounded: bool = True) -> int:
+    most = HEADS if bounded else min(HEADS, UNBOUNDED_HEADS)
+    return max(n for n in range(2, most + 1, 2) if heads % n == 0)
 
 
 # grid (batch, block of chunks, group): the blocks carry the states, the
@@ -201,8 +214,10 @@ class _Chunk:
     another's result is on its way (a head at a time, the chain of a
     dozen dependent products was most of a grid step)."""
 
-    def __init__(self, q, k, g, beta, lower, row, col):
+    def __init__(self, q, k, g, beta, lower, row, col, diagonal=None):
         self.dtype = q.dtype
+        # None: the bounded form; else `_diagonals`: the pairwise one
+        self.diagonal = diagonal
         self.q32, self.k32 = q.astype(_F32), k.astype(_F32)
         self.beta = beta                                   # (C, 1)
         self.strict, self.seen = row > col, row >= col
@@ -221,25 +236,72 @@ class _Chunk:
         # the scores' operands, `SUB` rows at a time against the
         # sub-block's first position: rows decayed from it (<= 0), columns
         # back to it (<= 0 before the sub-block, at most (SUB - 1) x 5
-        # inside it; behind it the clamp, and the masks take the product)
+        # inside it under the bounded gate; behind it the clamp, and the
+        # masks take the product)
+        cap = _CAP if self.diagonal is None else 0.0
         self.row_decay, self.col_decay, self.rows, self.cols = [], [], [], []
         for at in range(0, CHUNK, SUB):
             first = G[at:at + 1]
             self.row_decay.append(jnp.exp(G[at:at + SUB] - first))
-            self.col_decay.append(jnp.exp(jnp.minimum(first - G, _CAP)))
+            self.col_decay.append(jnp.exp(jnp.minimum(first - G, cap)))
             self.rows.append(jnp.concatenate(
                 [lo(self.k32[at:at + SUB] * self.row_decay[-1]),
                  lo(self.q32[at:at + SUB] * self.row_decay[-1])], axis=0))
             self.cols.append(lo(self.k32 * self.col_decay[-1]))
+
+    def pair(self, d):
+        """(C, dk): e^{G_i - G_{i-d}} <= 1 in row i, at an offset d > 0;
+        rows whose column i - d lies in another sub-block, or wraps round,
+        are the masks'. Made again where it is used rather than kept (15
+        of them a head, for each of the group's heads)."""
+        return jnp.exp(jnp.minimum(self.G - pltpu.roll(self.G, d, 0), 0.0))
+
+    def band(self, d, pair=None):
+        """(C, dk): row i holds k_{i-d} e^{G_i - G_{i-d}}."""
+        if d == 0:
+            return self.k32
+        return pltpu.roll(self.k32, d, 0) * (
+            self.pair(d) if pair is None else pair)
 
     def scores(self):
         both = [_dot(rows, cols, _NT)                      # (2 SUB, C)
                 for rows, cols in zip(self.rows, self.cols)]
         self.kk = jnp.where(self.strict, jnp.concatenate(
             [b[:SUB] for b in both], axis=0), 0.0)
-        self.qk = jnp.where(self.seen, jnp.concatenate(
-            [b[SUB:] for b in both], axis=0), 0.0).astype(self.dtype)
+        qk = jnp.where(self.seen, jnp.concatenate(
+            [b[SUB:] for b in both], axis=0), 0.0)
+        if self.diagonal is not None:
+            before, offsets = self.diagonal
+            self.kk = jnp.where(before, self.kk, 0.0)
+            qk = jnp.where(before, qk, 0.0)
+            for d, at_d in enumerate(offsets):
+                band = self.band(d)
+                if d:                      # A is strictly lower triangular
+                    self.kk = jnp.where(at_d, jnp.sum(
+                        self.k32 * band, axis=1, keepdims=True), self.kk)
+                qk = jnp.where(at_d, jnp.sum(self.q32 * band, axis=1,
+                                             keepdims=True), qk)
+        self.qk = qk.astype(self.dtype)
         self.a = self.beta * self.kk
+
+    def diagonal_grads(self, d_kk, d_qk):
+        """What the pairwise diagonal sub-blocks give dk as rows, dq, and
+        dk as columns, from the scores' float32 cotangents (C, C)."""
+        _, offsets = self.diagonal
+        rows = qs = cols = 0.0
+        for d, at_d in enumerate(offsets):
+            d_kk_d = jnp.sum(jnp.where(at_d, d_kk, 0.0), axis=1,
+                             keepdims=True)                # (C, 1)
+            d_qk_d = jnp.sum(jnp.where(at_d, d_qk, 0.0), axis=1,
+                             keepdims=True)
+            pair = self.pair(d) if d else None
+            band = self.band(d, pair)
+            rows += d_kk_d * band
+            qs += d_qk_d * band
+            back = d_kk_d * self.k32 + d_qk_d * self.q32
+            # row i's share belongs to column i - d: rolled back up
+            cols += back if d == 0 else pltpu.roll(back * pair, CHUNK - d, 0)
+        return rows, qs, cols
 
     def read_state(self, inverse, state, v):
         self.inverse = inverse.astype(self.dtype)          # (C, C)
@@ -253,7 +315,8 @@ class _Chunk:
         self.u = _dot(self.inverse, self.weighed).astype(self.dtype)
 
 
-def _chunks(q_ref, k_ref, g_ref, rows, betas, first, lower, row, col):
+def _chunks(q_ref, k_ref, g_ref, rows, betas, first, lower, row, col,
+            diagonal=None):
     """The heads' chunks at `rows` of a grid step's block, as far as the
     entering states are not needed: scores and inverses. A stage for all
     heads, then the next."""
@@ -263,7 +326,8 @@ def _chunks(q_ref, k_ref, g_ref, rows, betas, first, lower, row, col):
         lanes = slice(x * LANES, (x + 1) * LANES)
         chunks.append(_Chunk(q_ref[rows, lanes], k_ref[rows, lanes],
                              g_ref[rows, lanes],
-                             _head_column(betas, first + x), lower, row, col))
+                             _head_column(betas, first + x), lower, row, col,
+                             diagonal))
     for c in chunks:
         c.decays()
     for c in chunks:
@@ -298,8 +362,18 @@ def _constants():
     return (row >= col).astype(jnp.bfloat16), row, col
 
 
+def _diagonals(row, col, bounded: bool):
+    """None for the bounded form; else (the columns before a row's
+    sub-block, [where column = row - d inside it, d = 0 .. SUB - 1])."""
+    if bounded:
+        return None
+    same = (row // SUB) == (col // SUB)
+    return (col // SUB) < (row // SUB), [same & (row - col == d)
+                                         for d in range(SUB)]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
-                save_states):
+                save_states, bounded):
     states_ref = rest[0] if save_states else None
     s_ref = rest[-1]                               # (h, dv, dk) float32
     heads = q_ref.shape[1] // LANES
@@ -310,11 +384,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
         s_ref[pl.ds(first, heads)] = jnp.zeros((heads, LANES, LANES), _F32)
 
     lower, row, col = _constants()
+    diagonal = _diagonals(row, col, bounded)
 
     def chunk(i, carry):
         rows = _rows(i)
         chunks, inverses = _chunks(q_ref, k_ref, g_ref, rows,
-                                   beta_ref[rows, :], first, lower, row, col)
+                                   beta_ref[rows, :], first, lower, row, col,
+                                   diagonal)
         states = [s_ref[first + x] for x in range(heads)]
         if save_states:
             for x, state in enumerate(states):
@@ -332,7 +408,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_ref):
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_ref, *,
+                bounded):
     heads = q_ref.shape[1] // LANES
     group = pl.program_id(2)
     first = group * heads
@@ -348,6 +425,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
         dbeta_ref[...] = jnp.zeros_like(dbeta_ref)
 
     lower, row, col = _constants()
+    diagonal = _diagonals(row, col, bounded)
     upper = (row <= col).astype(jnp.bfloat16)
     head_lane = lax.broadcasted_iota(jnp.int32, (CHUNK, beta_ref.shape[1]), 1)
     chunks_here = q_ref.shape[0] // CHUNK
@@ -357,7 +435,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
         rows = _rows(i)
         of = lambda x: slice(x * LANES, (x + 1) * LANES)
         chunks, inverses = _chunks(q_ref, k_ref, g_ref, rows,
-                                   beta_ref[rows, :], first, lower, row, col)
+                                   beta_ref[rows, :], first, lower, row, col,
+                                   diagonal)
         entering = [states_ref[i, x] for x in range(heads)]
         _enter(chunks, inverses, entering, v_ref, rows)
         d_o = [lo(do_ref[rows, of(x)]) for x in range(heads)]
@@ -386,10 +465,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
         d_a = [jnp.where(c.strict, -_dot(lo(d), c.u, _NT), 0.0)
                for c, d in zip(chunks, d_weighed)]
         d_kk = [c.beta * d for c, d in zip(chunks, d_a)]
-        # the scores' sub-blocks
+        # the scores' sub-blocks (under an unbounded gate the products'
+        # columns before a row's sub-block only: its own are pairwise)
+        split_kk, split_qk = d_kk, d_qk
+        if diagonal is not None:
+            split_kk, split_qk = ([jnp.where(diagonal[0], d, 0.0) for d in ds]
+                                  for ds in (d_kk, d_qk))
         d_both = [[jnp.concatenate([lo(dk[at:at + SUB]), lo(dq[at:at + SUB])],
                                    axis=0) for at in range(0, CHUNK, SUB)]
-                  for dk, dq in zip(d_kk, d_qk)]
+                  for dk, dq in zip(split_kk, split_qk)]
         d_rows = [[_dot(d, cols) for d, cols in zip(ds, c.cols)]
                   for ds, c in zip(d_both, chunks)]        # (2 SUB, dk) each
         d_cols = [[_dot(d, rows_, _TN) for d, rows_ in zip(ds, c.rows)]
@@ -405,6 +489,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
             d_k_cols = d_k_out[x] * c.to_end
             for d, decay in zip(d_cols[x], c.col_decay):
                 d_k_cols += d * decay
+            if diagonal is not None:
+                rows_, qs, cols = c.diagonal_grads(d_kk[x], d_qk[x])
+                d_k_rows, d_q, d_k_cols = (d_k_rows + rows_, d_q + qs,
+                                           d_k_cols + cols)
             dq_ref[rows, of(x)] = d_q.astype(dq_ref.dtype)
             dk_ref[rows, of(x)] = (d_k_rows + d_k_cols).astype(dk_ref.dtype)
             dv_ref[rows, of(x)] = d_error[x].astype(dv_ref.dtype)
@@ -436,8 +524,8 @@ def _chunks_a_step(chunks: int) -> int:
     return max(n for n in range(1, CHUNKS + 1) if chunks % n == 0)
 
 
-def _specs(h, blocks, per, *, backward):
-    heads = _heads_a_step(h)
+def _specs(h, blocks, per, *, backward, bounded):
+    heads = _heads_a_step(h, bounded)
     """Block specs by name for a grid (batch, block of `per` chunks,
     group), the blocks walked from the last to the first where
     `backward`."""
@@ -451,11 +539,11 @@ def _specs(h, blocks, per, *, backward):
                                lambda bi, ci, gi: (bi, at(ci), gi, 0, 0))}
 
 
-def _forward(q, k, v, g, beta, save_states):
+def _forward(q, k, v, g, beta, save_states, bounded):
     b, t, width = q.shape
     h, c = width // LANES, t // CHUNK
     per = _chunks_a_step(c)
-    spec = _specs(h, c // per, per, backward=False)
+    spec = _specs(h, c // per, per, backward=False, bounded=bounded)
     out_shape = [jax.ShapeDtypeStruct((b, t, width), _F32)]
     out_specs = [spec["wide"]]
     if save_states:
@@ -463,8 +551,9 @@ def _forward(q, k, v, g, beta, save_states):
             jax.ShapeDtypeStruct((b, c, h, LANES, LANES), _F32))
         out_specs.append(spec["states"])
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, save_states=save_states),
-        grid=(b, c // per, h // _heads_a_step(h)),
+        functools.partial(_fwd_kernel, save_states=save_states,
+                          bounded=bounded),
+        grid=(b, c // per, h // _heads_a_step(h, bounded)),
         in_specs=[spec["wide"]] * 4 + [spec["heads"]],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((h, LANES, LANES), _F32)],
@@ -473,28 +562,29 @@ def _forward(q, k, v, g, beta, save_states):
     return out if save_states else out[0]
 
 
-@jax.custom_vjp
-def _core(q, k, v, g, beta):
-    return _forward(q, k, v, g, beta, False)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _core(q, k, v, g, beta, bounded):
+    return _forward(q, k, v, g, beta, False, bounded)
 
 
-def _core_fwd(q, k, v, g, beta):
-    o, states = _forward(q, k, v, g, beta, True)
+def _core_fwd(q, k, v, g, beta, bounded):
+    o, states = _forward(q, k, v, g, beta, True, bounded)
     return o, (q, k, v, g, beta, states)
 
 
-def _core_bwd(residuals, d_o):
+def _core_bwd(bounded, residuals, d_o):
     q, k, v, g, beta, states = residuals
     b, t, width = q.shape
     h, c = width // LANES, t // CHUNK
     per = _chunks_a_step(c)
-    spec = _specs(h, c // per, per, backward=True)
+    spec = _specs(h, c // per, per, backward=True, bounded=bounded)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
     # the name `chunked` opens around the forward: this function is traced
     # outside it (the row `kda_core` reads both passes)
     with jax.named_scope("kda_core"):
         return tuple(pl.pallas_call(
-            _bwd_kernel, grid=(b, c // per, h // _heads_a_step(h)),
+            functools.partial(_bwd_kernel, bounded=bounded),
+            grid=(b, c // per, h // _heads_a_step(h, bounded)),
             in_specs=[spec["wide"]] * 4 + [spec["heads"], spec["states"],
                                            spec["wide"]],
             out_specs=[spec["wide"]] * 4 + [spec["heads"]],
@@ -507,9 +597,10 @@ def _core_bwd(residuals, d_o):
 _core.defvjp(_core_fwd, _core_bwd)
 
 
-@jax.jit
-def chunked(q, k, v, g, beta):
-    """`kda.kda`'s arguments at sizes `applies` admits (chunks of 64). One
+@functools.partial(jax.jit, static_argnames="bounded")
+def chunked(q, k, v, g, beta, bounded: bool = True):
+    """`kda.kda`'s arguments at sizes `applies` admits (chunks of 64);
+    `bounded` the form of the diagonal sub-blocks (ops/kda.py). One
     jitted function, so that the layers of a model share one trace and
     lowering of each kernel (PERF.md section 6, PR 33 and PR 35). JAX
     lowers such a function once, apart from its call sites, so what is
@@ -519,5 +610,5 @@ def chunked(q, k, v, g, beta):
     flat = lambda x: x.reshape(b, t, h * x.shape[-1])
     with jax.named_scope("kda_core"):
         o = _core(flat(q), flat(k), flat(v), flat(g.astype(_F32)),
-                  beta.astype(_F32))
+                  beta.astype(_F32), bounded)
         return o.reshape(b, t, h, v.shape[-1])

@@ -55,6 +55,14 @@ HYBRID_LM_LAYERS = ("ssm_in", "ssm_conv", "ssm_scan", "ssm_gate_out",
 LING_LM_LAYERS = ("kda_qkv", "kda_conv", "kda_gates", "kda_core", "kda_out",
                   "mlp_dense")
 
+#: The layer of models/solar_open2.py that the lists above lack (its KDA
+#: layers are `LING_LM_LAYERS`' `kda_*`, its attention `HYBRID_LM_LAYERS`'
+#: `gqa_*`): the gated attention's output gate, `gqa_gate`
+#: (models/nemotron_h.py `GroupedQueryAttention` with `output_gate`). Its
+#: cell's traces are reduced by a fifth file, `chipbench/
+#: solar_lm_scopes.json`.
+SOLAR_LM_LAYERS = ("gqa_gate",)
+
 # ---------------------------------------------------------------------------
 # Host spans of set-up: names on the telemetry ring (`telemetry.span`), not
 # on the device. `chipbench/host_spans.json` is the benchmark's copy, which

@@ -338,7 +338,7 @@ def validate_augment_block(block: Any, where: str,
 #: models/ingest.INGEST_DESCRIPTORS — duplicated as a literal so this
 #: module stays a leaf; the drift is guarded by test).
 _ZOO_MODELS = ("vggf", "vgg16", "resnet50", "vit_s16", "vggf_student",
-               "mistral4", "nemotron_h", "ling3")
+               "mistral4", "nemotron_h", "ling3", "solar_open2")
 
 
 # ---------------------------------------------------------------------- comm

@@ -1178,6 +1178,17 @@ class Trainer:
                                 reg.set_gauge(
                                     "kda/conv_kernel_layers",
                                     over_layers("kda_conv_kernel/"))
+                                # Kimi's own gate and beta in (0, 2)
+                                # (models/solar_open2.py): the largest share
+                                # of gate entries under -5, the largest beta
+                                for gauge, metric in (
+                                        ("kda/gate_below_bound",
+                                         "kda_gate_below_bound/"),
+                                        ("kda/beta_max", "kda_beta_max/")):
+                                    seen = [v for k, v in last_metrics.items()
+                                            if k.startswith(metric)]
+                                    if seen:
+                                        reg.set_gauge(gauge, max(seen))
                             shares = [v for k, v in last_metrics.items()
                                       if k.startswith("moe_group_share/")]
                             if shares:

@@ -330,6 +330,32 @@ def test_delta_rule_core_compiles_at_the_cell_s_widths(chip, monkeypatch):
     assert temporaries < 1.5, f"{temporaries:.2f} GiB of temporaries"
 
 
+def test_unbounded_delta_rule_compiles_at_solar_s_widths(chip, monkeypatch):
+    """The unbounded form of `ops/kda.py` (Kimi's softplus gate: the
+    diagonal sub-blocks pair by pair) at `solar_open2_250b_ep40`'s widths
+    (4,096 positions, 64 heads of 128, chunks of 64, bf16) with its five
+    gradients: the two Pallas kernels, whose backward with 16 heads a grid
+    step asked for 74 MiB of VMEM against the 64 it may have (the compile
+    refuses that), and the states entering each chunk once."""
+    from distributed_vgg_f_tpu.ops import kda
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, t, h, d = 1, 4096, 64, 128
+    arg = lambda shape, kind: jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda.kda(q, k, v, g, beta, bounded=False))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((b, t, h, d), jnp.bfloat16), arg((b, t, h, d), jnp.bfloat16),
+        arg((b, t, h, d), jnp.bfloat16), arg((b, t, h, d), jnp.float32),
+        arg((b, t, h), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"= .*custom-call.*tpu_custom_call", text)) == 2
+    kinds = set(re.findall(r"\w+\[[\d,]+\]", text))
+    states = {kind for kind in kinds if _elements(kind) >= b * t * h * d * 2}
+    assert states == {"f32[1,64,64,128,128]"}, states
+
+
 def _short_conv_with_gradients(chip, monkeypatch, *, b, t, heads, width,
                                dtype, scale):
     """`short_conv.conv_silu_heads` with its output and both gradients,

@@ -6,6 +6,8 @@ recurrence, at the gate's bound too; that the state is handed from chunk to
 chunk; which shapes and backends take them; and a whole
 `KimiDeltaAttention` layer through them against the plain reference."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -97,6 +99,90 @@ def test_a_gate_at_its_bound_stays_finite_and_equals_the_recurrence(
     for name, a, b in zip("qkvgb", d_got, d_want):
         # g's gradient is a difference of large terms at the bound
         assert _rel(a, b) < (1e-3 if name == "g" else TOLERANCE), name
+
+
+#: gates a position from 0 down to where the bounded form no longer holds,
+#: (b, t, h, dk) from a key: Kimi Linear's softplus gate at a seeded
+#: decay rate of up to 16 (its tail near -60), gates spread down to -60,
+#: and gates that forget at once (-1e4 at a tenth of the entries: exp
+#: underflows to 0, and nothing may turn into a NaN)
+GATES = {
+    "softplus": lambda key, shape: -jnp.exp(jax.random.uniform(
+        key, shape[2:3] + (1,), minval=0.0, maxval=jnp.log(16.0)))
+    * jax.nn.softplus(2 * jax.random.normal(key, shape)),
+    "to_minus_60": lambda key, shape: -60.0 * jax.random.uniform(key, shape),
+    "minus_1e4": lambda key, shape: jnp.where(
+        jax.random.uniform(key, shape) < 0.1, -1e4,
+        -0.3 * jax.random.uniform(jax.random.fold_in(key, 1), shape)),
+}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("gate", sorted(GATES))
+def test_unbounded_gate_and_doubled_beta_equal_the_recurrence(
+        impl, gate, monkeypatch):
+    """The unbounded form (`bounded=False`) against the literal recurrence,
+    values and the five gradients, float32, over three chunks, with beta
+    in (0, 2) (negative eigenvalues); the bounded form on the same gates is
+    not the recurrence. At -1e4 the running sums of g near -6e4 hold 4e-3
+    of absolute rounding, which the pairwise decays of small gates carry
+    (2e-3 measured, the XLA form's dg 1e-2): its tolerance is wider."""
+    if impl == "pallas":
+        monkeypatch.setattr(kda_pallas, "INTERPRET", True)
+        monkeypatch.setattr(kda_pallas, "HEADS", 2)
+        monkeypatch.setattr(kda_pallas, "CHUNKS", 2)
+    keys = jax.random.split(jax.random.key(21), 2)
+    shape = (1, 192, HEADS, WIDTH)
+    args = _inputs(seq=192, g=GATES[gate](keys[0], shape),
+                   beta=2 * jax.nn.sigmoid(jax.random.normal(keys[1],
+                                                             shape[:3])))
+    unbounded = functools.partial(
+        kda.kda if impl == "pallas" else kda.kda_xla, bounded=False)
+    assert kda.takes_kernels(args[1].shape, args[2].shape, 64) \
+        == (impl == "pallas")
+    got, d_got = _value_and_grads(unbounded, args)
+    want, d_want = _value_and_grads(kda.kda_recurrent, args)
+    limit = 2e-2 if gate == "minus_1e4" else TOLERANCE
+    assert np.isfinite(float(got))
+    assert abs(float(got) - float(want)) < limit * abs(float(want))
+    for name, a, b in zip("qkvgb", d_got, d_want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert _rel(a, b) < limit, name
+    if gate == "softplus":
+        _, d_bounded = _value_and_grads(kda.kda_xla, args)
+        assert max(_rel(a, b) for a, b in zip(d_bounded, d_want)) \
+            > 100 * TOLERANCE
+
+
+def test_the_bounded_form_gives_what_it_gave_before(monkeypatch):
+    """Ling's path (`bounded`, the default) is the parent's program: the
+    digest of the kernels' (in the interpreter) and the XLA form's values
+    and gradients on these seeded bf16 inputs, to the last bit, as the
+    implementation before the unbounded form gave them."""
+    import hashlib
+    monkeypatch.setattr(kda_pallas, "INTERPRET", True)
+    monkeypatch.setattr(kda_pallas, "HEADS", 2)
+    monkeypatch.setattr(kda_pallas, "CHUNKS", 3)
+    shape = (1, 192, 4, WIDTH)
+    keys = jax.random.split(jax.random.key(7), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    args = ((unit(jax.random.normal(keys[0], shape)) * WIDTH ** -0.5
+             ).astype(jnp.bfloat16),
+            unit(jax.random.normal(keys[1], shape)).astype(jnp.bfloat16),
+            jax.random.normal(keys[2], shape).astype(jnp.bfloat16),
+            kda.LOWER_BOUND * jax.nn.sigmoid(
+                2 * jax.random.normal(keys[3], shape)),
+            jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3])))
+    weigh = jax.random.normal(keys[5], shape)
+    for fn, before in ((kda.kda, "20abdbc8ae232a53"),
+                       (kda.kda_xla, "cb7f45aff674337d")):
+        out = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a) * weigh), argnums=(0, 1, 2, 3, 4)))(
+                *args)
+        digest = hashlib.sha1()
+        for x in jax.tree.leaves(out):
+            digest.update(np.asarray(x).tobytes())
+        assert digest.hexdigest()[:16] == before, fn.__name__
 
 
 def test_nothing_written_gives_exactly_nothing(interpreted):

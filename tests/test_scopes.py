@@ -77,7 +77,8 @@ def lowered():
             "vit_s16": _lowered_forward("vit_s16", depth=1),
             "mistral4": _lowered_token_step(),
             "nemotron_h": _lowered_token_step("nemotron3_nano_tiny"),
-            "ling3": _lowered_token_step("ling3_flash_tiny")}
+            "ling3": _lowered_token_step("ling3_flash_tiny"),
+            "solar_open2": _lowered_token_step("solar_open2_tiny")}
 
 
 def _stacks(text: str) -> set:
@@ -100,16 +101,20 @@ HOME = {**{name: "vggf" for name in scopes.PHASES},
         "embed_tokens": "vit_s16",
         **{name: "mistral4" for name in scopes.LM_LAYERS},
         **{name: "nemotron_h" for name in scopes.HYBRID_LM_LAYERS},
-        **{name: "ling3" for name in scopes.LING_LM_LAYERS}}
+        **{name: "ling3" for name in scopes.LING_LM_LAYERS},
+        **{name: "solar_open2" for name in scopes.SOLAR_LM_LAYERS}}
 
 
 def test_every_declared_name_has_a_home():
     assert set(HOME) == set(scopes.PHASES) | set(scopes.LAYERS) \
         | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS) \
-        | set(scopes.LING_LM_LAYERS)
+        | set(scopes.LING_LM_LAYERS) | set(scopes.SOLAR_LM_LAYERS)
     assert not (set(scopes.PHASES) | set(scopes.LAYERS)
                 | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS)) \
         & set(scopes.LING_LM_LAYERS)
+    assert not (set(scopes.PHASES) | set(scopes.LAYERS)
+                | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS)
+                | set(scopes.LING_LM_LAYERS)) & set(scopes.SOLAR_LM_LAYERS)
     assert not set(scopes.PHASES) & set(scopes.LAYERS)
     assert not (set(scopes.PHASES) | set(scopes.LAYERS)) \
         & set(scopes.LM_LAYERS)
@@ -119,7 +124,7 @@ def test_every_declared_name_has_a_home():
 
 @pytest.mark.parametrize("name", scopes.PHASES + scopes.LAYERS
                          + scopes.LM_LAYERS + scopes.HYBRID_LM_LAYERS
-                         + scopes.LING_LM_LAYERS)
+                         + scopes.LING_LM_LAYERS + scopes.SOLAR_LM_LAYERS)
 def test_declared_name_reaches_the_lowered_program(lowered, name):
     assert _holds(_stacks(lowered[HOME[name]]), name)
 
@@ -161,6 +166,39 @@ def test_the_hybrid_model_s_names_file_equals_the_declared_lists(lowered):
     assert scope_reduce.scope_of(
         f"jit(train_step)/jvp({loss})/NemotronHLM.hidden/checkpoint/layer_0/"
         "norm/mul:", names) == ("checkpoint/layer_0/norm", False)
+
+
+def test_the_solar_model_s_names_file_equals_the_declared_lists(lowered):
+    """`chipbench/solar_lm_scopes.json`, the benchmark's own copy: its
+    layers are Solar-Open2's own name (`gqa_gate`) and the ones it shares
+    (Kimi Delta Attention's, grouped-query attention's, the expert
+    share's, the embedding, the head), each of which reaches that model's
+    lowered step, forward and backward; its phases are the first names
+    file's."""
+    import json
+    from chipbench import scope_reduce
+    here = os.path.dirname(scope_reduce.__file__)
+    with open(os.path.join(here, "solar_lm_scopes.json")) as f:
+        names = json.load(f)
+    kda = {n for n in scopes.LING_LM_LAYERS if n.startswith("kda_")}
+    moe = {n for n in scopes.LM_LAYERS if n.startswith("moe_")}
+    assert set(names["layers"]) == set(scopes.SOLAR_LM_LAYERS) | kda | moe \
+        | {n for n in scopes.HYBRID_LM_LAYERS if n.startswith("gqa_")} \
+        | {"embed_tokens", "lm_head"}
+    assert names["phases"] == scope_reduce.declared()["phases"]
+    assert set(names["kda"]) == kda and set(names["moe"]) == moe
+    assert set(scopes.SOLAR_LM_LAYERS) <= set(names["gqa"])
+    for group in ("kda_core", "gqa_core", "moe_experts"):
+        assert names[group] == [group]
+    stacks = _stacks(lowered["solar_open2"])
+    for name in names["layers"]:
+        assert _holds(stacks, name), name
+    for name in ("kda_core", "kda_gates", "gqa_core", "gqa_gate",
+                 "moe_experts"):
+        assert any(s.endswith(name) and "transpose(" not in s
+                   for s in stacks), name
+        assert any(s.endswith(name) and "transpose(" in s
+                   for s in stacks), name
 
 
 def test_the_ling_model_s_names_file_equals_the_declared_lists(lowered):
@@ -458,7 +496,7 @@ def test_call_sites_and_declared_lists_agree():
     found = (found - {"pool{b}"}) | {f"pool{b}" for b in range(1, 6)}
     assert found == set(scopes.PHASES) | set(scopes.LAYERS) \
         | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS) \
-        | set(scopes.LING_LM_LAYERS)
+        | set(scopes.LING_LM_LAYERS) | set(scopes.SOLAR_LM_LAYERS)
 
 
 def test_host_span_call_sites_and_declared_lists_agree():
