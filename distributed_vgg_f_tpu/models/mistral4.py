@@ -40,7 +40,9 @@ the whole expert share (`route`, `routed_capacity`, `routed_passes`,
 `ExpertShare`), which takes the scoring (`softmax` | `sigmoid`, the latter
 with a selection bias and a scale) and the expert's function (`swiglu`:
 gate, up, down | `relu2`: up, down) as static arguments. The windows, the
-sort, the grouped products and the counters are one code path for both.
+sort, the grouped products and the counters are one code path for both; a
+window's rows go back to their tokens through ops/routed_combine.py (a
+Pallas kernel on the chip at whole tiles, XLA's scatter-add elsewhere).
 """
 
 from __future__ import annotations
@@ -53,6 +55,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from distributed_vgg_f_tpu.ops import routed_combine
 
 
 # ---- rotary position embedding ---------------------------------------------
@@ -367,12 +371,10 @@ def routed_experts(x, mats, weights, order, load, capacity: int,
         with jax.named_scope("moe_experts"):
             outs = jax.lax.ragged_dot(
                 _hidden(rows, *ins, sizes=sizes, expert=expert), down, sizes)
-        with jax.named_scope("moe_combine"):
-            return routed.at[token].add(
-                jnp.where(live, outs, 0).astype(jnp.float32) * weight,
-                mode="promise_in_bounds")
+        return routed_combine.add_weighted(routed, outs, weight, token, live,
+                                           first=c == 0)
     return jax.lax.fori_loop(0, routed_passes(load, capacity), one,
-                             jnp.zeros(x.shape, jnp.float32))
+                             routed_combine.empty_sum(x, capacity))
 
 
 def _routed_forward(x, mats, weights, order, load, capacity, expert):
@@ -410,9 +412,7 @@ def _routed_backward(capacity, expert, inputs, d_routed):
                 jnp.where(live, q * h.astype(jnp.float32), 0), axis=-1)
             d_weights = jnp.zeros(weights.size, weights.dtype).at[picked].add(
                 d_weight, mode="promise_in_bounds").reshape(weights.shape)
-        with jax.named_scope("moe_dispatch"):
-            d_x = jnp.zeros_like(x).at[token].add(
-                jnp.where(live, d_rows, 0), mode="promise_in_bounds")
+        d_x = routed_combine.to_tokens(d_rows, token, live, x)
         return (d_x, *d_ins, d_down, d_weights)
 
     def one_more(c, so_far):
@@ -538,6 +538,10 @@ class ExpertShare(nn.Module):
         # (`mutable=["counters"]`): 1 pass = the compact buffers held all
         self.sow("counters", "passes", routed_passes(load, capacity))
         self.sow("counters", "capacity", capacity)
+        # 1 = both directions handed the rows back through the kernel of
+        # ops/routed_combine_pallas.py, 0 = through XLA's scatter-add
+        self.sow("counters", "combine_kernel", int(routed_combine.takes_kernel(
+            rows_in.shape, rows_in.dtype, capacity)))
         with jax.named_scope("moe_shared"):
             shared_width = self.shared_intermediate_size \
                 or width * self.n_shared_experts

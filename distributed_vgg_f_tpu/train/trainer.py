@@ -1140,6 +1140,12 @@ class Trainer:
                                 reg.set_gauge(gauge, max(
                                     v for k, v in last_metrics.items()
                                     if k.startswith(metric)))
+                            # the expert layers whose rows went back to
+                            # their tokens through the Pallas kernel
+                            # (ops/routed_combine_pallas.py)
+                            reg.set_gauge("moe/combine_kernel_layers", sum(
+                                v for k, v in last_metrics.items()
+                                if k.startswith("moe_combine_kernel/")))
                             # the state-space layers' receipts
                             # (models/nemotron_h.py): chunks scanned in the
                             # step, the smallest decay of any layer, and

@@ -253,7 +253,8 @@ def test_a_step_through_the_trainer_publishes_the_routed_passes():
     """One logged step of the tiny preset with telemetry on: the step's
     metrics carry each layer's passes and the trainer's gauges their
     largest, beside the rows the routed buffers hold (every expert is held
-    there: all 128 assignments, one pass)."""
+    there: all 128 assignments, one pass) and the layers whose rows went
+    back through the kernel (none on the CPU)."""
     from distributed_vgg_f_tpu import telemetry
     cfg = apply_overrides(TINY, {"train.steps": 1, "train.log_every": 1})
     mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
@@ -275,6 +276,9 @@ def test_a_step_through_the_trainer_publishes_the_routed_passes():
         == assignments * cfg.model.extra["num_hidden_layers"]
     assert gauges["moe/dropped_assignments"] == 0
     assert "moe_passes/layer_1=1" in stream.getvalue()
+    # hidden of 64 is no lane tile: XLA's scatter-add in every layer
+    assert gauges["moe/combine_kernel_layers"] == 0
+    assert "moe_combine_kernel/layer_1=0" in stream.getvalue()
 
 
 # ---- rotary embedding against hand values ----------------------------------
