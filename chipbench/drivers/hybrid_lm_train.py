@@ -5,15 +5,19 @@ followed by `reference/hybrid_lm_step.py`), the counts (`counts`: a module
 of `chipbench/`), the trace's names (`scopes`: a file of `chipbench/`) and
 the keys the reference reads (`arch_keys`) all taken from that file. A
 further language model costs a configuration, a reference and a names file,
-not a driver.
+not a driver: also one with no routed experts (the reference's
+`expert_layers` empty: no load is read and no routing check made) or with
+a head tied to its embedding (`tie_word_embeddings` among `arch_keys`:
+`reference/hybrid_lm_step.py`).
 
 Mode `step`, the set-up, the window and what `correct` holds are
 `lm_train`'s (its docstring): one batch of packed tokens from `--seed`,
 `trainer.train_step` back to back, at most `IN_FLIGHT` steps ahead, the
 loss fetched every `train.log_every` steps and at the end; the first
 `CHECK_STEPS` steps against the reference's by `compare.judge` and the
-cell's `limits`, `expert_load_diff` against the cell's `load_diff_limit`,
-`dropped_assignments` = 0, `compiles_in_window` = 0.
+cell's `limits`, `expert_load_diff` against the cell's `load_diff_limit`
+and `dropped_assignments` = 0 where the model has expert layers,
+`compiles_in_window` = 0.
 
 Two things `lm_train` has no need of. The seed's weights pass through the
 file's `init_from_uniform` after `inputs.make_params`: a leaf it names
@@ -68,17 +72,24 @@ def names(config: dict) -> dict:
 
 def arch_of(config: dict) -> dict:
     """The architecture as the reference and the counts take it: the
-    file's published widths (the pattern as it is run), and the router at
-    its published width (the file's own `n_routed_experts` is the experts
-    held)."""
-    return {**{k: config[k] for k in config["arch_keys"]},
-            "n_routed_experts": config["published"]["n_routed_experts"]}
+    file's published widths (the pattern as it is run), and, for a model
+    with routed experts, the router at its published width (the file's own
+    `n_routed_experts` is the experts held)."""
+    arch = {k: config[k] for k in config["arch_keys"]}
+    if "n_routed_experts" in config["published"]:
+        arch["n_routed_experts"] = config["published"]["n_routed_experts"]
+    return arch
+
+
+def expert_layers(config: dict) -> list:
+    """The layers with routed experts, as the reference reads them."""
+    return reference_of(config).expert_layers(arch_of(config))
 
 
 def recipe_of(cfg, config: dict) -> dict:
     """The configuration file's recipe, after checking that the program's
     preset states the same numbers, the same cut and every published
-    width."""
+    width. A model without expert layers needs no `first_expert`."""
     recipe = dict(config["recipe"])
     extra = dict(cfg.model.extra)
     stated = {
@@ -89,8 +100,9 @@ def recipe_of(cfg, config: dict) -> dict:
         "schedule": cfg.optim.schedule,
         "seq_len": extra["seq_len"],
         "compute_dtype": cfg.model.compute_dtype,
-        "first_expert": extra.get("first_expert", 0),
     }
+    if "first_expert" in recipe or expert_layers(config):
+        stated["first_expert"] = extra.get("first_expert", 0)
     differ = {k: (recipe.get(k), v) for k, v in stated.items()
               if recipe.get(k) != v}
     held = {"num_hidden_layers": len(extra["hybrid_override_pattern"])
@@ -199,11 +211,13 @@ def first_steps(trainer, cfg, config: dict, seed: int, *, fault=None) -> dict:
     elif fault is not None and fault not in REFERENCE_FAULTS:
         raise ValueError(f"no fault {fault!r} to plant")
 
+    routed = bool(expert_layers(config))
     got = {"losses": [], "loads": [], "dropped": []}
     for i in range(CHECK_STEPS):
         state, metrics = step_fn(state, batch, rng)
         got["losses"].append(metrics["loss"])
-        got["loads"].append(metrics["moe_load"])
+        if routed:
+            got["loads"].append(metrics["moe_load"])
         got["dropped"].append(sum(v for k, v in metrics.items()
                                   if k.startswith("moe_dropped/")))
         if i == 0:
@@ -229,7 +243,8 @@ def follow_reference(config: dict, cfg, recipe: dict, shapes, seed: int,
                          config["vocab_size"])["tokens"]
     return hybrid_lm_step.follow(
         reference_of(config), arch_of(config),
-        (recipe["first_expert"], config["n_routed_experts"]), recipe,
+        (recipe.get("first_expert", 0), config.get("n_routed_experts", 0)),
+        recipe,
         lambda group: make(word, group), list(shapes),
         jax.numpy.asarray(tokens), steps=CHECK_STEPS,
         probes=config["probe_leaves"],
@@ -238,7 +253,11 @@ def follow_reference(config: dict, cfg, recipe: dict, shapes, seed: int,
 
 def routing_checks(got: dict, want: dict, cell: dict) -> list:
     """`lm_train.routing_checks`, `expert_load_diff` held to the cell's own
-    `load_diff_limit` (that module's constant is its own cell's reading)."""
+    `load_diff_limit` (that module's constant is its own cell's reading);
+    none where the reference's load tables have no row (a model without
+    expert layers), where both would read 0 by construction."""
+    if not np.shape(want["loads"])[1]:
+        return []
     checks = lm_train.routing_checks(got, want)
     limit = float(cell["load_diff_limit"])
     checks[0].update(limit=limit, ok=checks[0]["value"] <= limit)
@@ -340,7 +359,8 @@ def _run_step(ctx, trainer, cfg, compiles) -> dict:
     _say(f"assignments held a layer (the reference's routing): {held}")
     lm = {"arch": arch_of(config), "layers": config["num_hidden_layers"],
           "vocab_rows": config["vocab_size"],
-          "experts_held": config["n_routed_experts"], "seq_len": seq_len,
+          "experts_held": config.get("n_routed_experts", 0),
+          "seq_len": seq_len,
           "rows": rows, "assignments_held": held}
     facts = {"trace_dir": trace_dir if traced else None, "traced": traced,
              "chips": chips, "device_kind": device["kind"],

@@ -10,7 +10,8 @@ seeds also the control (the reference computed in fp8, put in the
 program's place) and the faults against the same reference (the upper
 readings): `half_batch`; `state_unchanged`, which needs no run; and the
 reference module's own (`chunk_reset`: the state-space layers' state set to
-zero at every chunk boundary). Every row is also judged as a run judges it
+zero at every chunk boundary). A model without expert layers has no
+`expert_load_diff`. Every row is also judged as a run judges it
 (`correct`). One JSON object per line on standard output, a summary last;
 the exit code is 1 where a program row is not correct or a control or
 fault row is.
@@ -58,7 +59,8 @@ def readings(cell: dict, config: dict, seeds, controls: int,
             gaps = compare.training_gaps(gave, want, config["probe_leaf"])
             checks = compare.judge(gaps, cell["limits"]) \
                 + driver.routing_checks(gave, want, cell)
-            gaps["expert_load_diff"] = (checks[-2]["value"], "")
+            gaps.update((c["name"], (c["value"], "")) for c in checks
+                        if c["name"] == "expert_load_diff")
             ok = all(c["ok"] for c in checks)
             done = correct.setdefault(side, [0, 0])
             done[0] += ok
@@ -74,7 +76,7 @@ def readings(cell: dict, config: dict, seeds, controls: int,
                 worst[side][k] = (min(lo, v), max(hi, v))
     emit(json.dumps({"summary_min_max": worst, "correct_of_rows": correct,
                      "limits": cell["limits"],
-                     "load_diff_limit": cell["load_diff_limit"]}))
+                     "load_diff_limit": cell.get("load_diff_limit")}))
     return {"worst": worst, "correct": correct}
 
 

@@ -15,12 +15,23 @@ One step, as the configuration states it (key `recipe`):
 Layers of one kind (one letter of `arch["hybrid_override_pattern"]`)
 share their compiled forward and backward programs.
 
+Two kinds of stack besides. One with no expert layer (`expert_layers`
+empty; `share` then holds 0 experts and `block` gives an empty load):
+each step's load table is empty, (0, experts held). One whose head is its
+embedding's transpose (`arch["tie_word_embeddings"]` true, no "lm_head"
+group): the head is computed with the held vocabulary rows
+`embed/embedding`, transposed, its gradient is held until the lookup's
+is added to it, and the sum gets one SGD-momentum update of that one
+matrix with one momentum trace, as `optax.sgd` updates a single leaf; the
+head and the lookup both use the matrix as it stood at the step's start.
+
 How it fits the chip is `lm_step.py`'s: the gradient is never whole. The
 forward pass keeps each layer's input; the backward pass takes one layer's
 `vjp` at a time (its forward made again inside) and updates that layer's
 momentum and weights in place at once. Per group of leaves ("embed",
-"layer_0", ..., "norm", "lm_head") the follower keeps the gradient's
-per-leaf norms, and the gradient itself only for the `probes`.
+"layer_0", ..., "norm", and "lm_head" where the head is untied) the
+follower keeps the gradient's per-leaf norms, and the gradient itself only
+for the `probes`.
 
 `mode="fp8"` is the control (`ops.Ops`); `fault="half_batch"` repeats the
 first half of every sequence in place of its second half; any other fault
@@ -43,8 +54,8 @@ def make_steps(model, arch: dict, share, recipe: dict, probes, *,
                mode: str = "float32", block_rows: int = 512,
                fault: str | None = None):
     """The jitted pieces of one step: `forward(p, x, layer)`, `head(...)`,
-    `backward(..., layer)`, `embed(...)`; each updating call donates the
-    weights and momentum it replaces."""
+    `tied_head(...)`, `backward(..., layer)`, `embed(...)`; each updating
+    call donates the weights and momentum it replaces."""
     ops = Ops(mode)
     lr, mu = recipe["base_lr"] * recipe["global_batch"] \
         / recipe["reference_batch"], recipe["momentum"]
@@ -85,9 +96,22 @@ def make_steps(model, arch: dict, share, recipe: dict, probes, *,
         return p_norm, t_norm, p_head, t_head, loss, dx, g_norm, g_head
 
     @partial(jax.jit, donate_argnums=(0, 1))
-    def embed(p, trace, tokens, dx):
-        grads = {"embedding": jnp.zeros_like(p["embedding"]).at[
-            tokens.reshape(-1)].add(dx.reshape(-1, dx.shape[-1]))}
+    def tied_head(p_norm, t_norm, embedding, x, targets):
+        """`head` with the embedding's transpose as its kernel: the head's
+        gradient is returned as the embedding's, not applied."""
+        loss, (g_norm, g_head, dx) = jax.value_and_grad(
+            head_loss, argnums=(0, 1, 2))(
+                p_norm, {"kernel": embedding.T}, x, targets)
+        p_norm, t_norm = _sgd(p_norm, t_norm, g_norm, lr, mu)
+        return p_norm, t_norm, loss, dx, g_norm, g_head["kernel"].T
+
+    @partial(jax.jit, donate_argnums=(0, 1))
+    def embed(p, trace, tokens, dx, held=None):
+        """The lookup's gradient, plus a tied head's (`held`), and one
+        update of the matrix."""
+        grad = jnp.zeros_like(p["embedding"]).at[
+            tokens.reshape(-1)].add(dx.reshape(-1, dx.shape[-1]))
+        grads = {"embedding": grad if held is None else grad + held}
         new_p, new_trace = _sgd(p, trace, grads, lr, mu)
         return new_p, new_trace, grads
 
@@ -95,7 +119,8 @@ def make_steps(model, arch: dict, share, recipe: dict, probes, *,
         """What is kept of one group's gradient: norms, and probe leaves."""
         return leaf_norms(grads), _keep(grads, group, probes)
 
-    return forward, backward, head, embed, jax.jit(reduce, static_argnums=1)
+    return forward, backward, head, tied_head, embed, \
+        jax.jit(reduce, static_argnums=1)
 
 
 def follow(model, arch: dict, share, recipe: dict, make_group, groups,
@@ -106,14 +131,15 @@ def follow(model, arch: dict, share, recipe: dict, make_group, groups,
     `groups` names them all. Returns each step's loss, the first
     gradient's per-leaf norms and its probe leaves (host arrays), the
     per-leaf norms of the weights' change over the steps, and each step's
-    held-expert loads (expert layers, experts held)."""
+    held-expert loads (expert layers, experts held; no rows where the
+    stack has no expert layer)."""
     batches = [tokens] * steps
     if fault == "half_batch":
         half = (tokens.shape[1] - 1) // 2
         batches = [jnp.concatenate([t[:, :half], t[:, :half + 1]], 1)
                    for t in batches]
         fault = None
-    forward, backward, head, embed, reduce = make_steps(
+    forward, backward, head, tied_head, embed, reduce = make_steps(
         model, arch, share, recipe, tuple(probes), mode=mode,
         block_rows=block_rows, fault=fault)
     pattern = arch["hybrid_override_pattern"]
@@ -125,6 +151,10 @@ def follow(model, arch: dict, share, recipe: dict, make_group, groups,
     # stands for the others
     stands_for = [pattern.index(letter) for letter in pattern]
     with_experts = model.expert_layers(arch)
+    tied = bool(arch.get("tie_word_embeddings"))
+    if tied == ("lm_head" in groups):
+        raise ValueError(f"tie_word_embeddings {tied}, groups "
+                         f"{sorted(groups)}")
     params = {g: make_group(g) for g in groups}
     trace = {g: jax.tree.map(jnp.zeros_like, params[g]) for g in groups}
     out = {"losses": [], "loads": [], "grad_norms": {}, "first_grad": {}}
@@ -144,12 +174,19 @@ def follow(model, arch: dict, share, recipe: dict, make_group, groups,
             x.append(y)
             if i in with_experts:
                 loads.append(load)
-        (params["norm"], trace["norm"], params["lm_head"], trace["lm_head"],
-         loss, dx, g_norm, g_head) = head(
-            params["norm"], trace["norm"], params["lm_head"],
-            trace["lm_head"], x.pop(), targets)
+        held = g_head = None
+        if tied:
+            (params["norm"], trace["norm"], loss, dx, g_norm,
+             held) = tied_head(params["norm"], trace["norm"],
+                               params["embed"]["embedding"], x.pop(), targets)
+        else:
+            (params["norm"], trace["norm"], params["lm_head"],
+             trace["lm_head"], loss, dx, g_norm, g_head) = head(
+                params["norm"], trace["norm"], params["lm_head"],
+                trace["lm_head"], x.pop(), targets)
         note(step, "norm", g_norm)
-        note(step, "lm_head", g_head)
+        if not tied:
+            note(step, "lm_head", g_head)
         del g_norm, g_head
         for i, name in reversed(list(enumerate(layers))):
             params[name], trace[name], dx, grads = backward(
@@ -157,11 +194,12 @@ def follow(model, arch: dict, share, recipe: dict, make_group, groups,
             note(step, name, grads)
             del grads
         params["embed"], trace["embed"], grads = embed(
-            params["embed"], trace["embed"], inputs, dx)
+            params["embed"], trace["embed"], inputs, dx, held)
         note(step, "embed", grads)
-        del grads
+        del grads, held
         out["losses"].append(loss)
-        out["loads"].append(jnp.stack(loads))
+        out["loads"].append(jnp.stack(loads) if loads
+                            else jnp.zeros((0, share[1]), jnp.int32))
     del trace
     change = jax.jit(lambda p, p0: leaf_norms(jax.tree.map(
         jnp.subtract, p, p0)))

@@ -192,6 +192,10 @@ def test_entry_in_benchmark_json(metric):
     assert entry["workloads"] == [
         "vggf_b1024_step", "resnet50_b256_step",
         "mistral_small4_ep16_step", "nemotron3_nano_ep8_step"]
-    # appended: the accepted entries come first, in their order
-    assert [m["name"] for m in bench["per_layer"][-6:]] \
+    # appended together, in their order, after the entries accepted before
+    # them (the last of which is `ling_moe_pct`); later cells' metrics
+    # come after them
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index("ling_moe_pct") + 1
+    assert names[first:first + len(_startup.METRICS)] \
         == list(_startup.METRICS)
