@@ -1302,8 +1302,8 @@ MISTRAL_SMALL4_PUBLISHED = {
 def _language_model(name: str, extra: dict, vocab_rows: int, steps: int,
                     model: str = "mistral4") -> ExperimentConfig:
     """The language-model presets' common frame (`model`: the registry's
-    name, models/mistral4.py, models/nemotron_h.py, models/ling3.py or
-    models/solar_open2.py):
+    name, models/mistral4.py, models/nemotron_h.py, models/ling3.py,
+    models/solar_open2.py or models/jamba2.py):
     packed int32 tokens
     from the seeded source, one sequence a step, bf16 compute on float32
     weights, SGD-momentum 0.9 at a constant rate, no weight decay, no
@@ -1538,6 +1538,61 @@ def _solar_open2_tiny() -> ExperimentConfig:
         train=_replace(cfg.train, log_every=1))
 
 
+#: `AI21-Jamba2-3B`'s published config (https://huggingface.co/ai21labs/
+#: AI21-Jamba2-3B/blob/main/config.json, `model_type: jamba`): every key
+#: as published; the kinds follow from `attn_layer_period` and
+#: `attn_layer_offset`.
+JAMBA2_3B_PUBLISHED = {
+    "attn_layer_offset": 7, "attn_layer_period": 14,
+    "expert_layer_offset": 1, "expert_layer_period": 2, "hidden_act": "silu",
+    "hidden_size": 2560, "intermediate_size": 8192, "mamba_conv_bias": True,
+    "mamba_d_conv": 4, "mamba_d_state": 16, "mamba_dt_rank": 160,
+    "mamba_expand": 2, "mamba_proj_bias": False,
+    "max_position_embeddings": 262144, "num_attention_heads": 20,
+    "num_experts": 1, "num_experts_per_tok": 1, "num_hidden_layers": 28,
+    "num_key_value_heads": 1, "num_logits_to_keep": 1, "rms_norm_eps": 1e-06,
+    "sliding_window": None, "tie_word_embeddings": True,
+    "use_mamba_kernels": True,
+}
+
+
+def _jamba2_3b_v8() -> ExperimentConfig:
+    """One chip's share of AI21-Jamba2-3B's first pipeline stage: layers
+    0-13 of 28 (`MMMMMMMAMMMMMM`: thirteen Mamba-1 mixers and the attention
+    layer, a SwiGLU MLP in each), 8192 of 65536 rows of the tied
+    vocabulary (8 chips share a stage), one sequence of 4096 a step.
+    1.45 B parameters: float32 weights and momentum and bf16 gradients
+    (10 bytes each) take 13.5 GiB of the chip's 15.75 before the first
+    activation; recomputation per block and the loss in chunks, as the
+    Mistral preset and for its reason."""
+    return _language_model(
+        "jamba2_3b_v8",
+        {**JAMBA2_3B_PUBLISHED, "num_hidden_layers": 14,
+         "hybrid_override_pattern": "MMMMMMMAMMMMMM", "seq_len": 4096},
+        vocab_rows=8192, steps=100, model="jamba")
+
+
+def _jamba2_3b_tiny() -> ExperimentConfig:
+    """The same two kinds of layer at a size a CPU test holds: hidden 32
+    (64 Mamba channels, no lane tile, so the recurrence's XLA form), a
+    state of 16, dt rank 8, 2 query heads on 1 key/value head of 16, an
+    MLP of 64, attention at layer 2 of 4 (`MMAM`), vocabulary 256 tied,
+    sequences of 256 (two chunks of the recurrence), float32."""
+    cfg = _language_model(
+        "jamba2_3b_tiny",
+        {**JAMBA2_3B_PUBLISHED, "hidden_size": 32, "intermediate_size": 64,
+         "mamba_dt_rank": 8, "num_attention_heads": 2,
+         "attn_layer_period": 4, "attn_layer_offset": 2,
+         "num_hidden_layers": 4, "hybrid_override_pattern": "MMAM",
+         "seq_len": 256},
+        vocab_rows=256, steps=3, model="jamba")
+    return _replace(
+        cfg, model=_replace(cfg.model, compute_dtype="float32"),
+        data=_replace(cfg.data, global_batch_size=2),
+        optim=_replace(cfg.optim, reference_batch_size=2),
+        train=_replace(cfg.train, log_every=1))
+
+
 PRESETS = {
     "vggf_cifar10_smoke": _vggf_cifar10_smoke,
     "vggf_imagenet_dp": _vggf_imagenet_dp,
@@ -1554,6 +1609,8 @@ PRESETS = {
     "ling3_flash_tiny": _ling3_flash_tiny,
     "solar_open2_250b_ep40": _solar_open2_250b_ep40,
     "solar_open2_tiny": _solar_open2_tiny,
+    "jamba2_3b_v8": _jamba2_3b_v8,
+    "jamba2_3b_tiny": _jamba2_3b_tiny,
 }
 
 

@@ -98,7 +98,8 @@ INGEST_DESCRIPTORS: Dict[str, IngestDescriptor] = {
     "vggf_student": IngestDescriptor("vggf_student", space_to_depth=True,
                                      serving_only=True),
     # the decoder-only language models (models/mistral4.py,
-    # models/nemotron_h.py, models/ling3.py, models/solar_open2.py): packed
+    # models/nemotron_h.py, models/ling3.py, models/solar_open2.py,
+    # models/jamba2.py): packed
     # int32 tokens, no pixel wire;
     # `zoo_model_names` (the image grids, the serving router) leaves them
     # out by their kind
@@ -108,6 +109,7 @@ INGEST_DESCRIPTORS: Dict[str, IngestDescriptor] = {
     "ling3": IngestDescriptor("ling3", kind="tokens", wire="tokens"),
     "solar_open2": IngestDescriptor("solar_open2", kind="tokens",
                                     wire="tokens"),
+    "jamba": IngestDescriptor("jamba", kind="tokens", wire="tokens"),
 }
 
 

@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from distributed_vgg_f_tpu.models.mistral4 import (
-    ExpertShare, Head, LatentAttention, RMSNorm, _dense,
+    ExpertShare, Head, LatentAttention, RMSNorm, _dense, _logits,
     chunked_next_token_loss)
 from distributed_vgg_f_tpu.models.nemotron_h import (
     _a_log, _log_uniform_dt_bias)
@@ -277,8 +277,9 @@ class LingBlock(nn.Module):
 
 class LingLM(nn.Module):
     """Token ids (B, T) -> float32 logits (B, T, vocabulary held);
-    `next_token_loss` is what the train step calls. `kinds` (the letters)
-    and `block` (a layer) are the class's: models/solar_open2.py gives its
+    `next_token_loss` is what the train step calls. `kinds` (the letters),
+    `block` (a layer) and `tied` (the head is the embedding's transpose)
+    are the class's: models/solar_open2.py and models/jamba2.py give their
     own."""
     vocab_size: int
     hidden_size: int
@@ -290,6 +291,7 @@ class LingLM(nn.Module):
 
     kinds = KINDS
     block = LingBlock
+    tied = False
 
     @property
     def expert_layers(self) -> tuple:
@@ -306,12 +308,20 @@ class LingLM(nn.Module):
             letter, self.layers, self.compute_dtype, self.eps,
             name=f"layer_{i}") for i, letter in enumerate(self.pattern)]
         self.norm = RMSNorm(self.eps, name="norm")
-        self.lm_head = Head(self.hidden_size, self.vocab_size,
-                            self.compute_dtype, name="lm_head")
+        if not self.tied:
+            self.lm_head = Head(self.hidden_size, self.vocab_size,
+                                self.compute_dtype, name="lm_head")
+
+    def head_kernel(self):
+        """The head, hidden x vocabulary: its own, or the embedding's
+        transpose (one matrix, whose gradient autodiff sums over both
+        uses)."""
+        return self.embed.embedding.T if self.tied else self.lm_head.kernel
 
     def hidden(self, tokens):
         """Final-norm hidden states (B, T, hidden) and the expert layers'
-        counts (expert layers, experts_held + 1)."""
+        counts (expert layers, experts_held + 1; no rows where no layer
+        has experts)."""
         with jax.named_scope("embed_tokens"):
             x = self.embed(tokens)
         counts = []
@@ -319,17 +329,20 @@ class LingLM(nn.Module):
             x, count = block(x)
             if count is not None:
                 counts.append(count)
+        if not counts:
+            held = self.layers.get("experts", {}).get("experts_held", 0)
+            return self.norm(x), jnp.zeros((0, held + 1), jnp.int32)
         return self.norm(x), jnp.stack(counts)
 
     def __call__(self, tokens, *, train: bool = False):
         h, _ = self.hidden(tokens)
         with jax.named_scope("lm_head"):
-            return self.lm_head(h)
+            return _logits(h, self.head_kernel(), self.compute_dtype)
 
     def next_token_loss(self, tokens, targets):
         h, counts = self.hidden(tokens)
         return chunked_next_token_loss(
-            self.lm_head.kernel, h, targets, self.loss_chunk_rows,
+            self.head_kernel(), h, targets, self.loss_chunk_rows,
             self.compute_dtype), counts
 
 

@@ -274,7 +274,8 @@ class NemotronHLM(nn.Module):
 
     def hidden(self, tokens):
         """Final-norm hidden states (B, T, hidden) and the expert layers'
-        counts (expert layers, experts_held + 1)."""
+        counts (expert layers, experts_held + 1; no rows where no layer
+        has experts)."""
         with jax.named_scope("embed_tokens"):
             x = self.embed(tokens)
         counts = []
@@ -282,6 +283,9 @@ class NemotronHLM(nn.Module):
             x, count = block(x)
             if count is not None:
                 counts.append(count)
+        if not counts:
+            held = self.mixers["experts"]["experts_held"]
+            return self.norm(x), jnp.zeros((0, held + 1), jnp.int32)
         return self.norm(x), jnp.stack(counts)
 
     def __call__(self, tokens, *, train: bool = False):

@@ -117,3 +117,11 @@ def _build_solar_open2(cfg: ModelConfig) -> nn.Module:
     # as `ling3`
     from distributed_vgg_f_tpu.models import solar_open2
     return solar_open2.build(cfg.num_classes, _dtype(cfg), cfg.extra)
+
+
+@register("jamba")
+def _build_jamba(cfg: ModelConfig) -> nn.Module:
+    # as `ling3`: vocabulary rows held, published widths and the cut in
+    # depth (no share of experts: the stack has none)
+    from distributed_vgg_f_tpu.models import jamba2
+    return jamba2.build(cfg.num_classes, _dtype(cfg), cfg.extra)

@@ -63,6 +63,17 @@ LING_LM_LAYERS = ("kda_qkv", "kda_conv", "kda_gates", "kda_core", "kda_out",
 #: solar_lm_scopes.json`.
 SOLAR_LM_LAYERS = ("gqa_gate",)
 
+#: The layers of models/jamba2.py that the lists above lack (its attention
+#: is `HYBRID_LM_LAYERS`' `gqa_*`, its MLP `LING_LM_LAYERS`' `mlp_dense`):
+#: the Mamba-1 mixer (`mamba1_in` its input projection, `mamba1_conv` the
+#: convolution and its silu, `mamba1_xdt` the projection to delta, B and C,
+#: their norms and dt, `mamba1_scan` the recurrence of
+#: ops/selective_scan.py, `mamba1_gate_out` the gate and the output
+#: projection). Its cell's traces are reduced by a sixth file,
+#: `chipbench/jamba_lm_scopes.json`.
+JAMBA_LM_LAYERS = ("mamba1_in", "mamba1_conv", "mamba1_xdt", "mamba1_scan",
+                   "mamba1_gate_out")
+
 # ---------------------------------------------------------------------------
 # Host spans of set-up: names on the telemetry ring (`telemetry.span`), not
 # on the device. `chipbench/host_spans.json` is the benchmark's copy, which

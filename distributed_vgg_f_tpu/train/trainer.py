@@ -1121,31 +1121,35 @@ class Trainer:
                         if "moe_load" in fetched and tele.enabled:
                             # routing receipts (models/mistral4.py's
                             # expert share), over the expert layers of the
-                            # step just logged
+                            # step just logged; none where no layer has
+                            # experts (models/jamba2.py: an empty table)
                             load = np.asarray(fetched["moe_load"])
-                            reg.set_gauge("moe/assignments_held",
-                                          float(load.sum()))
-                            reg.set_gauge("moe/expert_load_max",
-                                          float(load.max()))
-                            reg.set_gauge("moe/expert_load_min",
-                                          float(load.min()))
-                            reg.set_gauge("moe/dropped_assignments", sum(
-                                v for k, v in last_metrics.items()
-                                if k.startswith("moe_dropped/")))
-                            # 1 = the routed buffers (`routed_capacity`
-                            # rows) held every layer's load in one pass
-                            for gauge, metric in (
-                                    ("moe/routed_passes", "moe_passes/"),
-                                    ("moe/routed_capacity", "moe_capacity/")):
-                                reg.set_gauge(gauge, max(
+                            if load.size:
+                                reg.set_gauge("moe/assignments_held",
+                                              float(load.sum()))
+                                reg.set_gauge("moe/expert_load_max",
+                                              float(load.max()))
+                                reg.set_gauge("moe/expert_load_min",
+                                              float(load.min()))
+                                reg.set_gauge("moe/dropped_assignments", sum(
                                     v for k, v in last_metrics.items()
-                                    if k.startswith(metric)))
-                            # the expert layers whose rows went back to
-                            # their tokens through the Pallas kernel
-                            # (ops/routed_combine_pallas.py)
-                            reg.set_gauge("moe/combine_kernel_layers", sum(
-                                v for k, v in last_metrics.items()
-                                if k.startswith("moe_combine_kernel/")))
+                                    if k.startswith("moe_dropped/")))
+                                # 1 = the routed buffers (`routed_capacity`
+                                # rows) held every layer's load in one pass
+                                for gauge, metric in (
+                                        ("moe/routed_passes",
+                                         "moe_passes/"),
+                                        ("moe/routed_capacity",
+                                         "moe_capacity/")):
+                                    reg.set_gauge(gauge, max(
+                                        v for k, v in last_metrics.items()
+                                        if k.startswith(metric)))
+                                # the expert layers whose rows went back to
+                                # their tokens through the Pallas kernel
+                                # (ops/routed_combine_pallas.py)
+                                reg.set_gauge("moe/combine_kernel_layers", sum(
+                                    v for k, v in last_metrics.items()
+                                    if k.startswith("moe_combine_kernel/")))
                             # the state-space layers' receipts
                             # (models/nemotron_h.py): chunks scanned in the
                             # step, the smallest decay of any layer, and

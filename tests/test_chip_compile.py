@@ -482,3 +482,56 @@ def test_latent_core_at_two_head_sizes_compiles_for_v5e(chip):
                            r"bf16\[2,8192,32,192\]\S*, "
                            r"bf16\[2,8192,32,128\]", text)
     assert gradients, "dq, dk at 192 and dv at 128"
+
+
+def _selective_scan_with_gradients(chip, monkeypatch, *, b, t, d, n, chunk,
+                                   dtype):
+    """`selective_scan.selective_scan` with its output and all six
+    gradients, compiled for the described chip as a TPU's trace would lower
+    it (`selective_scan` asks the backend, which is the CPU's here)."""
+    from distributed_vgg_f_tpu.ops import selective_scan
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    arg = lambda shape, kind: jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+
+    def loss(x, dt, a, b_in, c_out, d_skip):
+        return jnp.sum(selective_scan.selective_scan(
+            x, dt, a, b_in, c_out, d_skip, chunk=chunk))
+
+    return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))).lower(
+        arg((b, t, d), dtype), arg((b, t, d), jnp.float32),
+        arg((d, n), jnp.float32), arg((b, t, n), dtype),
+        arg((b, t, n), dtype), arg((d,), jnp.float32)).compile()
+
+
+def test_selective_scan_compiles_at_the_cell_s_widths(chip, monkeypatch):
+    """ops/selective_scan.py at `jamba2_3b_v8`'s widths (4,096 positions,
+    5,120 channels, a state of 16, chunks of 128, bf16) with its gradients:
+    the two Pallas kernels of ops/selective_scan_pallas.py (the forward
+    that saves the chunks' entering states, the backward), no array as
+    large as the positions' states (t x d x n, 336 M elements) nor any
+    larger than one of (t, d) in any computation, and the temporaries
+    under a quarter of a GiB (0.08 as compiled: the states entering the
+    chunks, B and C over a lane tile, the partial sums of dB and dC)."""
+    t, d = 4096, 5120
+    compiled = _selective_scan_with_gradients(
+        chip, monkeypatch, b=1, t=t, d=d, n=16, chunk=128,
+        dtype=jnp.bfloat16)
+    text = compiled.as_text()
+    assert len(re.findall(r"= .*custom-call.*tpu_custom_call", text)) == 2
+    wide = sorted({kind for kind in re.findall(r"\w+\[[\d,]+\]", text)
+                   if _elements(kind) > t * d})
+    assert not wide, wide
+    memory = compiled.memory_analysis()
+    temporaries = (memory.peak_memory_in_bytes - memory.argument_size_in_bytes
+                   - memory.output_size_in_bytes) / 2 ** 30
+    assert temporaries < 0.25, f"{temporaries:.2f} GiB of temporaries"
+
+
+def test_selective_scan_at_the_tiny_preset_s_widths_stays_xla(chip,
+                                                              monkeypatch):
+    """`jamba2_3b_tiny` (64 channels, a state of 16, float32) on a TPU: the
+    channels are no lane tile, so no kernel."""
+    compiled = _selective_scan_with_gradients(
+        chip, monkeypatch, b=2, t=256, d=64, n=16, chunk=128,
+        dtype=jnp.float32)
+    assert "tpu_custom_call" not in compiled.as_text()

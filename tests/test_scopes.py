@@ -78,7 +78,8 @@ def lowered():
             "mistral4": _lowered_token_step(),
             "nemotron_h": _lowered_token_step("nemotron3_nano_tiny"),
             "ling3": _lowered_token_step("ling3_flash_tiny"),
-            "solar_open2": _lowered_token_step("solar_open2_tiny")}
+            "solar_open2": _lowered_token_step("solar_open2_tiny"),
+            "jamba2": _lowered_token_step("jamba2_3b_tiny")}
 
 
 def _stacks(text: str) -> set:
@@ -102,13 +103,19 @@ HOME = {**{name: "vggf" for name in scopes.PHASES},
         **{name: "mistral4" for name in scopes.LM_LAYERS},
         **{name: "nemotron_h" for name in scopes.HYBRID_LM_LAYERS},
         **{name: "ling3" for name in scopes.LING_LM_LAYERS},
-        **{name: "solar_open2" for name in scopes.SOLAR_LM_LAYERS}}
+        **{name: "solar_open2" for name in scopes.SOLAR_LM_LAYERS},
+        **{name: "jamba2" for name in scopes.JAMBA_LM_LAYERS}}
 
 
 def test_every_declared_name_has_a_home():
     assert set(HOME) == set(scopes.PHASES) | set(scopes.LAYERS) \
         | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS) \
-        | set(scopes.LING_LM_LAYERS) | set(scopes.SOLAR_LM_LAYERS)
+        | set(scopes.LING_LM_LAYERS) | set(scopes.SOLAR_LM_LAYERS) \
+        | set(scopes.JAMBA_LM_LAYERS)
+    assert not (set(scopes.PHASES) | set(scopes.LAYERS)
+                | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS)
+                | set(scopes.LING_LM_LAYERS) | set(scopes.SOLAR_LM_LAYERS)) \
+        & set(scopes.JAMBA_LM_LAYERS)
     assert not (set(scopes.PHASES) | set(scopes.LAYERS)
                 | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS)) \
         & set(scopes.LING_LM_LAYERS)
@@ -124,7 +131,8 @@ def test_every_declared_name_has_a_home():
 
 @pytest.mark.parametrize("name", scopes.PHASES + scopes.LAYERS
                          + scopes.LM_LAYERS + scopes.HYBRID_LM_LAYERS
-                         + scopes.LING_LM_LAYERS + scopes.SOLAR_LM_LAYERS)
+                         + scopes.LING_LM_LAYERS + scopes.SOLAR_LM_LAYERS
+                         + scopes.JAMBA_LM_LAYERS)
 def test_declared_name_reaches_the_lowered_program(lowered, name):
     assert _holds(_stacks(lowered[HOME[name]]), name)
 
@@ -199,6 +207,74 @@ def test_the_solar_model_s_names_file_equals_the_declared_lists(lowered):
                    for s in stacks), name
         assert any(s.endswith(name) and "transpose(" in s
                    for s in stacks), name
+
+
+def test_the_jamba_model_s_names_file_equals_the_declared_lists(lowered):
+    """`chipbench/jamba_lm_scopes.json`, the benchmark's own copy: its
+    layers are AI21-Jamba2-3B's own names (`mamba1_*`) and the ones it
+    shares (grouped-query attention's, the dense MLP's, the embedding, the
+    head), each of which reaches that model's lowered step, forward and
+    backward; its phases are the first names file's."""
+    import json
+    from chipbench import scope_reduce
+    here = os.path.dirname(scope_reduce.__file__)
+    with open(os.path.join(here, "jamba_lm_scopes.json")) as f:
+        names = json.load(f)
+    gqa = {n for n in scopes.HYBRID_LM_LAYERS if n.startswith("gqa_")}
+    assert set(names["layers"]) == set(scopes.JAMBA_LM_LAYERS) | gqa \
+        | {"mlp_dense", "embed_tokens", "lm_head"}
+    assert names["phases"] == scope_reduce.declared()["phases"]
+    assert set(names["mamba1"]) == set(scopes.JAMBA_LM_LAYERS)
+    assert set(names["gqa"]) == gqa and names["mlp"] == ["mlp_dense"]
+    for group in ("mamba1_scan", "gqa_core"):
+        assert names[group] == [group]
+    stacks = _stacks(lowered["jamba2"])
+    for name in names["layers"]:
+        assert _holds(stacks, name), name
+    for name in (*scopes.JAMBA_LM_LAYERS, "gqa_core", "mlp_dense"):
+        assert any(name in s and "transpose(" not in s
+                   for s in stacks), name
+        assert any(name in s and "transpose(" in s for s in stacks), name
+    # the tied head's products sit under `lm_head`, both ways
+    assert any(s.endswith("lm_head") and "transpose(" in s for s in stacks)
+
+
+def test_selective_scan_kernels_keep_the_name_mamba1_scan(monkeypatch):
+    """Jamba's train step at the smallest width the selective scan's Pallas
+    kernels take (128 Mamba channels), lowered for a TPU. As `ssd_pallas.
+    scan`, the op is one jitted function lowered once for the three Mamba
+    layers: two kernels (the forward that saves the chunks' states, which
+    the blocks' recomputation runs; the hand-written backward; the plain
+    forward is not traced, as every Mamba layer is recomputed), each under
+    the `mamba1_scan` the function opens itself, so the benchmark's reader
+    gives them to that row."""
+    import json
+    from chipbench import scope_reduce
+    cfg = apply_overrides(get_config("jamba2_3b_tiny"),
+                          {"model.extra.hidden_size": 64})
+    mesh = build_mesh(MeshSpec((cfg.mesh.data_axis,), (1,)),
+                      jax.devices()[:1])
+    trainer = Trainer(cfg, mesh=mesh, logger=MetricLogger(stream=io.StringIO()))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (cfg.data.global_batch_size, cfg.model.extra["seq_len"] + 1),
+        jnp.int32)}
+    state, rng = jax.eval_shape(trainer.init_state), trainer.base_rng()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = trainer.train_step.__wrapped__.trace(state, batch, rng).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    monkeypatch.undo()
+    with open(os.path.join(os.path.dirname(scope_reduce.__file__),
+                           "jamba_lm_scopes.json")) as f:
+        names = json.load(f)
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = [named[loc] for loc in re.findall(
+        r"custom_call @tpu_custom_call.*loc\((#loc\d+)\)", text)]
+    scan = sorted(call for call in calls if "gqa_core" not in call)
+    assert scan and all("mamba1_scan" in call for call in scan), calls
+    assert {scope_reduce.scope_of(call + ":", names)[0]
+            for call in scan} == {"mamba1_scan"}
+    assert any(stack.endswith("layer_0/mixer/mamba1_scan/jit(scan)")
+               for stack in re.findall(r'loc\("([^"]*)"', text))
 
 
 def test_the_ling_model_s_names_file_equals_the_declared_lists(lowered):
@@ -496,7 +572,8 @@ def test_call_sites_and_declared_lists_agree():
     found = (found - {"pool{b}"}) | {f"pool{b}" for b in range(1, 6)}
     assert found == set(scopes.PHASES) | set(scopes.LAYERS) \
         | set(scopes.LM_LAYERS) | set(scopes.HYBRID_LM_LAYERS) \
-        | set(scopes.LING_LM_LAYERS) | set(scopes.SOLAR_LM_LAYERS)
+        | set(scopes.LING_LM_LAYERS) | set(scopes.SOLAR_LM_LAYERS) \
+        | set(scopes.JAMBA_LM_LAYERS)
 
 
 def test_host_span_call_sites_and_declared_lists_agree():
